@@ -179,6 +179,9 @@ def _merge_trajectory(json_path, records, prune_stale):
 
 
 def main() -> None:
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     args = sys.argv[1:]
     json_path = None
     serving_path = None

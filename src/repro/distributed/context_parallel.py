@@ -32,8 +32,6 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
-
 from repro.distributed import sharding as shd
 from repro.distributed.sharding import constrain
 
@@ -48,12 +46,13 @@ def attn_context_mode() -> Optional[str]:
     The mode is read at TRACE time, and jax's tracing cache keys on
     function identity + avals, not on this thread-local context — so a
     closure traced under one mode would silently replay under another.
-    That reuse is guarded: every trace-time read is recorded
-    (sharding.record_traced_mode) and ``use_rules`` flushes jax's caches
-    whenever the effective mode changes across a context boundary, forcing
-    a retrace (counted as 'sharding/trace_cache_flushes'). Distinct
-    closures per mode (train()'s per-run step_fn) stay the cheap path —
-    they never trigger a flush.
+    That reuse is guarded: every read is recorded
+    (sharding.record_traced_mode) -- eager reads too, since a read cannot
+    tell whether it is being traced -- and ``use_rules`` flushes jax's
+    caches whenever a *different* mode was recorded across a context
+    boundary, forcing a retrace (counted as 'sharding/trace_cache_flushes').
+    Distinct closures per mode (train()'s per-run step_fn) stay the cheap
+    path — they never trigger a flush.
     """
     state = shd.current()
     if state is None:
@@ -67,8 +66,7 @@ def attn_context_mode() -> Optional[str]:
             mode = "gather"
         else:
             mode = None
-    if not jax.core.trace_state_clean():
-        shd.record_traced_mode(mode)
+    shd.record_traced_mode(mode)
     return mode
 
 

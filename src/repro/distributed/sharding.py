@@ -213,20 +213,12 @@ def named_sharding(*names: Optional[str]) -> Optional[NamedSharding]:
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` (replication checks off).
+    """``jax.shard_map`` with the replication checks off.
 
-    ``jax.shard_map(check_vma=...)`` only exists on newer jax; older
-    releases ship ``jax.experimental.shard_map.shard_map(check_rep=...)``.
     The manual-collective bodies here (MoE expert parallelism, ring
-    attention) always want the replication checker off — ppermute/psum
-    patterns it cannot verify.
+    attention) always want the checker off — ppermute/psum patterns it
+    cannot verify.
     """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )

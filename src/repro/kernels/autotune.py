@@ -37,9 +37,13 @@ plus the split-KV decode's ``num_splits``:
 
 The committed cache is honest only for the environment that produced it
 (the ``backend`` field records it; this repo's CI measures CPU interpret
-mode, where step count dominates). ``--check`` guards staleness: it
-re-sweeps the smoke shapes and fails if the committed knobs measure more
-than ``--tol`` slower than a fresh winner.
+mode, where step count dominates). :func:`lookup` therefore answers only
+when the ``backend`` field's platform (the part before ``/``) is the one
+running: on a TPU a ``cpu/interpret`` cache is ignored and every knob
+comes from the heuristics until a sweep on the chip writes its own
+cache. ``--check`` guards staleness: it re-sweeps the smoke shapes and
+fails if the committed knobs measure more than ``--tol`` slower than a
+fresh winner.
 
 CLI::
 
@@ -245,13 +249,18 @@ def lookup(impl: str, causal: bool, seq: int, heads: int, head_dim: int,
     impl/causal/head-dim/dtype whose seq is within NEAREST_SEQ_RADIUS
     (2x), ranked by (heads mismatch, |log2 seq ratio|). Null-valued knobs
     and provenance fields are stripped so callers can treat the result as
-    "knobs this entry pins".
+    "knobs this entry pins". A cache measured on another platform than the
+    running one answers nothing (see the module docstring).
     """
     import math
 
+    import jax
     import jax.numpy as jnp
 
-    entries = load_cache(path)["entries"]
+    doc = load_cache(path)
+    if doc["backend"].split("/")[0] != jax.default_backend():
+        return {}
+    entries = doc["entries"]
     key = cache_key(impl, causal, seq, heads, head_dim, dtype)
     entry = entries.get(key)
     if entry is None:

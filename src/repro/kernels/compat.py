@@ -1,9 +1,4 @@
-"""Version + backend shims for the Pallas TPU API surface.
-
-jax renamed ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams`` (and
-back-compat aliases come and go between releases); the kernels only ever
-need "the dataclass that accepts dimension_semantics". Resolve it once here
-so flash_fwd / flash_bwd / flash_decode are version-agnostic.
+"""Backend shim for the Pallas kernels.
 
 ``resolve_interpret`` is the single place where ``interpret=None`` (the
 default everywhere: ops.py, AttentionConfig, kernel entry points) becomes a
@@ -16,7 +11,6 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
@@ -24,11 +18,3 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
     if interpret is None:
         return jax.default_backend() != "tpu"
     return interpret
-
-if hasattr(pltpu, "CompilerParams"):
-    CompilerParams = pltpu.CompilerParams
-elif hasattr(pltpu, "TPUCompilerParams"):
-    CompilerParams = pltpu.TPUCompilerParams
-else:  # very old jax: dimension_semantics went via a plain dict
-    def CompilerParams(**kwargs):  # type: ignore[no-redef]
-        return dict(**kwargs)

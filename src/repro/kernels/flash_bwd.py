@@ -9,8 +9,9 @@ into dQ. TPUs have no HBM atomics; two TPU realizations live here:
     visible Q tiles past it. Per tile, ``(s, p)`` is recomputed ONCE and
     feeds all five streamed matmuls (dV, dP, dK, dQ plus the s recompute),
     dK/dV accumulate in VMEM scratch across the KV run, and the tile's dQ
-    contribution is added to a revisited f32 output block (the atomic-add
-    replacement: the grid's step axis is ``"arbitrary"``/sequential, so
+    contribution is read-modify-written into an f32 HBM block by explicit
+    DMA (the atomic-add replacement: the grid's step axis is
+    ``"arbitrary"``/sequential and each write-back is waited for, so
     revisits are ordered and race-free). ``delta = rowsum(dO o O)`` is
     fused into the q-row prologue: the schedule's STEP_QFIRST step for each
     q tile zero-inits the dq block and computes delta into a lane-major
@@ -29,9 +30,9 @@ q-major for dq (grid ``(BH, n_steps)``) -- so masked-out tiles cost no grid
 steps and no DMAs; ``"dense"`` is the legacy visit-everything grid.
 
 All recompute P = exp(S - L) from the logsumexp only (C1b, line 11).
-Softmax statistics arrive LANE-MAJOR: lse and delta are ``(BH, Sqp)`` f32
-with the sequence on the 128-lane axis (BlockSpec ``(1, block_q)``) -- the
-memory-diet contract shared with flash_fwd.py. In the split backward,
+Softmax statistics arrive LANE-MAJOR: lse and delta are ``(BH, 1, Sqp)`` f32
+with the sequence on the 128-lane axis (BlockSpec ``(None, 1, block_q)``) --
+the memory-diet contract shared with flash_fwd.py. In the split backward,
 D = rowsum(dO o O) (line 4) is computed by :func:`flash_bwd_delta`, a
 one-pass Pallas kernel, instead of an XLA elementwise pass over the
 broadcast layout; the fused backward absorbs even that launch.
@@ -48,8 +49,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.masks import DEFAULT_MASK_VALUE, MaskSpec
-from repro.kernels.compat import CompilerParams, resolve_interpret
-from repro.kernels.flash_fwd import _tile_mask, _visibility
+from repro.kernels.compat import resolve_interpret
+from repro.kernels.flash_fwd import _tile_mask, _visibility, lane_rows, lane_spec
 from repro.kernels.schedule import (
     STEP_QFIRST,
     build_tile_schedule,
@@ -78,7 +79,7 @@ def _delta_kernel(o_ref, do_ref, delta_ref):
 
 
 def flash_bwd_delta(o, do, *, block_q: int, interpret: Optional[bool] = None):
-    """rowsum(dO o O) over prepped (BH, Sqp, D) tensors -> (BH, Sqp) f32.
+    """rowsum(dO o O) over prepped (BH, Sqp, D) tensors -> (BH, 1, Sqp) f32.
 
     One fused read of O and dO per tile, emitting the lane-major delta the
     backward kernels consume directly (no 128x broadcast round-trip).
@@ -91,9 +92,9 @@ def flash_bwd_delta(o, do, *, block_q: int, interpret: Optional[bool] = None):
         _delta_kernel,
         grid=(BH, Sqp // block_q),
         in_specs=[spec, spec],
-        out_specs=pl.BlockSpec((1, block_q), lambda bh, i: (bh, i)),
-        out_shape=jax.ShapeDtypeStruct((BH, Sqp), jnp.float32),
-        compiler_params=CompilerParams(
+        out_specs=lane_spec(block_q, lambda bh, i: (bh, i)),
+        out_shape=jax.ShapeDtypeStruct((BH, 1, Sqp), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -239,7 +240,7 @@ def flash_bwd_dkv(
 ):
     """Returns (dk, dv) in (BHk, Skp, D) fp32. q pre-scaled by 1/sqrt(d).
 
-    lse/delta are lane-major (BH, Sqp) f32; segment ids (if any) are
+    lse/delta are lane-major (BH, 1, Sqp) f32; segment ids (if any) are
     unreplicated (B, Sqp)/(B, Skp).
     """
     interpret = resolve_interpret(interpret)
@@ -273,19 +274,17 @@ def flash_bwd_dkv(
         qspec = pl.BlockSpec(
             (1, block_q, D), lambda bh, j, g, i, grp=group: (bh * grp + g, i, 0)
         )
-        lspec = pl.BlockSpec(
-            (1, block_q), lambda bh, j, g, i, grp=group: (bh * grp + g, i)
-        )
+        lspec = lane_spec(block_q, lambda bh, j, g, i, grp=group: (bh * grp + g, i))
         kvspec = pl.BlockSpec((1, block_kv, D), lambda bh, j, g, i: (bh, j, 0))
         in_specs = [qspec, kvspec, kvspec, qspec, lspec, lspec]
         inputs = [q, k, v, do, lse, delta]
         if has_segments:
             heads = BHk // q_seg.shape[0]
             in_specs += [
-                pl.BlockSpec((1, block_q), lambda bh, j, g, i, h=heads: (bh // h, i)),
-                pl.BlockSpec((1, block_kv), lambda bh, j, g, i, h=heads: (bh // h, j)),
+                lane_spec(block_q, lambda bh, j, g, i, h=heads: (bh // h, i)),
+                lane_spec(block_kv, lambda bh, j, g, i, h=heads: (bh // h, j)),
             ]
-            inputs += [q_seg, kv_seg]
+            inputs += [lane_rows(q_seg), lane_rows(kv_seg)]
         return pl.pallas_call(
             kernel,
             grid=(BHk, t_kv, group, t_q),
@@ -293,7 +292,7 @@ def flash_bwd_dkv(
             out_specs=[kvspec, kvspec],
             out_shape=out_shape,
             scratch_shapes=scratch_shapes,
-            compiler_params=CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
             ),
             cost_estimate=cost,
@@ -315,8 +314,8 @@ def flash_bwd_dkv(
         (1, block_q, D),
         lambda bh, s, g, o_, i_, f_, *_, grp=group: (bh * grp + g, i_[s], 0),
     )
-    lspec = pl.BlockSpec(
-        (1, block_q),
+    lspec = lane_spec(
+        block_q,
         lambda bh, s, g, o_, i_, f_, *_, grp=group: (bh * grp + g, i_[s]),
     )
     kvspec = pl.BlockSpec(
@@ -332,14 +331,16 @@ def flash_bwd_dkv(
             segment_step_tables(q_seg, kv_seg, sched, block_q, block_kv, kv_major=True)
         )
         in_specs += [
-            pl.BlockSpec(
-                (1, block_q), lambda bh, s, g, o_, i_, f_, t_, h=heads: (bh // h, i_[s])
+            lane_spec(
+                block_q,
+                lambda bh, s, g, o_, i_, f_, t_, h=heads: (bh // h, i_[s]),
             ),
-            pl.BlockSpec(
-                (1, block_kv), lambda bh, s, g, o_, i_, f_, t_, h=heads: (bh // h, o_[s])
+            lane_spec(
+                block_kv,
+                lambda bh, s, g, o_, i_, f_, t_, h=heads: (bh // h, o_[s]),
             ),
         ]
-        inputs += [q_seg, kv_seg]
+        inputs += [lane_rows(q_seg), lane_rows(kv_seg)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalar_args),
         grid=(BHk, sched.n_steps, group),
@@ -351,7 +352,7 @@ def flash_bwd_dkv(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         cost_estimate=cost,
@@ -464,7 +465,7 @@ def flash_bwd_dq(
 ):
     """Returns dq in (BH, Sq, D) fp32 (gradient w.r.t. *scaled* q).
 
-    lse/delta are lane-major (BH, Sqp) f32; segment ids (if any) are
+    lse/delta are lane-major (BH, 1, Sqp) f32; segment ids (if any) are
     unreplicated (B, Sqp)/(B, Skp).
     """
     interpret = resolve_interpret(interpret)
@@ -490,17 +491,17 @@ def flash_bwd_dq(
             kv_valid=kv_valid, has_segments=has_segments,
         )
         qspec = pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0))
-        lspec = pl.BlockSpec((1, block_q), lambda bh, i, j: (bh, i))
+        lspec = lane_spec(block_q, lambda bh, i, j: (bh, i))
         kvspec = pl.BlockSpec((1, block_kv, D), lambda bh, i, j, g=group: (bh // g, j, 0))
         in_specs = [qspec, kvspec, kvspec, qspec, lspec, lspec]
         inputs = [q, k, v, do, lse, delta]
         if has_segments:
             heads = BH // q_seg.shape[0]
             in_specs += [
-                pl.BlockSpec((1, block_q), lambda bh, i, j, h=heads: (bh // h, i)),
-                pl.BlockSpec((1, block_kv), lambda bh, i, j, h=heads: (bh // h, j)),
+                lane_spec(block_q, lambda bh, i, j, h=heads: (bh // h, i)),
+                lane_spec(block_kv, lambda bh, i, j, h=heads: (bh // h, j)),
             ]
-            inputs += [q_seg, kv_seg]
+            inputs += [lane_rows(q_seg), lane_rows(kv_seg)]
         return pl.pallas_call(
             kernel,
             grid=(BH, t_q, t_kv),
@@ -508,7 +509,7 @@ def flash_bwd_dq(
             out_specs=qspec,
             out_shape=out_shape,
             scratch_shapes=scratch_shapes,
-            compiler_params=CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
             cost_estimate=cost,
@@ -527,7 +528,7 @@ def flash_bwd_dq(
     qspec = pl.BlockSpec(
         (1, block_q, D), lambda bh, s, o_, i_, f_, *_: (bh, o_[s], 0)
     )
-    lspec = pl.BlockSpec((1, block_q), lambda bh, s, o_, i_, f_, *_: (bh, o_[s]))
+    lspec = lane_spec(block_q, lambda bh, s, o_, i_, f_, *_: (bh, o_[s]))
     kvspec = pl.BlockSpec(
         (1, block_kv, D), lambda bh, s, o_, i_, f_, *_, g=group: (bh // g, i_[s], 0)
     )
@@ -541,14 +542,13 @@ def flash_bwd_dq(
             segment_step_tables(q_seg, kv_seg, sched, block_q, block_kv)
         )
         in_specs += [
-            pl.BlockSpec(
-                (1, block_q), lambda bh, s, o_, i_, f_, t_, h=heads: (bh // h, o_[s])
-            ),
-            pl.BlockSpec(
-                (1, block_kv), lambda bh, s, o_, i_, f_, t_, h=heads: (bh // h, i_[s])
+            lane_spec(block_q, lambda bh, s, o_, i_, f_, t_, h=heads: (bh // h, o_[s])),
+            lane_spec(
+                block_kv,
+                lambda bh, s, o_, i_, f_, t_, h=heads: (bh // h, i_[s]),
             ),
         ]
-        inputs += [q_seg, kv_seg]
+        inputs += [lane_rows(q_seg), lane_rows(kv_seg)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalar_args),
         grid=(BH, sched.n_steps),
@@ -560,7 +560,7 @@ def flash_bwd_dq(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         cost_estimate=cost,
@@ -575,19 +575,20 @@ def flash_bwd_dq(
 #
 # kv-major like the dkv kernel, but the step body also emits the tile's dQ
 # contribution, so (s, p) is recomputed once per visible tile instead of
-# twice and Q/dO/lse tiles stream once instead of twice. dQ lives in an f32
-# OUTPUT revisited across the sequential axis ("arbitrary" semantics: steps
-# run in order, and an output block whose index map returns to a previously
-# written block sees the written values -- the interpret-mode executor
-# carries outputs block-by-block, and the Mosaic pipeline re-fetches a
-# non-immediately-revisited window). The schedule's STEP_QFIRST bit marks
-# each q tile's first visit: zero the dq block and compute
-# delta = rowsum(dO o O) into a lane-major VMEM scratch row, keyed by
-# (g, q_tile) so it survives the revisits of that q tile later in the
+# twice and Q/dO/lse tiles stream once instead of twice. dQ is an f32 HBM
+# output (memory space ANY) that each visit read-modify-writes by explicit
+# DMA through a (block_q, D) VMEM buffer: the kv-major sweep returns to a
+# dq block after other blocks, and a pipelined output block is never read
+# back from HBM when the sweep returns to it, so accumulating into one is
+# wrong under Mosaic. Each visit waits for its own write-back, so the next
+# visit's read sees it. The schedule's STEP_QFIRST bit marks each q tile's
+# first visit: start the dq block from zero instead of reading it, and
+# compute delta = rowsum(dO o O) into a lane-major VMEM scratch row, keyed
+# by (g, q_tile) so it survives the revisits of that q tile later in the
 # sweep; no separate flash_bwd_delta launch, no delta HBM array at all.
 
 
-def _fused_qrow_prologue(o_ref, do_ref, delta_scr, dq_ref, g, i, q_first):
+def _fused_qrow_prologue(o_ref, do_ref, delta_scr, dq_buf, g, i, q_first):
     """QFIRST work: delta = rowsum(dO o O) (Algorithm 2 line 4) + dq = 0.
 
     Runs before the tile compute so the same step can consume the delta it
@@ -599,19 +600,21 @@ def _fused_qrow_prologue(o_ref, do_ref, delta_scr, dq_ref, g, i, q_first):
         delta_scr[g, i] = jnp.sum(
             o_ref[0].astype(jnp.float32) * do_ref[0].astype(jnp.float32), axis=-1
         )
-        dq_ref[0] = jnp.zeros_like(dq_ref[0])
+        dq_buf[...] = jnp.zeros_like(dq_buf)
 
     return delta_scr[g, i][:, None]
 
 
 def _fused_compute(q_ref, k_ref, v_ref, do_ref, lse_ref, delta,
-                   dk_scr, dv_scr, dq_ref, spec, i, j, bq, bk, kv_valid,
-                   needs_mask, q_seg, kv_seg):
+                   dk_scr, dv_scr, dq_buf, dq_read, q_first, spec, i, j, bq,
+                   bk, kv_valid, needs_mask, q_seg, kv_seg):
     """One visible tile of the fused backward: 5 streamed matmuls total.
 
     The (s, p) recompute and the dK/dV/dS math are the shared
     :func:`_dkv_tile_math`; the fused kernel adds only the lse cleanup (the
-    split path does it outside the kernel) and the dQ contribution.
+    split path does it outside the kernel) and the dQ contribution, added
+    to ``dq_buf`` once ``dq_read`` (started by the caller unless
+    ``q_first``) has landed.
     """
     k = k_ref[0]      # (bk, d)
     lse = lse_ref[0]  # (bq,), lane-major source
@@ -623,11 +626,47 @@ def _fused_compute(q_ref, k_ref, v_ref, do_ref, lse_ref, delta,
         dk_scr, dv_scr, spec, i, j, bq, bk, kv_valid, needs_mask,
         q_seg, kv_seg,
     )
-    # dQ_i += dS K_j -- revisit-accumulated in the f32 output   (line 15)
-    dq_ref[0] += jax.lax.dot_general(
+    dq_tile = jax.lax.dot_general(
         ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
+
+    @pl.when(~q_first)
+    def _land():
+        dq_read.wait()
+
+    # dQ_i += dS K_j -- accumulated across the sweep's visits  (line 15)
+    dq_buf[...] += dq_tile
+
+
+def _fused_dq_step(o_ref, do_ref, delta_scr, dq_hbm, dq_buf, dq_sems,
+                   row, g, i, bq, q_first, active, compute):
+    """The dq read-modify-write around one fused step.
+
+    A visible tile that is not its q tile's first visit starts reading the
+    tile's dq so far from HBM before ``compute(delta, dq_read)`` runs the
+    tile's matmuls (which wait for it only before the add); every step that
+    touched ``dq_buf`` writes it back and waits, so the next visit of the
+    same block reads the sum.
+    """
+    rows = pl.ds(pl.multiple_of(i * bq, bq), bq)
+    dq_read = pltpu.make_async_copy(dq_hbm.at[row, rows], dq_buf, dq_sems.at[0])
+    dq_write = pltpu.make_async_copy(dq_buf, dq_hbm.at[row, rows], dq_sems.at[1])
+
+    @pl.when(jnp.logical_and(active, ~q_first))
+    def _fetch():
+        dq_read.start()
+
+    delta = _fused_qrow_prologue(o_ref, do_ref, delta_scr, dq_buf, g, i, q_first)
+
+    @pl.when(active)
+    def _compute():
+        compute(delta, dq_read)
+
+    @pl.when(jnp.logical_or(active, q_first))
+    def _store():
+        dq_write.start()
+        dq_write.wait()
 
 
 def _fused_kernel_dense(
@@ -637,12 +676,15 @@ def _fused_kernel_dense(
 ):
     if has_segments:
         (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, qs_ref, ks_ref,
-         dk_ref, dv_ref, dq_ref, dk_scr, dv_scr, delta_scr) = refs
+         dk_ref, dv_ref, dq_hbm, dk_scr, dv_scr, delta_scr, dq_buf,
+         dq_sems) = refs
         q_seg, kv_seg = qs_ref[0], ks_ref[0]
     else:
         (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-         dk_ref, dv_ref, dq_ref, dk_scr, dv_scr, delta_scr) = refs
+         dk_ref, dv_ref, dq_hbm, dk_scr, dv_scr, delta_scr, dq_buf,
+         dq_sems) = refs
         q_seg = kv_seg = None
+    bh = pl.program_id(0)
     j = pl.program_id(1)
     g = pl.program_id(2)
     i = pl.program_id(3)
@@ -652,16 +694,16 @@ def _fused_kernel_dense(
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    # Dense q-row prologue: every (i, g) is first visited at j == 0.
-    delta = _fused_qrow_prologue(o_ref, do_ref, delta_scr, dq_ref, g, i, j == 0)
-
     empty, needs_mask = _visibility(spec, i, j, bq, bk, kv_valid, q_seg, kv_seg)
+    q_first = j == 0  # dense: every (i, g) is first visited at j == 0
 
-    @pl.when(~empty)
-    def _compute():
+    def compute(delta, dq_read):
         _fused_compute(q_ref, k_ref, v_ref, do_ref, lse_ref, delta,
-                       dk_scr, dv_scr, dq_ref, spec, i, j, bq, bk, kv_valid,
-                       needs_mask, q_seg, kv_seg)
+                       dk_scr, dv_scr, dq_buf, dq_read, q_first, spec, i, j,
+                       bq, bk, kv_valid, needs_mask, q_seg, kv_seg)
+
+    _fused_dq_step(o_ref, do_ref, delta_scr, dq_hbm, dq_buf, dq_sems,
+                   bh * group + g, g, i, bq, q_first, ~empty, compute)
 
     @pl.when(jnp.logical_and(g == group - 1, i == t_q - 1))
     def _emit():
@@ -677,12 +719,14 @@ def _fused_kernel_compact(
     if has_segments:
         (outer_ref, inner_ref, flags_ref, seg_ref,
          q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, qs_ref, ks_ref,
-         dk_ref, dv_ref, dq_ref, dk_scr, dv_scr, delta_scr) = refs
+         dk_ref, dv_ref, dq_hbm, dk_scr, dv_scr, delta_scr, dq_buf,
+         dq_sems) = refs
         q_seg, kv_seg = qs_ref[0], ks_ref[0]
     else:
         (outer_ref, inner_ref, flags_ref,
          q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-         dk_ref, dv_ref, dq_ref, dk_scr, dv_scr, delta_scr) = refs
+         dk_ref, dv_ref, dq_hbm, dk_scr, dv_scr, delta_scr, dq_buf,
+         dq_sems) = refs
         q_seg = kv_seg = None
     bh = pl.program_id(0)
     s = pl.program_id(1)
@@ -699,15 +743,15 @@ def _fused_kernel_compact(
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    delta = _fused_qrow_prologue(
-        o_ref, do_ref, delta_scr, dq_ref, g, i, (flags & STEP_QFIRST) != 0
-    )
+    q_first = (flags & STEP_QFIRST) != 0
 
-    @pl.when(active)
-    def _compute():
+    def compute(delta, dq_read):
         _fused_compute(q_ref, k_ref, v_ref, do_ref, lse_ref, delta,
-                       dk_scr, dv_scr, dq_ref, spec, i, j, bq, bk, kv_valid,
-                       needs_mask, q_seg, kv_seg)
+                       dk_scr, dv_scr, dq_buf, dq_read, q_first, spec, i, j,
+                       bq, bk, kv_valid, needs_mask, q_seg, kv_seg)
+
+    _fused_dq_step(o_ref, do_ref, delta_scr, dq_hbm, dq_buf, dq_sems,
+                   bh * group + g, g, i, bq, q_first, active, compute)
 
     @pl.when(jnp.logical_and(last, g == group - 1))
     def _emit():
@@ -724,7 +768,7 @@ def flash_bwd_fused(
     """One-pass Algorithm 2: (dk, dv, dq) from a single pallas_call.
 
     q pre-scaled by 1/sqrt(d); o/do are the prepped (BH, Sqp, D) residual
-    and cotangent; lse is the RAW lane-major (BH, Sqp) f32 logsumexp (the
+    and cotangent; lse is the RAW lane-major (BH, 1, Sqp) f32 logsumexp (the
     -inf cleanup for fully-masked rows happens in-kernel). Returns
 
       dk, dv  (BHk, Skp, D) f32
@@ -752,19 +796,23 @@ def flash_bwd_fused(
         flops=BH * n_vis * 2 * block_q * block_kv * D * 5,  # 5 matmuls/tile
         bytes_accessed=2 * k.size * k.dtype.itemsize
         + BH * n_vis * 3 * block_q * D * q.dtype.itemsize   # q, do, o tiles
-        + BH * n_vis * 2 * block_q * D * 4,                 # dq revisit r/w
+        + BH * n_vis * 2 * block_q * D * 4,                 # dq DMA r/w
         transcendentals=BH * n_vis * block_q * block_kv,    # ONE exp/tile
     )
     out_shape = [
         jax.ShapeDtypeStruct((BHk, Skp, D), jnp.float32),  # dk
         jax.ShapeDtypeStruct((BHk, Skp, D), jnp.float32),  # dv
-        jax.ShapeDtypeStruct((BH, Sq, D), jnp.float32),    # dq (revisited)
+        jax.ShapeDtypeStruct((BH, Sq, D), jnp.float32),    # dq (read-modify-write)
     ]
     scratch_shapes = [
         pltpu.VMEM((block_kv, D), jnp.float32),             # dk run scratch
         pltpu.VMEM((block_kv, D), jnp.float32),             # dv run scratch
         pltpu.VMEM((group, t_q, block_q), jnp.float32),     # delta rows
+        pltpu.VMEM((block_q, D), jnp.float32),              # dq in flight
+        pltpu.SemaphoreType.DMA((2,)),                      # dq read, write
     ]
+    # dq stays in HBM: _fused_dq_step moves its blocks by explicit DMA.
+    dq_out = pl.BlockSpec(memory_space=pl.ANY)
 
     if schedule == "dense":
         kernel = functools.partial(
@@ -774,27 +822,25 @@ def flash_bwd_fused(
         qspec = pl.BlockSpec(
             (1, block_q, D), lambda bh, j, g, i, grp=group: (bh * grp + g, i, 0)
         )
-        lspec = pl.BlockSpec(
-            (1, block_q), lambda bh, j, g, i, grp=group: (bh * grp + g, i)
-        )
+        lspec = lane_spec(block_q, lambda bh, j, g, i, grp=group: (bh * grp + g, i))
         kvspec = pl.BlockSpec((1, block_kv, D), lambda bh, j, g, i: (bh, j, 0))
         in_specs = [qspec, kvspec, kvspec, qspec, qspec, lspec]
         inputs = [q, k, v, do, o, lse]
         if has_segments:
             heads = BHk // q_seg.shape[0]
             in_specs += [
-                pl.BlockSpec((1, block_q), lambda bh, j, g, i, h=heads: (bh // h, i)),
-                pl.BlockSpec((1, block_kv), lambda bh, j, g, i, h=heads: (bh // h, j)),
+                lane_spec(block_q, lambda bh, j, g, i, h=heads: (bh // h, i)),
+                lane_spec(block_kv, lambda bh, j, g, i, h=heads: (bh // h, j)),
             ]
-            inputs += [q_seg, kv_seg]
+            inputs += [lane_rows(q_seg), lane_rows(kv_seg)]
         return pl.pallas_call(
             kernel,
             grid=(BHk, t_kv, group, t_q),
             in_specs=in_specs,
-            out_specs=[kvspec, kvspec, qspec],
+            out_specs=[kvspec, kvspec, dq_out],
             out_shape=out_shape,
             scratch_shapes=scratch_shapes,
-            compiler_params=CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 # j is sequential here (dq accumulates across KV runs) --
                 # the dense-fused baseline gives up dkv's parallel j axis.
                 dimension_semantics=("parallel", "arbitrary", "arbitrary", "arbitrary"),
@@ -818,8 +864,8 @@ def flash_bwd_fused(
         (1, block_q, D),
         lambda bh, s, g, o_, i_, f_, *_, grp=group: (bh * grp + g, i_[s], 0),
     )
-    lspec = pl.BlockSpec(
-        (1, block_q),
+    lspec = lane_spec(
+        block_q,
         lambda bh, s, g, o_, i_, f_, *_, grp=group: (bh * grp + g, i_[s]),
     )
     kvspec = pl.BlockSpec(
@@ -835,26 +881,28 @@ def flash_bwd_fused(
             segment_step_tables(q_seg, kv_seg, sched, block_q, block_kv, kv_major=True)
         )
         in_specs += [
-            pl.BlockSpec(
-                (1, block_q), lambda bh, s, g, o_, i_, f_, t_, h=heads: (bh // h, i_[s])
+            lane_spec(
+                block_q,
+                lambda bh, s, g, o_, i_, f_, t_, h=heads: (bh // h, i_[s]),
             ),
-            pl.BlockSpec(
-                (1, block_kv), lambda bh, s, g, o_, i_, f_, t_, h=heads: (bh // h, o_[s])
+            lane_spec(
+                block_kv,
+                lambda bh, s, g, o_, i_, f_, t_, h=heads: (bh // h, o_[s]),
             ),
         ]
-        inputs += [q_seg, kv_seg]
+        inputs += [lane_rows(q_seg), lane_rows(kv_seg)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalar_args),
         grid=(BHk, sched.n_steps, group),
         in_specs=in_specs,
-        out_specs=[kvspec, kvspec, qspec],
+        out_specs=[kvspec, kvspec, dq_out],
         scratch_shapes=scratch_shapes,
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         cost_estimate=cost,

@@ -10,9 +10,9 @@ head in one step (the paper's MQA/GQA indexing note).
 
 Layouts (ops.py): q (B*Hkv, G, D) pre-scaled; kv (B*Hkv, S, D);
 lengths (B*Hkv,) int32 in SMEM. Outputs o_parts (B*Hkv, ns, G, D) fp32 and
-lse_parts (B*Hkv, ns, G) fp32 -- lane-major, the same softmax-stat layout
-contract as flash_fwd.py (DESIGN.md Section 2), merged in XLA by
-``online_softmax.combine_lse_outputs``.
+lse_parts (B*Hkv, ns, 1, G) fp32 -- lane-major with a unit sublane axis, the
+same softmax-stat layout contract as flash_fwd.py (DESIGN.md Section 2),
+merged in XLA by ``online_softmax.combine_lse_outputs``.
 """
 
 from __future__ import annotations
@@ -26,9 +26,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.masks import DEFAULT_MASK_VALUE
-from repro.kernels.compat import CompilerParams, resolve_interpret
+from repro.kernels.compat import resolve_interpret
+from repro.kernels.flash_fwd import LANES, lane_rows, lane_spec
 
-LANES = 128
+
+def _lse_spec(G: int, index_map) -> pl.BlockSpec:
+    """One split's ``(G,)`` row of the (BHk, ns, 1, G) lse partials: the
+    last two block dims equal the whole array dims, which Mosaic accepts
+    for any G."""
+    return pl.BlockSpec((None, None, 1, G), index_map)
 
 
 def _decode_kernel(
@@ -71,7 +77,7 @@ def _decode_kernel(
     ) / l_safe
     lse = jnp.where(l == 0.0, -jnp.inf, m + jnp.log(l_safe))
     o_ref[0, 0] = jnp.where(any_valid, o, 0.0)
-    lse_ref[0, 0] = lse[:, 0]  # (G,) lane-major
+    lse_ref[0] = lse[:, 0]  # (G,) lane-major
 
 
 def flash_decode_kernel(
@@ -95,9 +101,11 @@ def flash_decode_kernel(
     # parallelism gone exactly when the cache is ragged. Instead: 8-aligned
     # (sublane) ceil-div chunks, the cache padded up to ns*chunk, and the
     # tail masked by the existing `cols < L` guard (pad cols sit at logical
-    # positions >= S >= L), so the partial merge stays exact.
-    ns = max(1, min(num_splits, -(-S // 8)))
-    chunk = -(-(-(-S // ns)) // 8) * 8  # ceil(ceil(S/ns) / 8) * 8
+    # positions >= S >= L), so the partial merge stays exact. Packed-cache
+    # segment ids put the chunk on the lane axis, so there it is 128-aligned.
+    align = LANES if kv_seg is not None else 8
+    ns = max(1, min(num_splits, -(-S // align)))
+    chunk = -(-(-(-S // ns)) // align) * align  # ceil(ceil(S/ns) / align) * align
     ns = -(-S // chunk)
     pad = ns * chunk - S
     if pad:
@@ -130,21 +138,21 @@ def flash_decode_kernel(
     if has_segments:
         in_specs.insert(1, pl.BlockSpec(memory_space=pltpu.SMEM))
         inputs.insert(1, q_seg)
-        in_specs.append(pl.BlockSpec((1, chunk), lambda bh, c: (bh, c)))
-        inputs.append(kv_seg)
+        in_specs.append(lane_spec(chunk, lambda bh, c: (bh, c)))
+        inputs.append(lane_rows(kv_seg))
     return pl.pallas_call(
         kernel,
         grid=(BHk, ns),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, G, D), lambda bh, c: (bh, c, 0, 0)),
-            pl.BlockSpec((1, 1, G), lambda bh, c: (bh, c, 0)),
+            _lse_spec(G, lambda bh, c: (bh, c, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BHk, ns, G, D), jnp.float32),
-            jax.ShapeDtypeStruct((BHk, ns, G), jnp.float32),
+            jax.ShapeDtypeStruct((BHk, ns, 1, G), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         cost_estimate=cost,
@@ -165,7 +173,7 @@ def _paged_decode_kernel(
     k_ref,    # (1, 1, ps, D) -- the page the index map named
     v_ref,
     o_ref,    # (1, 1, G, D)
-    lse_ref,  # (1, 1, G)
+    lse_ref,  # (1, G)
     m_scr,    # VMEM (G, LANES) f32
     l_scr,    # VMEM (G, LANES) f32
     acc_scr,  # VMEM (G, D) f32
@@ -244,7 +252,7 @@ def _paged_decode_kernel(
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0] = acc_scr[...] / l_safe
         lse = jnp.where(l == 0.0, -jnp.inf, m_scr[:, :1] + jnp.log(l_safe))
-        lse_ref[0, 0] = lse[:, 0]  # (G,) lane-major
+        lse_ref[0] = lse[:, 0]  # (G,) lane-major
 
 
 def flash_decode_paged_kernel(
@@ -271,7 +279,7 @@ def flash_decode_paged_kernel(
     (0): their DMA is a cheap repeat and their compute is skipped.
 
     Returns per-split partials ``(o_parts (BHk, ns, G, D) f32,
-    lse_parts (BHk, ns, G) f32)`` for ``combine_lse_outputs``.
+    lse_parts (BHk, ns, 1, G) f32)`` for ``combine_lse_outputs``.
     """
     interpret = resolve_interpret(interpret)
     BHk, G, D = q.shape
@@ -316,7 +324,7 @@ def flash_decode_paged_kernel(
         ],
         out_specs=[
             pl.BlockSpec((1, 1, G, D), lambda bh, c, p, *_: (bh, c, 0, 0)),
-            pl.BlockSpec((1, 1, G), lambda bh, c, p, *_: (bh, c, 0)),
+            _lse_spec(G, lambda bh, c, p, *_: (bh, c, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((G, LANES), jnp.float32),
@@ -329,9 +337,9 @@ def flash_decode_paged_kernel(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((BHk, ns, G, D), jnp.float32),
-            jax.ShapeDtypeStruct((BHk, ns, G), jnp.float32),
+            jax.ShapeDtypeStruct((BHk, ns, 1, G), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=cost,

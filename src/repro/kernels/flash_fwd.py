@@ -34,16 +34,21 @@ TPU mapping of the paper's scheme (DESIGN.md Section 2):
     cross-"worker" communication, exactly as in the paper's Figure 3 right.
   * C1: the accumulator is un-rescaled until the final KV step, where we
     apply ``diag(l)^-1`` once and emit the logsumexp.
-  * The logsumexp is emitted LANE-MAJOR: ``(BH, Sq)`` f32 with the sequence
-    on the 128-lane axis, BlockSpec ``(1, block_q)`` -- 128x fewer softmax-
-    stat bytes than the historical ``(BH, Sq, LANES)`` broadcast. The
-    backward consumes the same layout; decode's split merge reuses it.
+  * The logsumexp is emitted LANE-MAJOR: ``(BH, 1, Sq)`` f32 with the
+    sequence on the 128-lane axis, BlockSpec ``(None, 1, block_q)`` -- 128x
+    fewer softmax-stat bytes than the historical ``(BH, Sq, LANES)``
+    broadcast. The unit sublane axis is what Mosaic needs: the last two
+    block dims must each be a multiple of (8, 128) or the whole array dim,
+    and ``1`` is the whole dim while a ``block_q`` that is a multiple of 128
+    tiles the lanes. The backward consumes the same layout; decode's split
+    merge reuses it.
 
 Layout contract (set up by ops.py): q (BH, Sq, D), k/v (BHk, Skv, D) with
 BH = B * Hq, BHk = B * Hkv, q head ``h`` reading kv head ``h // G``.
 All sequence lengths pre-padded to the block size; KV padding masked here.
-Segment ids (packed varlen) arrive UNREPLICATED as (B, Sqp)/(B, Skp); the
-index maps divide the head-row id by the head count.
+Segment ids (packed varlen) arrive UNREPLICATED as (B, Sqp)/(B, Skp) and
+get the same unit sublane axis as lse (:func:`lane_rows`); the index maps
+divide the head-row id by the head count.
 """
 
 from __future__ import annotations
@@ -57,7 +62,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.masks import DEFAULT_MASK_VALUE, MaskSpec
-from repro.kernels.compat import CompilerParams, resolve_interpret
+from repro.kernels.compat import resolve_interpret
 from repro.kernels.schedule import (
     build_partitioned_schedule,
     build_tile_schedule,
@@ -66,6 +71,23 @@ from repro.kernels.schedule import (
 )
 
 LANES = 128
+
+
+def lane_rows(x: jnp.ndarray) -> jnp.ndarray:
+    """(N, S) -> (N, 1, S): a lane-major side array in the layout its
+    ``(None, 1, block)`` BlockSpecs tile (see the module docstring)."""
+    return x[:, None, :]
+
+
+def lane_spec(block: int, index_map) -> pl.BlockSpec:
+    """BlockSpec of one ``(block,)`` row of a :func:`lane_rows` array; the
+    kernel reads and writes ``ref[0]``. ``index_map`` returns the (row,
+    lane-block) pair."""
+    def _map(*ids):
+        row, col = index_map(*ids)
+        return row, 0, col
+
+    return pl.BlockSpec((None, 1, block), _map)
 
 
 def _visibility(
@@ -374,10 +396,10 @@ def flash_fwd(
     paper's Section 3.2 forward partitioning: the grid grows a *parallel*
     partition axis over q-row bands x contiguous kv ranges (see
     ``schedule.build_partitioned_schedule``). With ``kv_splits == 1`` the
-    return contract is unchanged -- ``(o (BH, Sq, D), lse (BH, Sq))``,
+    return contract is unchanged -- ``(o (BH, Sq, D), lse (BH, 1, Sq))``,
     bitwise-equal to the unbanded schedule. With ``kv_splits > 1`` the
     kernel returns *partials* ``(o_parts (BH, kv_splits, Sq, D) f32,
-    lse_parts (BH, kv_splits, Sq) f32)`` for the caller to fold with
+    lse_parts (BH, kv_splits, 1, Sq) f32)`` for the caller to fold with
     ``online_softmax.merge_partials`` (ops.py does).
     """
     interpret = resolve_interpret(interpret)
@@ -402,7 +424,7 @@ def flash_fwd(
     cost = _fwd_cost(BH, n_vis, block_q, block_kv, D, q, k)
     out_shape = [
         jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
-        jax.ShapeDtypeStruct((BH, Sq), jnp.float32),  # lane-major lse
+        jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),  # lane-major lse
     ]
     scratch_shapes = [
         pltpu.VMEM((block_q, LANES), jnp.float32),
@@ -424,21 +446,21 @@ def flash_fwd(
         if has_segments:
             heads = BH // q_seg.shape[0]
             in_specs += [
-                pl.BlockSpec((1, block_q), lambda bh, i, j, h=heads: (bh // h, i)),
-                pl.BlockSpec((1, block_kv), lambda bh, i, j, h=heads: (bh // h, j)),
+                lane_spec(block_q, lambda bh, i, j, h=heads: (bh // h, i)),
+                lane_spec(block_kv, lambda bh, i, j, h=heads: (bh // h, j)),
             ]
-            inputs += [q_seg, kv_seg]
+            inputs += [lane_rows(q_seg), lane_rows(kv_seg)]
         return pl.pallas_call(
             kernel,
             grid=(BH, t_q, t_kv),
             in_specs=in_specs,
             out_specs=[
                 pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
-                pl.BlockSpec((1, block_q), lambda bh, i, j: (bh, i)),
+                lane_spec(block_q, lambda bh, i, j: (bh, i)),
             ],
             out_shape=out_shape,
             scratch_shapes=scratch_shapes,
-            compiler_params=CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
             cost_estimate=cost,
@@ -480,17 +502,18 @@ def flash_fwd(
             segment_step_tables(q_seg, kv_seg, sched, block_q, block_kv)
         )
         in_specs += [
-            pl.BlockSpec((1, block_q), lambda bh, s, o_, i_, f_, t_, h=heads: (bh // h, o_[s])),
-            pl.BlockSpec((1, block_kv), lambda bh, s, o_, i_, f_, t_, h=heads: (bh // h, i_[s])),
+            lane_spec(block_q, lambda bh, s, o_, i_, f_, t_, h=heads: (bh // h, o_[s])),
+            lane_spec(block_kv,
+                      lambda bh, s, o_, i_, f_, t_, h=heads: (bh // h, i_[s])),
         ]
-        inputs += [q_seg, kv_seg]
+        inputs += [lane_rows(q_seg), lane_rows(kv_seg)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalar_args),
         grid=(BH, sched.n_steps),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, s, o_, i_, f_, *_: (bh, o_[s], 0)),
-            pl.BlockSpec((1, block_q), lambda bh, s, o_, i_, f_, *_: (bh, o_[s])),
+            lane_spec(block_q, lambda bh, s, o_, i_, f_, *_: (bh, o_[s])),
         ],
         scratch_shapes=scratch_shapes,
     )
@@ -498,7 +521,7 @@ def flash_fwd(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         cost_estimate=cost,
@@ -551,30 +574,30 @@ def _flash_fwd_partitioned(
             segment_step_tables(q_seg, kv_seg, sched, block_q, block_kv)
         )
         in_specs += [
-            pl.BlockSpec(
-                (1, block_q),
+            lane_spec(
+                block_q,
                 lambda bh, p, s, o_, i_, f_, k_, t_, h=heads: (bh // h, o_[p, s]),
             ),
-            pl.BlockSpec(
-                (1, block_kv),
+            lane_spec(
+                block_kv,
                 lambda bh, p, s, o_, i_, f_, k_, t_, h=heads: (bh // h, i_[p, s]),
             ),
         ]
-        inputs += [q_seg, kv_seg]
+        inputs += [lane_rows(q_seg), lane_rows(kv_seg)]
     if ks == 1:
         # bands only: same outputs as the unbanded schedule, bitwise-equal
         # (each q row runs its unchanged kv visit sequence, just on a
         # different parallel grid cell).
         out_shape = [
             jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),
         ]
         out_specs = [
             pl.BlockSpec(
                 (1, block_q, D), lambda bh, p, s, o_, i_, f_, k_, *_: (bh, o_[p, s], 0)
             ),
-            pl.BlockSpec(
-                (1, block_q), lambda bh, p, s, o_, i_, f_, k_, *_: (bh, o_[p, s])
+            lane_spec(
+                block_q, lambda bh, p, s, o_, i_, f_, k_, *_: (bh, o_[p, s])
             ),
         ]
     else:
@@ -585,15 +608,15 @@ def _flash_fwd_partitioned(
         # output refs rank-identical to the unsplit path.
         out_shape = [
             jax.ShapeDtypeStruct((BH * ks, Sq, D), jnp.float32),
-            jax.ShapeDtypeStruct((BH * ks, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((BH * ks, 1, Sq), jnp.float32),
         ]
         out_specs = [
             pl.BlockSpec(
                 (1, block_q, D),
                 lambda bh, p, s, o_, i_, f_, k_, *_, n=ks: (bh * n + k_[p], o_[p, s], 0),
             ),
-            pl.BlockSpec(
-                (1, block_q),
+            lane_spec(
+                block_q,
                 lambda bh, p, s, o_, i_, f_, k_, *_, n=ks: (bh * n + k_[p], o_[p, s]),
             ),
         ]
@@ -613,7 +636,7 @@ def _flash_fwd_partitioned(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=cost,
@@ -622,4 +645,4 @@ def _flash_fwd_partitioned(
     )(*scalar_args, *inputs)
     if ks == 1:
         return o, lse
-    return o.reshape(BH, ks, Sq, D), lse.reshape(BH, ks, Sq)
+    return o.reshape(BH, ks, Sq, D), lse.reshape(BH, ks, 1, Sq)

@@ -13,16 +13,17 @@ Memory contract (DESIGN.md Section 2):
     kernel outputs -- the backward never re-runs ``_prep`` (no re-transpose
     / re-pad / re-scale of q, k, v). The cheap layout ops around the core
     are differentiated by XLA itself.
-  * The logsumexp is lane-major ``(BH, Sqp)`` f32 end to end (kernels emit
-    it, the backward consumes it, decode's split merge reuses it) -- 128x
-    fewer softmax-stat bytes than the old ``(BH, Sqp, LANES)`` broadcast,
-    for both lse and delta.
+  * The logsumexp is lane-major ``(BH, 1, Sqp)`` f32 end to end (kernels
+    emit it, the backward consumes it, decode's split merge reuses it) --
+    128x fewer softmax-stat bytes than the old ``(BH, Sqp, LANES)``
+    broadcast, for both lse and delta. The unit axis is the sublane axis
+    Mosaic's block-shape rule needs (flash_fwd.py's module docstring).
   * The backward is ``bwd="fused"`` by default: ONE kv-major pallas_call
     (``flash_bwd.flash_bwd_fused``) producing dK, dV, dQ *and* delta --
     (s, p) recomputed once per visible tile, delta fused into the q-row
-    prologue, dQ revisit-accumulated in an f32 output. ``bwd="split"``
-    keeps the 3-launch baseline (``flash_bwd_delta`` + ``flash_bwd_dkv`` +
-    ``flash_bwd_dq``) for parity and comparison.
+    prologue, dQ read-modify-written by DMA in an f32 HBM output.
+    ``bwd="split"`` keeps the 3-launch baseline (``flash_bwd_delta`` +
+    ``flash_bwd_dkv`` + ``flash_bwd_dq``) for parity and comparison.
   * Tile scheduling is ``schedule="compact"`` by default (see
     kernels/schedule.py); ``"dense"`` keeps the legacy visit-every-tile
     grid for comparison.
@@ -39,9 +40,12 @@ Memory contract (DESIGN.md Section 2):
     head dim grows so the fused backward's f32 dK/dV scratch plus streamed
     tiles stay inside the VMEM budget. Pass explicit ``block_q``/
     ``block_kv`` to override, exactly as before -- explicit values are
-    *legalized* (rounded up to the 8-sublane alignment the kernels assume,
-    clamped to the padded sequence length) with a warning, instead of
-    silently mis-padding the sequence.
+    *legalized* (rounded up to the alignment the kernels need, clamped to
+    the padded sequence length) with a warning, instead of silently
+    mis-padding the sequence. Compiled by Mosaic, a block is 128-aligned:
+    ``block_q`` is the lane axis of the lse/delta rows and ``block_kv`` that
+    of the kv segment ids. Interpret mode keeps the 8-row alignment, so
+    small test shapes still get several tiles.
 """
 
 from __future__ import annotations
@@ -61,6 +65,7 @@ from repro.kernels import autotune as _autotune
 from repro.kernels import flash_bwd as _bwd
 from repro.kernels import flash_decode as _dec
 from repro.kernels import flash_fwd as _fwd
+from repro.kernels.compat import resolve_interpret
 from repro.kernels.schedule import (  # re-export
     PartitionedSchedule,
     TileSchedule,
@@ -147,7 +152,9 @@ def _round_up(x: int, m: int) -> int:
 # O(G * padded_seq) term no block size can shrink. Past this budget the
 # fused kernel would blow the ~16 MB/core VMEM on real TPUs (interpret
 # mode never notices), so bwd="fused" silently degrades to the split
-# 3-launch baseline, which keeps delta in HBM.
+# 3-launch baseline, which keeps delta in HBM. The TPU compiler accepts
+# the fused kernel at the budget's edge on a v5e
+# (tests/test_tpu_compile.py).
 _FUSED_DELTA_VMEM_BUDGET = 2 * 1024 * 1024  # bytes; G * Sqp * 4 must fit
 
 
@@ -212,7 +219,7 @@ def default_block_sizes(seq_q: int, seq_kv: int, head_dim: int):
 
     The table keys off the head dim: the fused backward holds two f32
     ``(block_kv, D)`` scratch tiles (dK, dV) plus the streamed q/do/o tiles
-    and the revisited f32 dq block in VMEM at once, so ``block_kv`` shrinks
+    and the f32 dq block in flight in VMEM at once, so ``block_kv`` shrinks
     as D grows to keep that working set inside the ~16 MB/core budget.
     Both blocks clamp to the (8-aligned) padded sequence length so short
     sequences never over-pad. Explicit ``block_q``/``block_kv`` arguments
@@ -227,27 +234,30 @@ def default_block_sizes(seq_q: int, seq_kv: int, head_dim: int):
     return min(bq, _round_up(seq_q, 8)), min(bk, _round_up(seq_kv, 8))
 
 
-def _legalize_block(name: str, val, seq: int, *, explicit: bool) -> int:
+def _legalize_block(name: str, val, seq: int, *, explicit: bool,
+                    align: int = 8) -> int:
     """Legalize one block-size knob against the kernels' layout contract.
 
-    The kernels assume 8-sublane-aligned blocks and pad the sequence to a
-    block multiple; a misaligned explicit value used to flow straight into
-    ``_round_up(S, block)`` and silently corrupt the padding geometry.
-    Non-positive / non-integer values raise; otherwise the value is rounded
-    up to a multiple of 8 and clamped to the padded sequence length, with a
-    warning when an *explicit* request had to change (the heuristic and the
-    tuned cache legalize silently -- clamping to a short sequence is their
-    normal operating mode, not a user error).
+    The kernels pad the sequence to a block multiple; a misaligned explicit
+    value used to flow straight into ``_round_up(S, block)`` and silently
+    corrupt the padding geometry. Non-positive / non-integer values raise;
+    otherwise the value is rounded up to a multiple of ``align`` (8 rows in
+    interpret mode; 128 lanes under Mosaic, whose block shapes must tile
+    the lane-major side arrays) and clamped to the 8-aligned padded
+    sequence length -- a block that covers the whole padded axis is legal
+    at any alignment. A warning fires when an *explicit* request had to
+    change (the heuristic and the tuned cache legalize silently -- clamping
+    to a short sequence is their normal operating mode, not a user error).
     """
     if isinstance(val, bool) or not isinstance(val, int):
         raise ValueError(f"{name} must be an int >= 1, got {val!r}")
     if val < 1:
         raise ValueError(f"{name} must be >= 1, got {val}")
-    legal = min(_round_up(val, 8), _round_up(seq, 8))
+    legal = min(_round_up(val, align), _round_up(seq, 8))
     if explicit and legal != val:
         warnings.warn(
             f"{name}={val} is not legal for seq={seq} (blocks must be "
-            f"8-aligned and <= the padded sequence); using {legal}",
+            f"{align}-aligned and <= the padded sequence); using {legal}",
             stacklevel=3,
         )
     return legal
@@ -281,8 +291,12 @@ def resolve_pallas_knobs(cfg: PallasFlashConfig, q_shape, k_shape,
     bq_def, bk_def = default_block_sizes(Sq, Sk, D)
     bq = cfg.block_q if cfg.block_q is not None else tuned.get("block_q", bq_def)
     bk = cfg.block_kv if cfg.block_kv is not None else tuned.get("block_kv", bk_def)
-    bq = _legalize_block("block_q", bq, Sq, explicit=cfg.block_q is not None)
-    bk = _legalize_block("block_kv", bk, Sk, explicit=cfg.block_kv is not None)
+    mosaic = not resolve_interpret(cfg.interpret)
+    align = LANES if mosaic else 8
+    bq = _legalize_block("block_q", bq, Sq, explicit=cfg.block_q is not None,
+                         align=align)
+    bk = _legalize_block("block_kv", bk, Sk, explicit=cfg.block_kv is not None,
+                         align=align)
     Sqp, Skp = _round_up(Sq, bq), _round_up(Sk, bk)
     schedule = cfg.schedule or tuned.get("schedule") or "compact"
     bwd = cfg.bwd or tuned.get("bwd") or "fused"
@@ -397,7 +411,7 @@ def _prep_call(q, k, v, cfg: PallasFlashConfig, q_seg=None, kv_seg=None):
 
 
 def _core_fwd(qh, kh, vh, qs, ks, meta: _KernelMeta):
-    """flash_fwd on prepped tensors -> (o (BH, Sqp, D), lse (BH, Sqp)).
+    """flash_fwd on prepped tensors -> (o (BH, Sqp, D), lse (BH, 1, Sqp)).
 
     With ``meta.kv_splits > 1`` the kernel emits per-split partials which
     are folded here by the associative ``merge_partials`` tree
@@ -413,11 +427,11 @@ def _core_fwd(qh, kh, vh, qs, ks, meta: _KernelMeta):
         num_q_bands=meta.num_q_bands, kv_splits=meta.kv_splits,
     )
     if meta.kv_splits > 1:
-        o_parts, lse_parts = out  # (BH, ks, Sqp, D) f32, (BH, ks, Sqp) f32
+        o_parts, lse_parts = out  # (BH, ks, Sqp, D) f32, (BH, ks, 1, Sqp) f32
         o, lse = combine_lse_outputs(
-            jnp.moveaxis(o_parts, 1, 0), jnp.moveaxis(lse_parts, 1, 0)
+            jnp.moveaxis(o_parts, 1, 0), jnp.moveaxis(lse_parts[:, :, 0], 1, 0)
         )
-        return o.astype(qh.dtype), lse
+        return o.astype(qh.dtype), lse[:, None, :]
     return out
 
 
@@ -443,7 +457,7 @@ def _core_bwd(qh, kh, vh, o, lse, do, meta: _KernelMeta, qs=None, ks=None):
         return dq.astype(qh.dtype), dk.astype(kh.dtype), dv.astype(vh.dtype)
     delta = _bwd.flash_bwd_delta(
         o, do, block_q=meta.block_q, interpret=meta.interpret
-    )  # (BH, Sqp) f32: Algorithm 2 line 4
+    )  # (BH, 1, Sqp) f32: Algorithm 2 line 4
     # Fully-masked rows carry lse = -inf; zero it so exp(S - lse) stays 0
     # (S is DEFAULT_MASK_VALUE there) instead of producing inf.
     lse_s = jnp.where(jnp.isneginf(lse), 0.0, lse)
@@ -571,7 +585,7 @@ def _fwd_with_lse(q, k, v, cfg, q_seg=None, kv_seg=None):
     qh, kh, vh, qs, ks, m, meta = _prep_call(q, k, v, cfg, q_seg, kv_seg)
     o, lse = _core_fwd(qh, kh, vh, qs, ks, meta)
     o = _unheads_layout(o[:, : m["Sq"]], m["B"], m["Hq"]).astype(q.dtype)
-    lse_rows = lse[:, : m["Sq"]].reshape(m["B"], m["Hq"], m["Sq"])
+    lse_rows = lse[:, 0, : m["Sq"]].reshape(m["B"], m["Hq"], m["Sq"])
     return o, lse_rows
 
 
@@ -649,13 +663,14 @@ def flash_attention_pallas_shard_bwd(
     qh, kh, vh, _, _, m, meta = _prep_call(q, k, v, cfg)
     oh = _heads_layout(o.astype(jnp.float32))
     doh = _heads_layout(do.astype(jnp.float32))
-    lse_h = lse.astype(jnp.float32).reshape(m["B"] * m["Hq"], m["Sq"])
+    lse_h = lse.astype(jnp.float32).reshape(m["B"] * m["Hq"], 1, m["Sq"])
     pad_q = m["Sqp"] - m["Sq"]
     if pad_q:
         # Padded rows carry do = 0 and lse = -inf -> every bwd term is 0.
         oh = jnp.pad(oh, ((0, 0), (0, pad_q), (0, 0)))
         doh = jnp.pad(doh, ((0, 0), (0, pad_q), (0, 0)))
-        lse_h = jnp.pad(lse_h, ((0, 0), (0, pad_q)), constant_values=-jnp.inf)
+        lse_h = jnp.pad(lse_h, ((0, 0), (0, 0), (0, pad_q)),
+                        constant_values=-jnp.inf)
     dqh, dkh, dvh = _core_bwd(qh, kh, vh, oh, lse_h, doh, meta)
     # _core_bwd differentiates w.r.t. the pre-scaled q; fold the scale back.
     dq = _unheads_layout(dqh[:, : m["Sq"]].astype(jnp.float32) * m["scale"],
@@ -701,9 +716,8 @@ def flash_decode_pallas(
         kv_seg=kv_seg, q_seg=q_seg, interpret=interpret,
     )
     # Merge the splits (associative combine) -- (ns, BHk, G, D) / (ns, BHk, G).
-    # lse_parts is already lane-major (BHk, ns, G): no broadcast axis to strip.
     o, lse = combine_lse_outputs(
-        jnp.moveaxis(o_parts, 1, 0), jnp.moveaxis(lse_parts, 1, 0)
+        jnp.moveaxis(o_parts, 1, 0), jnp.moveaxis(lse_parts[:, :, 0], 1, 0)
     )
     return (
         o.reshape(B, 1, Hq, D).astype(q.dtype),
@@ -735,7 +749,7 @@ def flash_decode_paged_pallas(
         window=window, sink=sink, interpret=interpret,
     )
     o, lse = combine_lse_outputs(
-        jnp.moveaxis(o_parts, 1, 0), jnp.moveaxis(lse_parts, 1, 0)
+        jnp.moveaxis(o_parts, 1, 0), jnp.moveaxis(lse_parts[:, :, 0], 1, 0)
     )
     return (
         o.reshape(B, 1, Hq, D).astype(q.dtype),

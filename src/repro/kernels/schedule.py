@@ -51,12 +51,13 @@ STEP_LAST = 4    # last step of its outer-tile run -> finalize / emit
 STEP_MASKED = 8  # partial tile (or KV padding): apply the element mask
 # kv-major only (the fused one-pass backward, flash_bwd.flash_bwd_fused):
 # first/last visit of the *streamed q tile* anywhere in the flattened
-# schedule. The fused kernel consumes QFIRST (zero-init its revisited dq
-# output block + compute delta = rowsum(dO o O), so neither needs its own
-# pass). QLAST is schedule metadata only today: revisit-accumulation
-# writes dq on every visit, so there is no emit step -- the bit exists for
-# accounting (tests assert the pair brackets each q tile's visits) and for
-# an emit-style consumer (e.g. a variant that downcasts dq on last visit).
+# schedule. The fused kernel consumes QFIRST (start its dq block from zero
+# instead of reading it back + compute delta = rowsum(dO o O), so neither
+# needs its own pass). QLAST is schedule metadata only today: the fused
+# kernel writes dq back on every visit, so there is no emit step -- the
+# bit exists for accounting (tests assert the pair brackets each q tile's
+# visits) and for an emit-style consumer (e.g. a variant that downcasts dq
+# on last visit).
 STEP_QFIRST = 16
 STEP_QLAST = 32
 

@@ -8,17 +8,27 @@ parallelism within a pod's fast ICI.
 
 Functions, not module-level constants: importing this module must never
 touch jax device state (the dry-run sets XLA_FLAGS *before* first jax use).
+
+Every mesh has ``Auto`` axes: the model code places arrays with sharding
+constraints and lets the partitioner propagate them. ``jax.make_mesh``
+defaults to ``Explicit`` axes, under which a gather from a sharded
+embedding table already fails to trace.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(model_axis: int = 1):
@@ -35,7 +45,7 @@ def make_host_mesh(model_axis: int = 1):
             "(set XLA_FLAGS=--xla_force_host_platform_device_count=N)"
         )
     data = n // model_axis
-    return jax.make_mesh((data, model_axis), ("data", "model"))
+    return _mesh((data, model_axis), ("data", "model"))
 
 
 def make_long_context_mesh(data: int = 1, model: int = None):
@@ -58,4 +68,4 @@ def make_long_context_mesh(data: int = 1, model: int = None):
         raise ValueError(
             f"mesh (data={data}) x (model={model}) != {n} visible devices"
         )
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _mesh((data, model), ("data", "model"))

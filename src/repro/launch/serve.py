@@ -39,6 +39,7 @@ from repro.core.attention import AttentionConfig
 from repro.models import lm
 from repro.obs import MetricsRegistry, TraceRecorder, default_registry
 from repro.serving.engine import PagedServingEngine, Request, ServingEngine
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _drive_poisson(engine, requests, rate: float, seed: int,
@@ -70,6 +71,7 @@ def _drive_poisson(engine, requests, rate: float, seed: int,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduce", action="store_true")

@@ -49,6 +49,7 @@ from repro.training.fault_injection import FaultPlan
 from repro.training.fault_tolerance import CheckpointCadence, run_with_restarts
 from repro.training.optimizer import AdamWConfig, init_opt_state
 from repro.utils import flops as F
+from repro.utils.compile_cache import enable_compile_cache
 
 PRESETS: Dict[str, ModelConfig] = {
     # ~verifiable-on-CPU GPT-style models (paper Table 1 scale ladder)
@@ -287,9 +288,13 @@ def _train(cfg: ModelConfig, loop: TrainLoopConfig, opt_cfg: Optional[AdamWConfi
         _close_incarnation()
         inc["ctx"] = _mesh_context(cfg, loop)
         inc["ctx"].__enter__()
+        # Donated: the step's new (params, opt_state) reuse the old ones'
+        # buffers instead of holding both copies (about 14 B per param of
+        # state) at once. Nothing reads a state after its step: a save
+        # snapshots to host before returning, and a replay restores.
         inc["step_fn"] = jax.jit(build_train_step(
             cfg, attn_cfg, opt_cfg, microbatches=loop.microbatches, ce_chunk=512,
-        ))
+        ), donate_argnums=(0, 1))
         params = lm.init_lm(cfg, jax.random.PRNGKey(loop.seed))
         opt_state = init_opt_state(params)
         sharding_fn, shardings = _current_sharding_fn((params, opt_state))
@@ -428,6 +433,7 @@ def _train(cfg: ModelConfig, loop: TrainLoopConfig, opt_cfg: Optional[AdamWConfi
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, help="assigned architecture id")
     ap.add_argument("--preset", default=None, choices=sorted(PRESETS))

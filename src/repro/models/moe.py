@@ -179,9 +179,8 @@ def apply_moe(p: dict, cfg, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
         y = jax.lax.psum(y.astype(jnp.bfloat16), "model")
         return y.reshape(Bl, Sl, d).astype(x_blk.dtype)
 
-    # shd.shard_map: version-portable (jax.shard_map only exists on newer
-    # jax; 0.4.x ships jax.experimental.shard_map) with replication checks
-    # off -- the in-body psum is invisible to the checker.
+    # shd.shard_map: replication checks off -- the in-body psum is
+    # invisible to the checker.
     y = shd.shard_map(
         shard_body,
         mesh=mesh,

@@ -14,8 +14,9 @@ instead of a one-off benchmark:
     attention term -- causal/windowed masks shrink the work the kernels
     actually launch, so HFU > MFU on masked workloads;
   * **MFU / HFU** divide by the chip's peak FLOPs/s
-    (:func:`peak_flops`: ``REPRO_PEAK_FLOPS`` env override, else a
-    per-backend table).
+    (:func:`peak_flops`: ``REPRO_PEAK_FLOPS`` env override, else the
+    :data:`PEAKS` row of the running device's ``device_kind`` -- a kind
+    missing from the table raises, never falls back).
 
 All accounting is host-side arithmetic on numbers the loop already has
 (config, cache lengths, wall time) -- nothing here touches a traced
@@ -24,6 +25,7 @@ value, so attaching a meter cannot add compiles (tests/test_obs.py).
 
 from __future__ import annotations
 
+import dataclasses
 import os
 from typing import Dict, Optional, Sequence, Tuple
 
@@ -31,31 +33,60 @@ from repro.configs.base import ModelConfig, ShapeConfig
 from repro.obs.metrics import MetricsRegistry
 from repro.utils import flops as F
 
-__all__ = ["peak_flops", "mfu", "TrainEfficiency", "DecodeEfficiency"]
+__all__ = ["PEAKS", "ChipPeak", "chip_peak", "peak_flops", "mfu",
+           "TrainEfficiency", "DecodeEfficiency"]
 
-# Per-backend peak FLOPs/s (per chip). TPU matches utils/hlo_analysis
-# (bf16); gpu is the paper's A100 bf16 peak; cpu is an order-of-magnitude
-# figure for a few AVX cores -- on the CI host MFU is a sanity signal
-# (finite, > 0), not a hardware claim. REPRO_PEAK_FLOPS overrides.
-PEAK_FLOPS_BY_BACKEND: Dict[str, float] = {
-    "tpu": 197e12,
-    "gpu": 312e12,
-    "cpu": 1e11,
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeak:
+    """Published per-chip peaks of one device kind."""
+
+    flops: float            # dense bf16 FLOP/s
+    hbm_bytes_per_s: float  # HBM bandwidth
+    ici_bytes_per_s: float  # chip-to-chip bandwidth per link
+    source: str
+
+
+# The one peak table, keyed by ``jax.Device.device_kind``. MFU here and the
+# dry-run roofline (utils/hlo_analysis) both read it.
+PEAKS: Dict[str, ChipPeak] = {
+    "TPU v5 lite": ChipPeak(
+        flops=197e12, hbm_bytes_per_s=819e9, ici_bytes_per_s=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "819 GB/s HBM, 1,600 Gbit/s ICI over 4 links",
+    ),
+    # Not a hardware figure: an order of magnitude for a few AVX cores, so
+    # MFU on a CPU test host is finite and > 0 -- a sanity value only.
+    "cpu": ChipPeak(
+        flops=1e11, hbm_bytes_per_s=2e10, ici_bytes_per_s=0.0,
+        source="sanity value only, not a measurement",
+    ),
 }
 
 
-def peak_flops(backend: Optional[str] = None) -> float:
+def chip_peak(device_kind: Optional[str] = None) -> ChipPeak:
+    """The :data:`PEAKS` row of ``device_kind`` (default: the first JAX
+    device's). A kind missing from the table raises ``KeyError``."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peak for device kind {device_kind!r}; add it, "
+            "with its source, to repro.obs.mfu.PEAKS"
+        ) from None
+
+
+def peak_flops(device_kind: Optional[str] = None) -> float:
+    """Peak FLOP/s of one chip: ``REPRO_PEAK_FLOPS`` if set, else the
+    :func:`chip_peak` of ``device_kind``."""
     env = os.environ.get("REPRO_PEAK_FLOPS")
     if env:
         return float(env)
-    if backend is None:
-        try:
-            import jax
-
-            backend = jax.default_backend()
-        except Exception:
-            backend = "cpu"
-    return PEAK_FLOPS_BY_BACKEND.get(backend, PEAK_FLOPS_BY_BACKEND["cpu"])
+    return chip_peak(device_kind).flops
 
 
 def mfu(model_flops: float, seconds: float, peak: Optional[float] = None) -> float:

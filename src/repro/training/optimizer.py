@@ -39,7 +39,10 @@ class OptState(NamedTuple):
 
 
 def init_opt_state(params) -> OptState:
-    f32 = lambda t: jax.tree.map(lambda x: x.astype(jnp.float32), t)
+    # A copy even where params are already f32: the trainer donates params
+    # and state to its step, and one buffer cannot be donated twice.
+    f32 = lambda t: jax.tree.map(
+        lambda x: jnp.array(x, dtype=jnp.float32, copy=True), t)
     zeros = lambda t: jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), t)
     return OptState(jnp.zeros((), jnp.int32), f32(params), zeros(params), zeros(params))
 

@@ -3,8 +3,8 @@
 ``cost_analysis()`` gives FLOPs and bytes but NOT collective traffic, so we
 parse the (stable)HLO text: every all-gather / all-reduce / reduce-scatter /
 all-to-all / collective-permute op contributes its operand bytes. Hardware
-constants are TPU v5e-class per the brief: 197 bf16 TFLOP/s, 819 GB/s HBM,
-~50 GB/s/link ICI.
+peaks come from the one table, ``repro.obs.mfu.PEAKS``, by the target
+chip's ``device_kind`` (TPU v5e by default).
 """
 
 from __future__ import annotations
@@ -13,9 +13,7 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # bytes/s / chip
-ICI_BW = 50e9  # bytes/s / link
+from repro.obs.mfu import ChipPeak, chip_peak
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8": 1,
@@ -97,18 +95,23 @@ class Roofline:
     coll_bytes: float
     chips: int  # metadata (mesh size); terms below are already per-chip
     model_flops: Optional[float] = None
+    device_kind: str = "TPU v5 lite"  # the chip the program targets
+
+    @property
+    def peak(self) -> ChipPeak:
+        return chip_peak(self.device_kind)
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / self.peak.flops
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / self.peak.hbm_bytes_per_s
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes / ICI_BW
+        return self.coll_bytes / self.peak.ici_bytes_per_s
 
     @property
     def dominant(self) -> str:
@@ -136,7 +139,7 @@ class Roofline:
         class docstring), so the brief's MODEL_FLOPS/(chips*peak)/step_time
         reduces to mf/peak/step_time -- no further /chips."""
         mf = self.model_flops if self.model_flops is not None else self.flops
-        ideal = mf / PEAK_FLOPS
+        ideal = mf / self.peak.flops
         return ideal / max(self.step_time, 1e-30)
 
     def to_dict(self) -> dict:

@@ -49,8 +49,8 @@ def _fresh_cache_state(monkeypatch):
     autotune.clear_cache()
 
 
-def _write_cache(path, entries):
-    doc = autotune.new_doc("test", entries)
+def _write_cache(path, entries, backend=None):
+    doc = autotune.new_doc(backend or f"{jax.default_backend()}/test", entries)
     with open(path, "w") as f:
         json.dump(doc, f)
     autotune.clear_cache()
@@ -94,8 +94,9 @@ def test_validate_doc_rejects_bad_schema():
 
 def test_save_load_roundtrip(tmp_path):
     key = autotune.cache_key("flash_pallas", False, 256, 4, 64, "float32")
-    doc = autotune.new_doc("test", {key: {"block_q": 64, "block_kv": 64,
-                                          "us_fwd": 12.5}})
+    doc = autotune.new_doc(f"{jax.default_backend()}/test",
+                           {key: {"block_q": 64, "block_kv": 64,
+                                  "us_fwd": 12.5}})
     path = str(tmp_path / "tuned.json")
     autotune.save_cache(doc, path)
     loaded = autotune.load_cache(path)
@@ -163,6 +164,31 @@ def test_lookup_prefers_heads_match_then_seq(tmp_path):
     # same heads wins over closer seq
     assert autotune.lookup("flash_pallas", True, 400, 4, 64, jnp.float32,
                            path=path) == {"block_q": 512}
+
+
+def test_cache_from_another_platform_is_ignored(tmp_path, monkeypatch):
+    """A cache swept on another platform (here: a TPU cache read on the
+    CPU, or the committed CPU cache read on a TPU) pins no knob: every
+    knob falls back to the heuristics."""
+    key = autotune.cache_key("flash_pallas", True, 256, 2, 32, "float32")
+    knobs = {"block_q": 64, "block_kv": 64, "bwd": "split"}
+    other = "cpu" if jax.default_backend() == "tpu" else "tpu"
+    path = _write_cache(tmp_path / "t.json", {key: knobs},
+                        backend=f"{other}/v5e")
+    assert autotune.lookup("flash_pallas", True, 256, 2, 32, "float32",
+                           path=path) == {}
+    monkeypatch.setenv(autotune.ENV_PATH, path)
+    shape = (2, 256, 2, 32)
+    r = resolve_pallas_knobs(PallasFlashConfig(spec=CAUSAL), shape, shape)
+    heur = resolve_pallas_knobs(
+        PallasFlashConfig(spec=CAUSAL, use_tuned=False), shape, shape)
+    assert r["tuned"] == {}
+    assert {k: r[k] for k in ("block_q", "block_kv", "bwd")} == \
+        {k: heur[k] for k in ("block_q", "block_kv", "bwd")}
+    # the same entry under the running platform's label is consulted
+    path = _write_cache(tmp_path / "u.json", {key: knobs})
+    assert autotune.lookup("flash_pallas", True, 256, 2, 32, "float32",
+                           path=path) == knobs
 
 
 def test_window_and_sink_specs_skip_cache(tmp_path, monkeypatch):
@@ -314,6 +340,26 @@ def test_block_legalization_rounds_and_warns():
             shape, shape,
         )
     assert r["block_kv"] == 512  # clamped to the padded sequence
+
+
+def test_block_legalization_lane_aligned_for_mosaic():
+    """Compiled by Mosaic (interpret=False), a block is the lane axis of the
+    lse / delta / segment-id rows, so it rounds up to 128 -- unless it
+    covers the whole padded sequence, which any block shape may."""
+    shape = (1, 512, 2, 32)
+    with pytest.warns(UserWarning, match="128-aligned"):
+        r = resolve_pallas_knobs(
+            PallasFlashConfig(spec=CAUSAL, block_q=100, block_kv=64,
+                              interpret=False, use_tuned=False),
+            shape, shape,
+        )
+    assert (r["block_q"], r["block_kv"]) == (128, 128)
+    short = (1, 100, 2, 32)
+    r = resolve_pallas_knobs(
+        PallasFlashConfig(spec=CAUSAL, interpret=False, use_tuned=False),
+        short, short,
+    )
+    assert r["block_q"] == r["block_kv"] == 104  # the whole padded axis
 
 
 @pytest.mark.parametrize("bad", [0, -8, 2.5, "128", True])

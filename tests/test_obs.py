@@ -214,8 +214,10 @@ def test_peak_flops_env_override(monkeypatch):
     monkeypatch.setenv("REPRO_PEAK_FLOPS", "2.5e12")
     assert peak_flops() == 2.5e12
     monkeypatch.delenv("REPRO_PEAK_FLOPS")
-    assert peak_flops("tpu") == 197e12
-    assert peak_flops("unknown-chip") == peak_flops("cpu")
+    assert peak_flops("TPU v5 lite") == 197e12
+    # no silent stand-in: a device kind missing from the table raises
+    with pytest.raises(KeyError, match="unknown-chip"):
+        peak_flops("unknown-chip")
 
 
 def test_train_efficiency_gauges():

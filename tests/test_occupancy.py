@@ -323,8 +323,8 @@ def test_banded_grid_shape_and_parallel_axis():
         MaskSpec(causal=True), 3, 3, 64, 64, S, num_q_bands=nb
     )
     assert grid == (B * Hq, nb, sched.n_steps), grid
-    sem = eqns[0].params["compiler_params"]["mosaic"]["dimension_semantics"]
-    assert sem == ("parallel", "parallel", "arbitrary")
+    sem = eqns[0].params["compiler_params"]["mosaic_tpu"].dimension_semantics
+    assert tuple(sem) == ("parallel", "parallel", "arbitrary")
 
 
 def test_default_forward_partitions_policy():

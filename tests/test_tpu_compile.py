@@ -1,0 +1,169 @@
+"""The main-path Pallas kernels compile for a TPU v5e, at real widths.
+
+Each test lowers one kernel entry point with ``interpret=False`` for a
+described (not attached) ``v5e:2x2`` topology and compiles it with the TPU
+compiler, at qwen3-8b's attention widths: 32 query / 8 KV heads, head_dim
+128, bf16, S=4096. Mosaic refuses what interpret mode accepts -- a block
+shape that does not tile the (8, 128) vreg, a kernel whose VMEM working set
+does not fit -- so these run at no chip time and guard every change to the
+kernels. Nothing runs: results and times need the chip (``chip_smoke.py``).
+
+The topology is described only inside the module-scoped fixture: the TPU
+library may be loaded by one process at a time, and test collection must
+not depend on whether this worker got it.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.core.masks import MaskSpec
+from repro.kernels import ops
+
+B, S, HQ, HK, D = 1, 4096, 32, 8, 128
+DT = jnp.bfloat16
+SPECS = {
+    "causal": MaskSpec(causal=True),
+    "window1024": MaskSpec(causal=True, window=1024),
+}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    # A described chip's programs are written to the persistent cache but
+    # cannot be read back without one: keep the cache out of these tests.
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        jax.config.update("jax_enable_compilation_cache", prev)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _shape(shape, sharding, dtype=DT):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    """Compile ``fn`` for the described chip; returns the compiled program
+    after checking that a Mosaic kernel (not an interpreted one) is in it."""
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _qkv(sharding, seq=S):
+    return (_shape((B, seq, HQ, D), sharding), _shape((B, seq, HK, D), sharding),
+            _shape((B, seq, HK, D), sharding))
+
+
+@pytest.mark.parametrize("spec", list(SPECS))
+def test_forward_compiles(one_chip, spec):
+    def fwd(q, k, v):
+        return ops.flash_attention_pallas_with_lse(
+            q, k, v, SPECS[spec], interpret=False)
+
+    _compile(fwd, *_qkv(one_chip))
+
+
+BWD_KERNELS = {"fused": ("fa2_bwd_fused",),
+               "split": ("fa2_bwd_delta", "fa2_bwd_dkv", "fa2_bwd_dq")}
+
+
+@pytest.mark.parametrize("bwd", list(BWD_KERNELS))
+@pytest.mark.parametrize("spec", list(SPECS))
+def test_forward_backward_compiles(one_chip, spec, bwd):
+    """Each backward variant compiles as asked; the kernel names in the
+    program prove which one ran."""
+    def loss(q, k, v):
+        o = ops.flash_attention_pallas(
+            q, k, v, SPECS[spec], bwd=bwd, interpret=False)
+        return jnp.sum(o.astype(jnp.float32))
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(one_chip))
+    text = compiled.as_text()
+    for other, names in BWD_KERNELS.items():
+        for name in names:
+            assert (name in text) == (other == bwd), (bwd, name)
+
+
+def test_fused_backward_compiles_at_its_vmem_budget(one_chip):
+    """The fused kernel's delta scratch is exactly ops'
+    _FUSED_DELTA_VMEM_BUDGET (G * Sqp * 4 bytes) here, the largest the
+    resolution keeps fused; the chip's compiler must still accept it."""
+    from repro.kernels.ops import _FUSED_DELTA_VMEM_BUDGET
+
+    hq, hk = 8, 1
+    seq = _FUSED_DELTA_VMEM_BUDGET // (4 * hq)
+    r = ops.resolve_pallas_knobs(
+        ops.PallasFlashConfig(MaskSpec(causal=True), interpret=False),
+        (B, seq, hq, D), (B, seq, hk, D), DT)
+    assert r["bwd"] == "fused" and seq % r["block_q"] == 0
+
+    def loss(q, k, v):
+        o = ops.flash_attention_pallas(q, k, v, MaskSpec(causal=True),
+                                       interpret=False)
+        return jnp.sum(o.astype(jnp.float32))
+
+    compiled = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)),
+        _shape((B, seq, hq, D), one_chip), _shape((B, seq, hk, D), one_chip),
+        _shape((B, seq, hk, D), one_chip))
+    assert "fa2_bwd_fused" in compiled.as_text()
+
+
+def test_varlen_forward_backward_compiles(one_chip):
+    """Packed documents (segment ids on the lane axis of q and kv tiles)
+    and a lens-masked prefill (padding as segment 0), forward + backward."""
+    def loss(q, k, v, seg):
+        o = ops.flash_attention_pallas_varlen(
+            q, k, v, seg, MaskSpec(causal=True), interpret=False)
+        return jnp.sum(o.astype(jnp.float32))
+
+    def prefill(q, k, v, seg):
+        return ops.flash_attention_pallas_varlen_with_lse(
+            q, k, v, seg, MaskSpec(causal=True), interpret=False)
+
+    seg = _shape((B, S), one_chip, jnp.int32)
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(one_chip), seg)
+    _compile(prefill, *_qkv(one_chip), seg)
+
+
+def test_decode_compiles(one_chip):
+    batch = 8
+
+    def dec(q, k, v, lens):
+        return ops.flash_decode_pallas(q, k, v, lens, interpret=False)
+
+    _compile(dec, _shape((batch, 1, HQ, D), one_chip),
+             _shape((batch, S, HK, D), one_chip),
+             _shape((batch, S, HK, D), one_chip),
+             _shape((batch,), one_chip, jnp.int32))
+
+
+def test_paged_decode_compiles(one_chip):
+    batch, ps = 8, 16
+    n_pages = S // ps
+    pool = batch * n_pages + 1  # + the null page
+
+    def dec(q, kp, vp, lens, tbl):
+        return ops.flash_decode_paged_pallas(q, kp, vp, lens, tbl,
+                                             interpret=False)
+
+    _compile(dec, _shape((batch, 1, HQ, D), one_chip),
+             _shape((HK, pool, ps, D), one_chip),
+             _shape((HK, pool, ps, D), one_chip),
+             _shape((batch,), one_chip, jnp.int32),
+             _shape((batch, n_pages), one_chip, jnp.int32))
