@@ -166,14 +166,12 @@ def run(csv: List[str]) -> None:
     assert fixed.decode_compiles == 1, (
         f"fixed decode recompiled: {fixed.decode_compiles} traces"
     )
-    fx_snap, pg_snap = fixed.snapshot(), paged.snapshot()
 
     csv.append(
         f"serving_fixed/b{BF}_cache{CACHE},{fx['us_per_tok']:.1f},"
         f"tok_s={fx['tok_per_s']:.1f};p50_ms={fx['p50_ms']:.1f};"
         f"p95_ms={fx['p95_ms']:.1f};ticks={fx['ticks']};tokens={fx['tokens']};"
         f"slot_occupancy={fx['occupancy']:.3f};"
-        f"decode_mfu={fx_snap['decode/mfu']:.2e};"
         f"decode_compiles={fixed.decode_compiles}"
     )
     csv.append(
@@ -181,7 +179,6 @@ def run(csv: List[str]) -> None:
         f"tok_s={pg['tok_per_s']:.1f};p50_ms={pg['p50_ms']:.1f};"
         f"p95_ms={pg['p95_ms']:.1f};ticks={pg['ticks']};tokens={pg['tokens']};"
         f"page_occupancy={pg['occupancy']:.3f};"
-        f"decode_mfu={pg_snap['decode/mfu']:.2e};"
         f"preemptions={paged.preemptions};decode_compiles={paged.decode_compiles}"
     )
 

@@ -410,7 +410,8 @@ def _record_ring_pass(meta: _RingMeta, k, *, backward: bool) -> None:
     TraceRecorder is installed (launch/train.py --trace-out), emit its
     per-step span structure so an overlap/truncation regression (extra
     steps, fatter hops, lost empty-step skips) is visible in the Perfetto
-    output next to the train-step spans."""
+    output next to the train-step spans. This runs while JAX traces the
+    step, so its spans stay off the profiler's run-time clock."""
     from repro.obs.metrics import default_registry
     from repro.obs.trace import get_default_recorder
 
@@ -434,14 +435,15 @@ def _record_ring_pass(meta: _RingMeta, k, *, backward: bool) -> None:
     rec.name_thread(_RING_TRACE_TID, "ring schedule")
     with rec.span(name, tid=_RING_TRACE_TID,
                   args={"steps": T, "devices": layout.num_devices,
-                        "hop_bytes_per_device": hop_bytes}):
+                        "hop_bytes_per_device": hop_bytes}, profile=False):
         for t in range(T):
             if t < T - 1:
                 rec.instant(f"{name}_hop{t + 1}", tid=_RING_TRACE_TID,
                             args={"in_flight_during_step": t})
             with rec.span(f"{name}_step{t}", tid=_RING_TRACE_TID,
                           args={"max_tiles": int(tiles[t].max()),
-                                "tiles_per_device": tiles[t].tolist()}):
+                                "tiles_per_device": tiles[t].tolist()},
+                          profile=False):
                 pass
 
 

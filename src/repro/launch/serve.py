@@ -16,9 +16,10 @@ block-table indirect flash decode (attention-only archs).
 Observability (repro.obs): every run collects the unified metrics
 registry (printed as the ``metrics`` block of the JSON summary, written
 to ``--metrics-out``); ``--trace-out PATH`` additionally records the
-request lifecycle (submit -> queue_wait -> prefill -> per-tick decode ->
-retire, plus preempt/resume) as Chrome/Perfetto ``trace_event`` JSON --
-load the file at https://ui.perfetto.dev for a tick-by-tick timeline.
+request lifecycle (submit -> queue_wait -> prefill -> decode -> retire,
+plus preempt/resume) and each tick's ``engine.*`` spans as
+Chrome/Perfetto ``trace_event`` JSON -- load the file at
+https://ui.perfetto.dev for a tick-by-tick timeline.
 ``--arrival-rate R`` replays a Poisson arrival process (R requests per
 expected tick) instead of submitting everything upfront, so queue-wait
 spans reflect admission pressure rather than a thundering herd.
@@ -158,8 +159,6 @@ def main():
         "ticks": engine.ticks, "generated_tokens": toks,
         "tok_per_s": round(toks / dt, 1),
         "decode_compiles": engine.decode_compiles,
-        "decode_mfu": snap["decode/mfu"],
-        "decode_tok_per_s": round(snap["decode/tokens_per_s"], 1),
     }
     if args.engine == "paged":
         summary["preemptions"] = engine.preemptions

@@ -8,13 +8,14 @@ shapes (pinned by tests/test_obs.py):
     histograms behind one registry with a flat-dict ``snapshot()``
     schema. Both serving engines, the KV page pool, the kernel-knob
     resolution path and the train loop register into it.
-  * :mod:`repro.obs.trace`   -- span-based request-lifecycle and
-    train-step event log exported as Chrome/Perfetto ``trace_event``
-    JSON (``--trace-out`` on launch/serve.py and launch/train.py).
+  * :mod:`repro.obs.trace`   -- span-based request-lifecycle, engine-tick
+    and train-step event log exported as Chrome/Perfetto ``trace_event``
+    JSON (``--trace-out`` on launch/serve.py and launch/train.py); its
+    scoped spans are also ``jax.profiler`` annotations (``repro.*``).
   * :mod:`repro.obs.mfu`     -- analytic model-FLOPs (utils/flops) +
     the visible-tile census folded into live achieved-vs-model FLOPs,
-    tokens/s and MFU gauges for train and decode (the paper's Table 1
-    metric as a counter rather than a one-off benchmark).
+    tokens/s and MFU gauges for training (the paper's Table 1 metric as
+    a counter rather than a one-off benchmark).
 """
 
 from repro.obs.metrics import (  # noqa: F401
@@ -27,7 +28,6 @@ from repro.obs.metrics import (  # noqa: F401
     reset_default_registry,
 )
 from repro.obs.mfu import (  # noqa: F401
-    DecodeEfficiency,
     TrainEfficiency,
     peak_flops,
 )

@@ -1,8 +1,8 @@
 """Live efficiency accounting: achieved-vs-model FLOPs, tokens/s, MFU.
 
 The paper's headline metric (Table 1: 72% model-FLOPs utilization
-end-to-end) folded into gauges a running system updates every step/tick
-instead of a one-off benchmark:
+end-to-end) folded into gauges a training run updates every step instead of a
+one-off benchmark:
 
   * **model FLOPs** come from the analytic formulas in
     ``utils/flops.py`` (6*N_active*D + the 12*L*H*S^2 Megatron attention
@@ -19,7 +19,7 @@ instead of a one-off benchmark:
     missing from the table raises, never falls back).
 
 All accounting is host-side arithmetic on numbers the loop already has
-(config, cache lengths, wall time) -- nothing here touches a traced
+(config, batch shape, wall time) -- nothing here touches a traced
 value, so attaching a meter cannot add compiles (tests/test_obs.py).
 """
 
@@ -34,7 +34,7 @@ from repro.obs.metrics import MetricsRegistry
 from repro.utils import flops as F
 
 __all__ = ["PEAKS", "ChipPeak", "chip_peak", "peak_flops", "mfu",
-           "TrainEfficiency", "DecodeEfficiency"]
+           "TrainEfficiency"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -164,54 +164,3 @@ class TrainEfficiency:
             )
             self._g_tps.set(self._tok.value / secs)
             self._g_tflops.set(achieved / 1e12)
-
-
-class DecodeEfficiency:
-    """Per-tick decode gauges: ``<prefix>/mfu``, ``/tokens_per_s``.
-
-    A decode tick's model FLOPs depend on the *live* cache lengths (each
-    row re-reads its whole cache), so the meter takes them per tick:
-    2*N_active per live row plus the 4*d_q*L attention read per attention
-    layer -- the decode analogue of ``utils/flops.decode_model_flops``
-    summed over heterogeneous rows. Decode reads every cached key, so
-    hardware == model FLOPs here (windows still clip).
-    """
-
-    def __init__(self, cfg: ModelConfig, registry: MetricsRegistry,
-                 prefix: str = "decode", peak: Optional[float] = None):
-        self.registry = registry
-        self.prefix = prefix
-        self.peak = peak or peak_flops()
-        _, self._active_params = F.param_count(cfg)
-        self._q_dim = cfg.q_dim
-        self._attn_dims = _attn_layer_dims(cfg)
-        self._ticks = registry.counter(f"{prefix}/ticks")
-        self._tok = registry.counter(f"{prefix}/tokens")
-        self._flops = registry.counter(f"{prefix}/model_flops")
-        self._secs = registry.counter(f"{prefix}/compute_seconds")
-        self._g_mfu = registry.gauge(f"{prefix}/mfu")
-        self._g_tps = registry.gauge(f"{prefix}/tokens_per_s")
-
-    def tick_model_flops(self, cache_lens: Sequence[int]) -> float:
-        """Model FLOPs of one decode step over rows with these live cache
-        lengths (zero-length rows are dead slots and charge nothing)."""
-        live = [int(l) for l in cache_lens if int(l) > 0]
-        total = 2.0 * self._active_params * len(live)
-        for L in live:
-            for window, _sink in self._attn_dims:
-                s_eff = min(window, L) if window else L
-                total += 4.0 * self._q_dim * s_eff
-        return total
-
-    def tick(self, cache_lens: Sequence[int], seconds: float) -> int:
-        """Feed one measured decode tick; returns the live-row count."""
-        live = sum(1 for l in cache_lens if int(l) > 0)
-        self._ticks.inc()
-        self._tok.inc(live)
-        self._flops.inc(self.tick_model_flops(cache_lens))
-        self._secs.inc(seconds)
-        secs = self._secs.value
-        if secs > 0:
-            self._g_mfu.set(self._flops.value / secs / self.peak)
-            self._g_tps.set(self._tok.value / secs)
-        return live

@@ -1,20 +1,23 @@
-"""Span-based event log exported as Chrome/Perfetto ``trace_event`` JSON.
+"""Span-based event log exported as Chrome/Perfetto ``trace_event`` JSON,
+bridged onto the ``jax.profiler`` trace.
 
 One :class:`TraceRecorder` per run collects events host-side (a plain
-list of dicts -- no jax interaction, so recording around a jitted step
-cannot add compiles) and serializes to the JSON Object Format the
-Perfetto UI / ``chrome://tracing`` load directly::
+list of dicts) and serializes to the JSON Object Format the Perfetto UI /
+``chrome://tracing`` load directly::
 
     {"traceEvents": [{"name", "ph", "ts", "pid", "tid", ...}, ...],
      "displayTimeUnit": "ms"}
 
-Event vocabulary used by the repo (DESIGN.md §9 span taxonomy):
+Event vocabulary used by the repo (DESIGN.md §9.2 span taxonomy):
 
   * serving (pid ``serve``): per-request *tracks* (tid = request id)
     carry ``queue_wait`` -> ``prefill`` -> ``decode`` complete spans plus
     ``submit`` / ``retire`` / ``preempt`` / ``resume`` instants; the
-    engine track (tid 0) carries per-tick ``decode_tick`` spans,
-    ``admit`` batch spans and ``page_oom`` instants.
+    engine's own track (``serving.engine.ENGINE_TID``) carries the
+    scoped ``engine.*`` spans of each tick (``engine.tick`` >
+    ``engine.schedule`` / ``engine.admit`` / ``engine.decode`` >
+    ``.dispatch`` / ``.wait`` / ``engine.bookkeep``), ``page_oom``
+    instants and a ``resident`` counter series.
   * training (pid ``train``): per-step ``step`` spans with nested
     ``data`` / ``compute`` / ``checkpoint`` child spans on one track.
 
@@ -24,16 +27,32 @@ across NTP adjustments). Durations use ``X`` (complete) events recorded
 at span *exit* with the entry timestamp carried along: emission order
 never has to match nesting order, and a crashed span simply never emits
 (the trace stays schema-valid).
+
+A scoped :meth:`TraceRecorder.span` also opens a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>`` for its
+lifetime, carrying the span's numeric args, so a running profiler session
+records the program's spans on the device trace's clock (outside a
+session the annotation costs next to nothing). Span args are single
+numbers: the profiler keeps a number per arg, and an arg of another type
+stays in the JSON only. Retroactive :meth:`~TraceRecorder.complete`
+spans stay in the recorder, and so do spans opened while JAX traces a
+function (``profile=False``): they describe a program's structure, not a
+moment of its run. Neither side of the bridge adds a compile.
 """
 
 from __future__ import annotations
 
 import json
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Dict, List, Optional
 
+import jax.profiler
+
 __all__ = ["TraceRecorder", "set_default_recorder", "get_default_recorder"]
+
+# name prefix of the recorder's spans on the jax.profiler trace
+PROFILER_PREFIX = "repro."
 
 
 class TraceRecorder:
@@ -96,13 +115,27 @@ class TraceRecorder:
 
     @contextmanager
     def span(self, name: str, tid: int = 0, cat: str = "repro",
-             args: Optional[dict] = None):
-        """Context-managed span; emits one ``X`` event on normal exit."""
+             args: Optional[dict] = None, profile: bool = True):
+        """Context-managed span; emits one ``X`` event at exit.
+
+        Yields the span's args dict: what the body writes into it is
+        recorded at exit (args known only once the span's work is done).
+        With ``profile`` the span is also a ``jax.profiler``
+        annotation ``repro.<name>`` carrying its int and float args; pass
+        ``profile=False`` for a span opened while JAX traces a function.
+        """
+        args = dict(args or {})
         t0 = self.now_us()
-        try:
-            yield
-        finally:
-            self.complete(name, tid, t0, self.now_us() - t0, cat=cat, args=args)
+        with _annotation(name) if profile else nullcontext() as ann:
+            try:
+                yield args
+            finally:
+                numbers = {k: v for k, v in args.items()
+                           if isinstance(v, (int, float))}
+                if ann is not None and numbers:
+                    ann.set_metadata(**numbers)
+                self.complete(name, tid, t0, self.now_us() - t0, cat=cat,
+                              args=args or None)
 
     # ------------------------------------------------------------ export
     def to_json(self) -> dict:
@@ -111,6 +144,12 @@ class TraceRecorder:
     def save(self, path: str) -> None:
         with open(path, "w") as f:
             json.dump(self.to_json(), f)
+
+
+def _annotation(name: str):
+    """The profiler annotation of a span (looked up on ``jax.profiler`` at
+    each call, so a test can stand in for it)."""
+    return jax.profiler.TraceAnnotation(PROFILER_PREFIX + name)
 
 
 # ---------------------------------------------------------------------------

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from contextlib import nullcontext
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -38,7 +39,6 @@ from repro.launch.steps import (
     build_serve_step,
 )
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.mfu import DecodeEfficiency
 from repro.obs.trace import TraceRecorder
 from repro.serving.kv_pool import KVPagePool
 
@@ -67,43 +67,58 @@ _CACHE_BASE_NDIM = {"k": 4, "v": 4, "h": 3, "conv": 3}  # (B, ...) leaf ranks
 # Fixed buckets for the admission-size histogram (prompt pad buckets are
 # prompt_pad multiples clamped to capacity; pow2 bounds cover both engines)
 ADMIT_BUCKETS = (16.0, 32.0, 64.0, 128.0, 256.0, 512.0, 1024.0)
-QUEUE_WAIT_BUCKETS = (0.0, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0)
+QUEUE_WAIT_S_BUCKETS = (0.001, 0.01, 0.1, 0.5, 1.0, 2.0, 5.0, 10.0, 30.0, 60.0)
+# The engine's own trace track; request tracks use tid = rid, so it takes a
+# tid no request id reaches (the largest int32, as trace viewers read tids).
+ENGINE_TID = 2**31 - 1
 
 
 class _EngineTelemetry:
     """Shared observability surface of both serving engines.
 
-    Everything is host-side (obs/metrics, obs/trace, obs/mfu): it runs
-    *around* the jitted steps and never enters a trace, so enabling
-    telemetry adds zero compiles and leaves the step shapes untouched
+    Everything is host-side (obs/metrics, obs/trace): it runs *around*
+    the jitted steps and never enters a trace, so enabling telemetry adds
+    zero compiles and leaves the step shapes untouched
     (tests/test_obs.py pins ``decode_compiles == 1`` with it on).
 
-    Registry schema (``snapshot()``; the common interface that replaced
-    the paged-only ``stats()``):
+    Registry schema (``snapshot()``; always on):
 
-      counters   serving/{tokens, admissions, retirements, ticks}
-                 decode/{ticks, tokens, model_flops, compute_seconds}
-      gauges     decode/{mfu, tokens_per_s}  (cumulative; obs/mfu)
+      counters   serving/{tokens, admissions, retirements, ticks,
+                 prefill_tokens (real feed tokens prefilled),
+                 prefill_launched_tokens (width x bucket per launch)}
       gauge_fns  serving/{active_slots, slot_utilization, queue_depth,
                  kv_cells_active, kv_cells_capacity, token_occupancy}
                  (+ kv_pool/* and serving/{preemptions,page_oom} paged)
       histograms serving/admit_bucket (admitted pad bucket, tokens),
-                 serving/queue_wait_ticks (submit -> admission, ticks)
+                 serving/queue_wait_s (submit -> admission, seconds)
+
+    With a tracer, each tick is a tree of scoped spans on the engine
+    track, each also a ``repro.engine.*`` profiler annotation
+    (DESIGN.md §9.2): ``engine.tick`` {live, queued} holds
+    ``engine.schedule`` {picked}, one ``engine.admit`` {n, width,
+    bucket, tokens, launched} per prefill launch, ``engine.decode``
+    {live} with ``.dispatch`` and ``.wait`` children, and
+    ``engine.bookkeep`` {retired}. Without one, no span is opened.
     """
 
     def _obs_init(self, registry: Optional[MetricsRegistry],
                   tracer: Optional[TraceRecorder]):
         self.obs = registry if registry is not None else MetricsRegistry()
         self.tracer = tracer
+        if tracer is not None:
+            tracer.name_thread(ENGINE_TID, "engine")
         self._c_tokens = self.obs.counter("serving/tokens")
         self._c_admissions = self.obs.counter("serving/admissions")
         self._c_retirements = self.obs.counter("serving/retirements")
         self._c_ticks = self.obs.counter("serving/ticks")
         self._h_bucket = self.obs.histogram("serving/admit_bucket", ADMIT_BUCKETS)
-        self._h_wait = self.obs.histogram(
-            "serving/queue_wait_ticks", QUEUE_WAIT_BUCKETS
+        self._c_prefill_tokens = self.obs.counter("serving/prefill_tokens")
+        self._c_prefill_launched = self.obs.counter(
+            "serving/prefill_launched_tokens"
         )
-        self._eff = DecodeEfficiency(self.cfg, self.obs)
+        self._h_wait = self.obs.histogram(
+            "serving/queue_wait_s", QUEUE_WAIT_S_BUCKETS
+        )
         self.obs.gauge_fn(
             "serving/active_slots",
             lambda: sum(s is not None for s in self.slots),
@@ -119,7 +134,7 @@ class _EngineTelemetry:
             "serving/token_occupancy",
             lambda: self.resident_tokens() / max(1, self.kv_capacity()),
         )
-        self._submit_tick: Dict[int, int] = {}  # rid -> tick at (re)submit
+        self._submit_s: Dict[int, float] = {}  # rid -> perf_counter at (re)submit
         self._submit_ts: Dict[int, float] = {}  # rid -> trace us at submit
         self._decode_t0: Dict[int, float] = {}  # rid -> decode-span start us
         self._preempted_rids: set = set()  # resumes owe a 'resume' instant
@@ -132,9 +147,39 @@ class _EngineTelemetry:
     def decode_compiles(self) -> int:
         return self._step._cache_size()
 
+    # ------------------------------------------------------------ spans
+    def _span(self, name: str, **args):
+        """A scoped span on the engine track (yields its args dict, which
+        the body may add to); a no-op context without a tracer."""
+        if self.tracer is None:
+            return nullcontext({})
+        return self.tracer.span(name, tid=ENGINE_TID, args=args)
+
+    def _admit_span(self, n: int, width: int, bucket: int, tokens: int):
+        """One prefill launch of ``n`` requests padded to ``width`` rows x
+        ``bucket`` tokens, holding ``tokens`` real feed tokens: counted,
+        and its ``engine.admit`` span."""
+        launched = width * bucket
+        self._c_prefill_tokens.inc(tokens)
+        self._c_prefill_launched.inc(launched)
+        return self._span("engine.admit", n=n, width=width, bucket=bucket,
+                          tokens=tokens, launched=launched)
+
+    def _decode(self, live: int, next_token, *tables):
+        """The decode call, then its tokens on the host: the
+        ``engine.decode`` span with ``.dispatch`` / ``.wait`` children.
+        Returns the device tokens and their host copy."""
+        with self._span("engine.decode", live=live):
+            with self._span("engine.decode.dispatch"):
+                tok, self.caches = self._step(
+                    self.params, next_token, self.caches, *tables
+                )
+            with self._span("engine.decode.wait"):
+                return tok, np.asarray(tok)
+
     # --------------------------------------------------- lifecycle hooks
     def _note_submit(self, req: Request, *, resumed: bool = False):
-        self._submit_tick[req.rid] = self.ticks
+        self._submit_s[req.rid] = time.perf_counter()
         if self.tracer:
             self.tracer.name_thread(req.rid, f"req {req.rid}")
             self._submit_ts[req.rid] = self.tracer.now_us()
@@ -144,13 +189,16 @@ class _EngineTelemetry:
                     args={"rid": req.rid, "prompt_len": len(req.prompt)},
                 )
 
+    def _note_picked(self, req: Request):
+        """A request left the queue for a slot: its queue wait."""
+        self._h_wait.observe(time.perf_counter() - self._submit_s.pop(req.rid))
+
     def _note_admission(self, req: Request, bucket: int,
                         t_pref0: float, t_pref1: float):
         """One request admitted: counters + the rid track's queue_wait /
         prefill spans ([submit, admit) and [admit, prefill-done))."""
         self._c_admissions.inc()
         self._h_bucket.observe(bucket)
-        self._h_wait.observe(self.ticks - self._submit_tick.pop(req.rid, self.ticks))
         if self.tracer:
             sub = self._submit_ts.pop(req.rid, t_pref0)
             self.tracer.complete("queue_wait", req.rid, sub, t_pref0 - sub)
@@ -181,14 +229,11 @@ class _EngineTelemetry:
                 args={"rid": req.rid},
             )
 
-    def _note_decode_tick(self, cache_lens, t0_us: float, dt_s: float):
+    def _note_decode_tick(self, live: int):
+        self.ticks += 1
         self._c_ticks.inc()
-        live = self._eff.tick(cache_lens, dt_s)
         self._c_tokens.inc(live)
         if self.tracer:
-            self.tracer.complete(
-                "decode_tick", 0, t0_us, dt_s * 1e6, args={"live": live}
-            )
             self.tracer.counter(
                 "resident", {"slots": live, "tokens": self.resident_tokens()}
             )
@@ -286,23 +331,26 @@ class ServingEngine(_EngineTelemetry):
         beyond ``cache_len`` so decode never sees it — the first generated
         token simply overwrites it).
         """
+        self._note_picked(req)
         L = len(req.prompt)
         pad_to = -(-L // self.prompt_pad) * self.prompt_pad if self._bucket else L
         pad_to = min(pad_to, self.cache_size - 1)
         assert L <= pad_to, f"prompt ({L}) exceeds cache capacity {self.cache_size}"
-        prompt_arr = np.zeros((1, pad_to), np.int32)
-        prompt_arr[0, :L] = req.prompt
-        batch = {"inputs": jnp.asarray(prompt_arr)}
-        if self._bucket:
-            batch["lens"] = jnp.asarray([L], jnp.int32)
-        t_pref0 = self._now_us()
-        tok, cache1, lens = self._prefill(self.params, batch)
-        true_len = int(lens[0])
-        self._note_admission(req, pad_to, t_pref0, self._now_us())
+        with self._admit_span(1, 1, pad_to, L):
+            prompt_arr = np.zeros((1, pad_to), np.int32)
+            prompt_arr[0, :L] = req.prompt
+            batch = {"inputs": jnp.asarray(prompt_arr)}
+            if self._bucket:
+                batch["lens"] = jnp.asarray([L], jnp.int32)
+            t_pref0 = self._now_us()
+            tok, cache1, lens = self._prefill(self.params, batch)
+            true_len, first = int(lens[0]), int(tok[0, 0])
+            t_pref1 = self._now_us()
+        self._note_admission(req, pad_to, t_pref0, t_pref1)
         self.caches = _tree_slot_write(self.caches, cache1, slot)
         self.cache_len = self.cache_len.at[slot].set(true_len)
-        self.next_token = self.next_token.at[slot].set(int(tok[0, 0]))
-        req.generated.append(int(tok[0, 0]))
+        self.next_token = self.next_token.at[slot].set(first)
+        req.generated.append(first)
         self.slots[slot] = req
 
     def _retire(self, slot: int):
@@ -317,35 +365,33 @@ class ServingEngine(_EngineTelemetry):
     # -------------------------------------------------------------- tick
     def tick(self):
         """Admit from queue, run one decode step, retire finished."""
-        for slot in range(self.B):
-            if self.slots[slot] is None and self.queue:
-                self._admit(slot, self.queue.pop(0))
-        if not any(self.slots):
-            return
-        lens_before = np.asarray(self.cache_len)
-        t0_us, t0 = self._now_us(), time.perf_counter()
-        tok, self.caches = self._step(
-            self.params, self.next_token, self.caches, self.cache_len
-        )
-        self.cache_len = self.cache_len + jnp.asarray(
-            [1 if s is not None else 0 for s in self.slots], jnp.int32
-        )
-        self.next_token = tok
-        tok_host = np.asarray(tok)
-        self.ticks += 1
-        self._note_decode_tick(
-            [int(l) for l, s in zip(lens_before, self.slots) if s is not None],
-            t0_us, time.perf_counter() - t0,
-        )
-        for slot, req in enumerate(self.slots):
-            if req is None:
-                continue
-            t = int(tok_host[slot, 0])
-            req.generated.append(t)
-            if (req.eos_id is not None and t == req.eos_id) or len(
-                req.generated
-            ) >= req.max_new_tokens + 1 or int(self.cache_len[slot]) >= self.cache_size - 1:
-                self._retire(slot)
+        live = sum(s is not None for s in self.slots)
+        with self._span("engine.tick", live=live, queued=len(self.queue)):
+            for slot in range(self.B):
+                if self.slots[slot] is None and self.queue:
+                    self._admit(slot, self.queue.pop(0))
+            live = sum(s is not None for s in self.slots)
+            if not live:
+                return
+            tok, tok_host = self._decode(live, self.next_token, self.cache_len)
+            self.cache_len = self.cache_len + jnp.asarray(
+                [1 if s is not None else 0 for s in self.slots], jnp.int32
+            )
+            self.next_token = tok
+            self._note_decode_tick(live)
+            with self._span("engine.bookkeep") as span:
+                retired = 0
+                for slot, req in enumerate(self.slots):
+                    if req is None:
+                        continue
+                    t = int(tok_host[slot, 0])
+                    req.generated.append(t)
+                    if (req.eos_id is not None and t == req.eos_id) or len(
+                        req.generated
+                    ) >= req.max_new_tokens + 1 or int(self.cache_len[slot]) >= self.cache_size - 1:
+                        self._retire(slot)
+                        retired += 1
+                span["retired"] = retired
 
     def run(self, max_ticks: int = 1000) -> Dict[int, Request]:
         while (self.queue or any(s is not None for s in self.slots)) and self.ticks < max_ticks:
@@ -473,7 +519,51 @@ class PagedServingEngine(_EngineTelemetry):
         return min(max(pad, self.prompt_pad), self.n_max * self.ps)
 
     def _admit_tick(self):
-        """Strict-FIFO admission, then ONE batched prefill per bucket.
+        """Strict-FIFO admission, then ONE batched prefill per bucket."""
+        with self._span("engine.schedule") as span:
+            picks = self._pick()
+            span["picked"] = len(picks)
+        # Group by bucket; one batched admit call per bucket.
+        by_bucket: Dict[int, List[Tuple[int, Request, List[int]]]] = {}
+        for pick in picks:
+            by_bucket.setdefault(self._bucket(len(pick[1].feed)), []).append(pick)
+        for pad_to, group in sorted(by_bucket.items()):
+            W = min(_next_pow2(len(group)), self.B)
+            tokens = sum(len(req.feed) for _, req, _ in group)
+            with self._admit_span(len(group), W, pad_to, tokens):
+                npb = -(-pad_to // self.ps)
+                inputs = np.zeros((W, pad_to), np.int32)
+                lens = np.ones((W,), np.int32)  # dummy rows: 1 token, null dest
+                dest = np.zeros((W, npb), np.int32)
+                for i, (slot, req, pages) in enumerate(group):
+                    feed = req.feed
+                    inputs[i, : len(feed)] = feed
+                    lens[i] = len(feed)
+                    n_dest = min(-(-len(feed) // self.ps), npb)
+                    dest[i, :n_dest] = pages[:n_dest]
+                t_pref0 = self._now_us()
+                tok, lens_total, self.caches = self._admit(
+                    self.params,
+                    {"inputs": jnp.asarray(inputs), "lens": jnp.asarray(lens)},
+                    self.caches,
+                    jnp.asarray(dest),
+                )
+                tok_host = np.asarray(tok)
+                t_pref1 = self._now_us()
+            for i, (slot, req, pages) in enumerate(group):
+                self._note_admission(req, pad_to, t_pref0, t_pref1)
+                self.table[slot] = 0
+                self.table[slot, : len(pages)] = pages
+                self.cache_len[slot] = int(lens_total[i])
+                t = int(tok_host[i, 0])
+                req.generated.append(t)
+                self.next_token[slot, 0] = t
+                self.slots[slot] = req
+                self._slot_seq[slot] = self._seq
+                self._seq += 1
+
+    def _pick(self) -> List[Tuple[int, Request, List[int]]]:
+        """Strict-FIFO pick of (slot, request, pages) with pages allocated.
 
         A request is admitted only if, after taking its pages, the pool
         still holds one reserve page per resident request (including
@@ -500,46 +590,10 @@ class PagedServingEngine(_EngineTelemetry):
             if pages is None:
                 break
             self.queue.pop(0)
+            self._note_picked(req)
             picks.append((free_slots.pop(0), req, pages))
             reserve += 1
-        if not picks:
-            return
-        # Group by bucket; one batched admit call per bucket.
-        by_bucket: Dict[int, List[Tuple[int, Request, List[int]]]] = {}
-        for pick in picks:
-            by_bucket.setdefault(self._bucket(len(pick[1].feed)), []).append(pick)
-        for pad_to, group in sorted(by_bucket.items()):
-            W = min(_next_pow2(len(group)), self.B)
-            npb = -(-pad_to // self.ps)
-            inputs = np.zeros((W, pad_to), np.int32)
-            lens = np.ones((W,), np.int32)  # dummy rows: 1 token, null dest
-            dest = np.zeros((W, npb), np.int32)
-            for i, (slot, req, pages) in enumerate(group):
-                feed = req.feed
-                inputs[i, : len(feed)] = feed
-                lens[i] = len(feed)
-                n_dest = min(-(-len(feed) // self.ps), npb)
-                dest[i, :n_dest] = pages[:n_dest]
-            t_pref0 = self._now_us()
-            tok, lens_total, self.caches = self._admit(
-                self.params,
-                {"inputs": jnp.asarray(inputs), "lens": jnp.asarray(lens)},
-                self.caches,
-                jnp.asarray(dest),
-            )
-            tok_host = np.asarray(tok)
-            t_pref1 = self._now_us()
-            for i, (slot, req, pages) in enumerate(group):
-                self._note_admission(req, pad_to, t_pref0, t_pref1)
-                self.table[slot] = 0
-                self.table[slot, : len(pages)] = pages
-                self.cache_len[slot] = int(lens_total[i])
-                t = int(tok_host[i, 0])
-                req.generated.append(t)
-                self.next_token[slot, 0] = t
-                self.slots[slot] = req
-                self._slot_seq[slot] = self._seq
-                self._seq += 1
+        return picks
 
     def _clear_slot(self, slot: int):
         self.slots[slot] = None
@@ -594,7 +648,7 @@ class PagedServingEngine(_EngineTelemetry):
                     self._c_page_oom.inc()
                     if self.tracer:
                         self.tracer.instant(
-                            "page_oom", tid=0, args={"rid": req.rid}
+                            "page_oom", tid=ENGINE_TID, args={"rid": req.rid}
                         )
                     if not self._preempt_youngest():
                         raise RuntimeError(
@@ -608,37 +662,37 @@ class PagedServingEngine(_EngineTelemetry):
 
     # -------------------------------------------------------------- tick
     def tick(self):
-        self._admit_tick()
-        if not any(s is not None for s in self.slots):
-            return
-        lens_before = self.cache_len.copy()
-        t0_us, t0 = self._now_us(), time.perf_counter()
-        tok, self.caches = self._step(
-            self.params,
-            jnp.asarray(self.next_token),
-            self.caches,
-            jnp.asarray(self.table),
-            jnp.asarray(self.cache_len),
-        )
-        tok_host = np.asarray(tok)
-        self.ticks += 1
-        self._note_decode_tick(
-            lens_before, t0_us, time.perf_counter() - t0
-        )
-        for slot, req in enumerate(self.slots):
-            if req is None:
-                continue
-            self.cache_len[slot] += 1
-            t = int(tok_host[slot, 0])
-            req.generated.append(t)
-            self.next_token[slot, 0] = t
-            if (
-                (req.eos_id is not None and t == req.eos_id)
-                or len(req.generated) >= req.max_new_tokens + 1
-                or int(self.cache_len[slot]) >= self.n_max * self.ps - 1
-            ):
-                self._retire(slot)
-        self._grow()
+        live = sum(s is not None for s in self.slots)
+        with self._span("engine.tick", live=live, queued=len(self.queue)):
+            self._admit_tick()
+            live = sum(s is not None for s in self.slots)
+            if not live:
+                return
+            _, tok_host = self._decode(
+                live,
+                jnp.asarray(self.next_token),
+                jnp.asarray(self.table),
+                jnp.asarray(self.cache_len),
+            )
+            self._note_decode_tick(live)
+            with self._span("engine.bookkeep") as span:
+                retired = 0
+                for slot, req in enumerate(self.slots):
+                    if req is None:
+                        continue
+                    self.cache_len[slot] += 1
+                    t = int(tok_host[slot, 0])
+                    req.generated.append(t)
+                    self.next_token[slot, 0] = t
+                    if (
+                        (req.eos_id is not None and t == req.eos_id)
+                        or len(req.generated) >= req.max_new_tokens + 1
+                        or int(self.cache_len[slot]) >= self.n_max * self.ps - 1
+                    ):
+                        self._retire(slot)
+                        retired += 1
+                self._grow()
+                span["retired"] = retired
 
     def run(self, max_ticks: int = 10000) -> Dict[int, Request]:
         while (self.queue or any(s is not None for s in self.slots)) and self.ticks < max_ticks:

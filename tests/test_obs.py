@@ -16,7 +16,6 @@ from repro.core.attention import AttentionConfig
 from repro.core.masks import MaskSpec
 from repro.models import lm
 from repro.obs import (
-    DecodeEfficiency,
     MetricsRegistry,
     TraceRecorder,
     TrainEfficiency,
@@ -241,24 +240,6 @@ def test_train_efficiency_gauges():
     )
 
 
-def test_decode_efficiency_charges_live_rows_only():
-    reg = MetricsRegistry()
-    eff = DecodeEfficiency(TINY, reg, peak=1e12)
-    dead = eff.tick_model_flops([0, 0])
-    assert dead == 0.0
-    one = eff.tick_model_flops([16])
-    two = eff.tick_model_flops([16, 0, 16])
-    assert two == pytest.approx(2 * one)
-    # longer caches cost more (the 4*d_q*L attention read term)
-    assert eff.tick_model_flops([32]) > one
-    live = eff.tick([16, 0, 16], seconds=0.25)
-    assert live == 2
-    snap = reg.snapshot()
-    assert snap["decode/tokens"] == 2.0
-    assert snap["decode/tokens_per_s"] == pytest.approx(8.0)
-    assert math.isfinite(snap["decode/mfu"]) and snap["decode/mfu"] > 0
-
-
 # ---------------------------------------------------------------------------
 # Engine integration: common snapshot interface + THE zero-overhead pin
 # ---------------------------------------------------------------------------
@@ -293,8 +274,12 @@ def test_fixed_engine_snapshot_and_compiles(model):
     assert snap["serving/admit_bucket/count"] == 3.0
     assert snap["serving/kv_cells_capacity"] == 2 * 64
     assert snap["serving/active_slots"] == 0.0  # all retired by now
-    assert math.isfinite(snap["decode/mfu"]) and snap["decode/mfu"] > 0
-    assert snap["decode/tokens_per_s"] > 0
+    # every admission waited a finite time; prompts 4..6 pad to bucket 16
+    assert snap["serving/queue_wait_s/count"] == 3.0
+    assert math.isfinite(snap["serving/queue_wait_s/sum"])
+    assert snap["serving/queue_wait_s/sum"] >= 0
+    assert snap["serving/prefill_tokens"] == 4 + 5 + 6
+    assert snap["serving/prefill_launched_tokens"] == 3 * 16
 
 
 def test_paged_engine_zero_compile_overhead_with_full_telemetry(model):
@@ -322,7 +307,11 @@ def test_paged_engine_zero_compile_overhead_with_full_telemetry(model):
     assert snap["kv_pool/used_pages"] == 0.0  # everything freed on retire
     assert snap["serving/admit_bucket/count"] == snap["serving/admissions"]
     assert snap["serving/admissions"] == 4 + eng.preemptions  # re-admits
-    assert math.isfinite(snap["decode/mfu"]) and snap["decode/mfu"] > 0
+    assert snap["serving/queue_wait_s/count"] == snap["serving/admissions"]
+    assert math.isfinite(snap["serving/queue_wait_s/sum"])
+    # real feed tokens never exceed what the launches padded them to
+    assert 0 < snap["serving/prefill_tokens"] <= snap[
+        "serving/prefill_launched_tokens"]
 
     events = validate_trace(tracer.to_json())  # raises on schema violation
     # every request track carries the full lifecycle span chain
@@ -333,8 +322,9 @@ def test_paged_engine_zero_compile_overhead_with_full_telemetry(model):
     preempted = {e["args"]["rid"] for e in events if e["name"] == "preempt"}
     resumed = {e["args"]["rid"] for e in events if e["name"] == "resume"}
     assert preempted and preempted == resumed
-    # the engine track saw decode ticks and resident-counter samples
-    assert any(e["name"] == "decode_tick" and e["ph"] == "X" for e in events)
+    # the engine track saw decode spans and resident-counter samples
+    assert any(e["name"] == "engine.decode" and e["ph"] == "X" for e in events)
+    assert not any(e["name"] == "decode_tick" for e in events)
     assert any(e["ph"] == "C" and e["name"] == "resident" for e in events)
 
 
@@ -359,6 +349,177 @@ def test_train_step_jaxpr_unchanged_by_telemetry():
         eff.step(0.01)
     instrumented = str(jax.make_jaxpr(step)(params, opt, batch))
     assert plain == instrumented
+
+
+# ---------------------------------------------------------------------------
+# The bridge onto the jax.profiler trace
+# ---------------------------------------------------------------------------
+
+
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation and records its use."""
+
+    made: list = []
+
+    def __init__(self, name, **kwargs):
+        self.name, self.meta = name, dict(kwargs)
+        _FakeAnnotation.made.append(self)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **kwargs):
+        self.meta.update(kwargs)
+
+
+class _NoAnnotation:
+    def __init__(self, *a, **kw):
+        raise AssertionError("a profiler annotation was built")
+
+
+def test_span_forwards_numeric_args_to_the_profiler(monkeypatch):
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
+    _FakeAnnotation.made = []
+    tr = TraceRecorder(process="unit")
+    with tr.span("outer", args={"n": 3, "share": 0.5, "ids": [1, 2],
+                                "label": "x"}) as args:
+        args["late"] = 7  # known only once the work is done
+    with tr.span("structure", profile=False):
+        pass
+    (ann,) = _FakeAnnotation.made  # profile=False built none
+    assert ann.name == "repro.outer"
+    assert ann.meta == {"n": 3, "share": 0.5, "late": 7}
+    outer = next(e for e in tr.events if e.get("name") == "outer")
+    assert outer["args"] == {"n": 3, "share": 0.5, "ids": [1, 2],
+                             "label": "x", "late": 7}
+    assert any(e.get("name") == "structure" for e in tr.events)
+
+
+def _profiled_host_events(tmp_path, fn, prefix="repro."):
+    """Run ``fn`` under a jax.profiler session; the host events whose name
+    starts with ``prefix``, as (name, start_ns, end_ns, stats)."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                            "*.xplane.pb"))
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+             dict(ev.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith(prefix)]
+
+
+ENGINE_SPANS = {  # span -> (parent span, its args)
+    "engine.tick": (None, {"live", "queued"}),
+    "engine.schedule": ("engine.tick", {"picked"}),
+    "engine.admit": ("engine.tick",
+                     {"n", "width", "bucket", "tokens", "launched"}),
+    "engine.decode": ("engine.tick", {"live"}),
+    "engine.decode.dispatch": ("engine.decode", set()),
+    "engine.decode.wait": ("engine.decode", set()),
+    "engine.bookkeep": ("engine.tick", {"retired"}),
+}
+
+
+def test_engine_spans_reach_the_profiler(model, tmp_path):
+    """Every engine span is on the profiler's host plane as
+    ``repro.<name>``, as often as in the recorder's JSON, nested in its
+    parent, with the same args, all plain numbers."""
+    cfg, params = model
+    tracer = TraceRecorder(process="test-profile")
+    eng = PagedServingEngine(cfg, params, ATTN, max_batch=4, num_pages=32,
+                             page_size=4, pages_per_seq_max=8, prompt_pad=16,
+                             tracer=tracer)
+    for i, n in enumerate((5, 9, 20, 3, 11)):
+        eng.submit(Request(rid=i, prompt=[2 + i] * n, max_new_tokens=6))
+    got = _profiled_host_events(tmp_path, lambda: eng.run(max_ticks=100))
+    assert sorted(eng.finished) == list(range(5))
+    recorded = [e for e in tracer.to_json()["traceEvents"]
+                if e["ph"] == "X" and e["name"].startswith("engine.")]
+    assert {e["name"] for e in recorded} == set(ENGINE_SPANS)
+    for name, (parent, keys) in ENGINE_SPANS.items():
+        mine = [e for e in got if e[0] == "repro." + name]
+        theirs = [e for e in recorded if e["name"] == name]
+        assert len(mine) == len(theirs) > 0, name
+        key = lambda a: json.dumps(a, sort_keys=True)
+        assert sorted(map(key, (m[3] for m in mine))) == sorted(
+            key(e.get("args", {})) for e in theirs), name
+        for _, a, b, stats in mine:
+            assert set(stats) == keys, (name, stats)
+            assert all(type(v) in (int, float) for v in stats.values())
+            if parent is not None:
+                assert any(p[1] <= a and b <= p[2] for p in got
+                           if p[0] == "repro." + parent), name
+    for *_, st in (e for e in got if e[0] == "repro.engine.admit"):
+        assert st["launched"] == st["width"] * st["bucket"]
+        assert 0 < st["tokens"] <= st["launched"] and st["n"] <= st["width"]
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "fixed"])
+def test_engine_without_tracer_builds_no_annotation(model, monkeypatch,
+                                                    paged):
+    cfg, params = model
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _NoAnnotation)
+
+    def serve(tracer):
+        if paged:
+            eng = PagedServingEngine(cfg, params, ATTN, max_batch=2,
+                                     num_pages=16, page_size=4,
+                                     pages_per_seq_max=8, prompt_pad=16,
+                                     tracer=tracer)
+        else:
+            eng = ServingEngine(cfg, params, ATTN, max_batch=2, cache_size=32,
+                                prompt_pad=16, tracer=tracer)
+        for i in range(3):
+            eng.submit(Request(rid=i, prompt=[3 + i] * 5, max_new_tokens=3))
+        return eng.run(max_ticks=50)
+
+    assert sorted(serve(None)) == [0, 1, 2]
+    with pytest.raises(AssertionError, match="annotation was built"):
+        serve(TraceRecorder(process="on"))  # the same path, traced, does
+
+
+def test_jit_trace_time_spans_stay_off_the_profiler(monkeypatch):
+    """The ring schedule records its structure while JAX traces the step:
+    those spans stay in the recorder and build no profiler annotation."""
+    from repro.distributed import ring_attention as ra
+    from repro.distributed import ring_schedule as rs
+    from repro.obs import set_default_recorder
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _NoAnnotation)
+    spec = MaskSpec(causal=True)
+    meta = ra._RingMeta(spec=spec, layout=rs.make_layout(512, 4, spec),
+                        mesh=None, axis="model", batch_axes=None,
+                        impl="flash_xla", block_q=64, block_kv=64, scale=None,
+                        interpret=None, schedule=None, bwd=None,
+                        num_q_bands=None, kv_splits=None)
+
+    def f(k):
+        ra._record_ring_pass(meta, k, backward=False)
+        return k * 2
+
+    rec = TraceRecorder(process="ring")
+    set_default_recorder(rec)
+    try:
+        jax.jit(f)(jnp.zeros((1, 128, 2, 32), jnp.float32)).block_until_ready()
+    finally:
+        set_default_recorder(None)
+    names = [e["name"] for e in rec.events if e["ph"] == "X"]
+    assert "ring_fwd" in names and "ring_fwd_step0" in names
 
 
 # ---------------------------------------------------------------------------
