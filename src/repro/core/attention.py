@@ -177,6 +177,24 @@ def decode_attention(
     )[0]
 
 
+def paged_decode_splits(cfg: AttentionConfig, n_pages: int, page_size: int,
+                        heads: int, head_dim: int, dtype) -> int:
+    """Split-KV fan-out of the paged decode over ``n_pages`` logical pages:
+    ``cfg.decode_splits``, else resolved (and counted) by
+    ``autotune.resolve_decode_splits`` on the logical capacity."""
+    if cfg.decode_splits is not None:
+        from repro.obs.metrics import count_knob
+
+        count_knob(f"flash_decode_paged{page_size}", "explicit")
+        return cfg.decode_splits
+    from repro.kernels import autotune
+
+    return autotune.resolve_decode_splits(
+        n_pages * page_size, heads, head_dim, dtype,
+        page_size=page_size, use_tuned=cfg.use_tuned,
+    )
+
+
 def decode_attention_paged(
     q: jnp.ndarray,  # (B, 1, Hq, D)
     k_pages: jnp.ndarray,  # (Hkv, P, page_size, D) pool planes
@@ -197,20 +215,8 @@ def decode_attention_paged(
     resolves the split fan-out from the tuned cache keyed on the *logical*
     capacity ``n_pages * page_size`` and the page size
     (kernels/autotune.resolve_decode_splits)."""
-    ps = k_pages.shape[2]
-    logical = block_table.shape[1] * ps
-    splits = cfg.decode_splits
-    if splits is None:
-        from repro.kernels import autotune
-
-        splits = autotune.resolve_decode_splits(
-            logical, q.shape[2], q.shape[3], q.dtype,
-            page_size=ps, use_tuned=cfg.use_tuned,
-        )
-    else:
-        from repro.obs.metrics import count_knob
-
-        count_knob(f"flash_decode_paged{ps}", "explicit")
+    splits = paged_decode_splits(cfg, block_table.shape[1], k_pages.shape[2],
+                                 q.shape[2], q.shape[3], q.dtype)
     if cfg.impl == "flash_pallas":
         from repro.kernels.ops import flash_decode_paged_pallas
 

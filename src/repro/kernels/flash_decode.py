@@ -22,6 +22,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -165,101 +166,211 @@ def flash_decode_kernel(
 # Paged (block-table) split-KV decode
 # ---------------------------------------------------------------------------
 
+# Tokens one grid step of the paged decode gathers (per KV head): enough
+# pages that a step's fixed cost is small against its DMA, few enough that
+# two buffers of K and V for every KV head stay far inside scoped VMEM
+# (2 x 2 x 8 heads x 512 x 128 x bf16 = 4 MiB at qwen3-8b's widths).
+BLOCK_TOKENS = 512
+
+
+def paged_decode_geometry(n_pages: int, ps: int, num_splits: int):
+    """Grid geometry of :func:`flash_decode_paged_kernel` for a block table
+    of ``n_pages`` logical pages of ``ps`` tokens -> ``(ns, nb, ppb)``:
+    ``ns`` KV splits of ``nb`` blocks of ``ppb`` logical pages.
+
+    A split's ``ceil(n_pages / num_splits)`` pages are tiled by the fewest
+    blocks of at most ``BLOCK_TOKENS`` tokens, sized evenly so the tiling
+    pads the least; never more than a split's pages, so one page per split
+    keeps one page per block."""
+    ns = max(1, min(num_splits, n_pages))
+    pp = -(-n_pages // ns)
+    nb = -(-pp // max(1, BLOCK_TOKENS // ps))
+    ppb = -(-pp // nb)
+    ns = -(-n_pages // (nb * ppb))
+    return ns, nb, ppb
+
+
+def _visible(base, span, L, window: Optional[int], sink: int):
+    """Whether logical columns ``[base, base + span)`` hold one the query
+    sees: one below the length ``L`` and, with a window, inside it or in
+    the ``sink`` prefix (proof in DESIGN.md Section 5.1). Plain operators,
+    so the kernel applies it to SMEM scalars and the host to numpy arrays."""
+    vis = base < L
+    if window is not None:
+        in_win = base + span > L - window
+        if sink:
+            in_win = in_win | (base < sink)
+        vis = vis & in_win
+    return vis
+
+
+def _block_pages(L, page0, ps: int, ppb: int, xp=jnp):
+    """Pages of the block starting at logical page ``page0`` that hold
+    cached tokens (the ones the kernel copies): below ``ceil(L / ps)``."""
+    return xp.clip((L + ps - 1) // ps - page0, 0, ppb)
+
+
+def paged_decode_work(lengths, n_pages: int, ps: int, num_splits: int, *,
+                      window: Optional[int] = None, sink: int = 0) -> dict:
+    """What one :func:`flash_decode_paged_kernel` call does, counted on the
+    host from the kernel's own lengths (B,), geometry and visibility rule:
+    ``kv_pages`` pages copied, ``kv_blocks`` grid steps that copy and
+    compute, ``kv_blocks_launched`` grid steps in all."""
+    ns, nb, ppb = paged_decode_geometry(n_pages, ps, num_splits)
+    L = np.asarray(lengths, np.int64)[:, None]
+    page0 = np.arange(ns * nb)[None, :] * ppb
+    live = _visible(page0 * ps, ppb * ps, L, window, sink)
+    pages = _block_pages(L, page0, ps, ppb, np)
+    return {"kv_pages": int((pages * live).sum()),
+            "kv_blocks": int(live.sum()),
+            "kv_blocks_launched": int(L.shape[0] * ns * nb)}
+
 
 def _paged_decode_kernel(
-    tbl_ref,  # scalar prefetch: (B, n_pages) int32 block table (read by maps)
-    len_ref,  # scalar prefetch: (BHk,) int32 logical lengths
-    q_ref,    # (1, G, D)
-    k_ref,    # (1, 1, ps, D) -- the page the index map named
-    v_ref,
-    o_ref,    # (1, 1, G, D)
-    lse_ref,  # (1, G)
-    m_scr,    # VMEM (G, LANES) f32
-    l_scr,    # VMEM (G, LANES) f32
-    acc_scr,  # VMEM (G, D) f32
-    *, ps: int, pp: int, window: Optional[int], sink: int,
+    tbl_ref,  # scalar prefetch: (B, ns*nb*ppb) int32 block table
+    len_ref,  # scalar prefetch: (B,) int32 logical lengths
+    q_ref,    # (Hk, G, D) -- the slot's queries, every KV head
+    k_hbm,    # (Hk, P, ps, D) page planes, left in HBM
+    v_hbm,
+    o_ref,    # (Hk, G, D)
+    lse_ref,  # (Hk, 1, G)
+    k_buf,    # VMEM (2, Hk, ppb*ps, D): the block being computed + the next
+    v_buf,
+    sems,     # DMA (2, 2): [buffer, k|v]
+    walk,     # SMEM (2,) int32: [buffer of this block, a block was started]
+    m_scr,    # VMEM (Hk, G, LANES) f32
+    l_scr,    # VMEM (Hk, G, LANES) f32
+    acc_scr,  # VMEM (Hk, G, D) f32
+    *, ps: int, ppb: int, nb: int, ns: int, window: Optional[int], sink: int,
 ):
-    """One (split, page) step of the page-indirect decode.
+    """One (slot, split, block) step of the page-indirect decode.
 
-    The sequential ``p`` axis walks the split's pages with flash_fwd-style
-    online-softmax scratch. A page is *skipped entirely* (``pl.when``) when
-    the scalar arithmetic on (L, base, window, sink) proves every column
-    masked -- so a free/finished slot (L == 0, all-null table row) issues
-    zero compute, and the per-page update for an *active* page is
+    The grid walks slots, then splits, then blocks, in order. A block is
+    ``ppb`` logical pages; a live one (``_visible``) gathers its cached
+    pages for all KV heads by explicit DMA, one descriptor per page and
+    tensor, and runs the online-softmax update per head. Before computing,
+    it starts the copy of the next live block of the walk (this slot's or
+    a later one's) into the other buffer, so the copies of one step
+    overlap the compute of the one before. A dead block copies and
+    computes nothing. With one page per block the per-head update is
     op-for-op the contiguous kernel's chunk math (bitwise-equal partials
-    whenever one split == one page -- tests/test_paged.py pins it).
+    when one split == one page -- tests/test_paged.py pins it).
     """
-    del tbl_ref  # index maps read it; the body only needs lengths
-    bh = pl.program_id(0)
-    c = pl.program_id(1)
-    p = pl.program_id(2)
-    L = len_ref[bh]
-    base = (c * pp + p) * ps  # logical position of this page's column 0
+    b, c, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    B = pl.num_programs(0)
+    Hk, G, D = q_ref.shape
+    bk = ppb * ps
+    per_slot = ns * nb  # blocks a slot's table row holds
+    t = (b * ns + c) * nb + j  # position in the walk
+    T = B * per_slot
 
-    @pl.when(p == 0)
+    def live(u):  # is walk step u a live block? (u < T)
+        s = jnp.minimum(u // per_slot, B - 1)
+        return _visible((u % per_slot) * bk, bk, len_ref[s], window, sink)
+
+    def block_dma(u, buf, act: str):
+        """``act`` ("start" or "wait") the copies of walk step u's cached
+        pages into buffer ``buf``: one descriptor per page and tensor, all
+        KV heads at once."""
+        s, page0 = u // per_slot, (u % per_slot) * ppb
+
+        @pl.loop(0, _block_pages(len_ref[s], page0, ps, ppb))
+        def _(i):
+            phys = tbl_ref[s, page0 + i]
+            rows = pl.ds(pl.multiple_of(i * ps, ps), ps)
+            for x, (src, dst) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                getattr(pltpu.make_async_copy(src.at[:, phys],
+                                              dst.at[buf, :, rows],
+                                              sems.at[buf, x]), act)()
+
+    @pl.when(t == 0)
+    def _walk_init():
+        walk[0] = 0
+        walk[1] = 0
+
+    @pl.when(j == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # Page-level visibility, purely from scalars: an active page always has
-    # >= 1 valid column (proof in DESIGN.md Section 5.1), so the in-page
-    # masking below never needs the contiguous kernel's any_valid guard --
-    # fully-masked pages (which would corrupt l with exp(0) garbage) are
-    # exactly the skipped ones.
-    active = base < L
-    if window is not None:
-        in_win = base + ps > L - window
-        if sink:
-            in_win = in_win | (base < sink)
-        active = active & in_win
+    L = len_ref[b]
+    base = (c * nb + j) * bk  # logical position of the block's column 0
 
-    @pl.when(active)
+    # An active block always has >= 1 valid column (DESIGN.md Section 5.1),
+    # so the in-block masking below never needs the contiguous kernel's
+    # any_valid guard -- fully-masked blocks are exactly the skipped ones.
+    @pl.when(live(t))
     def _step():
-        q = q_ref[0]      # (G, D)
-        k = k_ref[0, 0]   # (ps, D)
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + base
+        buf = walk[0]
+
+        @pl.when(walk[1] == 0)
+        def _first():  # nothing before it in the walk prefetched it
+            block_dma(t, buf, "start")
+
+        nxt = jax.lax.while_loop(lambda u: (u < T) & ~live(u),
+                                 lambda u: u + 1, t + 1)
+
+        @pl.when(nxt < T)
+        def _prefetch():
+            block_dma(nxt, 1 - buf, "start")
+
+        walk[0] = 1 - buf
+        walk[1] = 1
+        block_dma(t, buf, "wait")
+
+        cols = jax.lax.broadcasted_iota(jnp.int32, (G, bk), 1) + base
         valid = cols < L
         if window is not None:
             in_win = cols >= L - window
             if sink:
                 in_win = in_win | (cols < sink)
             valid = valid & in_win
-        s = jnp.where(valid, s, DEFAULT_MASK_VALUE)
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # First touched page: m_prev = -inf -> alpha = 0, and 0 * prev + x
-        # leaves x bitwise intact -- the single-page path IS the contiguous
-        # kernel's math.
-        alpha = jnp.where(jnp.isneginf(m_prev), 0.0, jnp.exp(m_prev - m_new))
-        pexp = jnp.exp(s - m_new)
-        l_new = l_scr[:, :1] * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_scr[...] = acc_scr[...] * alpha + pv
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        # Rows past the length hold stale VMEM (pages not copied) or stale
+        # pool rows: their p is 0, but 0 * NaN is NaN, so zero them.
+        cached = jax.lax.broadcasted_iota(jnp.int32, (bk, D), 0) + base < L
+        for h in range(Hk):
+            q = q_ref[h]  # (G, D)
+            k = k_buf[buf, h]  # (bk, D)
+            v = jnp.where(cached, v_buf[buf, h], 0)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            s = jnp.where(valid, s, DEFAULT_MASK_VALUE)
+            m_prev = m_scr[h, :, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            # First touched block: m_prev = -inf -> alpha = 0, and
+            # 0 * prev + x leaves x bitwise intact -- the single-page path IS
+            # the contiguous kernel's math.
+            alpha = jnp.where(jnp.isneginf(m_prev), 0.0,
+                              jnp.exp(m_prev - m_new))
+            pexp = jnp.exp(s - m_new)
+            l_new = l_scr[h, :, :1] * alpha + jnp.sum(pexp, axis=-1,
+                                                      keepdims=True)
+            pv = jax.lax.dot_general(
+                pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            acc_scr[h] = acc_scr[h] * alpha + pv
+            m_scr[h] = jnp.broadcast_to(m_new, (G, LANES))
+            l_scr[h] = jnp.broadcast_to(l_new, (G, LANES))
 
-    @pl.when(p == pp - 1)
+    @pl.when(j == nb - 1)
     def _finalize():
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = acc_scr[...] / l_safe
-        lse = jnp.where(l == 0.0, -jnp.inf, m_scr[:, :1] + jnp.log(l_safe))
-        lse_ref[0] = lse[:, 0]  # (G,) lane-major
+        for h in range(Hk):
+            l = l_scr[h, :, :1]
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            o_ref[h] = acc_scr[h] / l_safe
+            lse = jnp.where(l == 0.0, -jnp.inf, m_scr[h, :, :1] + jnp.log(l_safe))
+            lse_ref[h, 0] = lse[:, 0]  # (G,) lane-major
 
 
 def flash_decode_paged_kernel(
-    q: jnp.ndarray,  # (BHk, G, D) pre-scaled
+    q: jnp.ndarray,  # (B, Hk, G, D) pre-scaled
     k_pages: jnp.ndarray,  # (Hk, P, ps, D) physical page planes
     v_pages: jnp.ndarray,
-    lengths: jnp.ndarray,  # (BHk,) int32 logical lengths
+    lengths: jnp.ndarray,  # (B,) int32 logical lengths
     block_table: jnp.ndarray,  # (B, n_pages) int32 logical -> physical page
     *,
     num_splits: int = 8,
@@ -269,78 +380,74 @@ def flash_decode_paged_kernel(
 ):
     """Split-KV decode that never sees a contiguous cache.
 
-    Each KV split covers ``pp = ceil(n_pages / num_splits)`` *logical*
-    pages; the k/v index maps dereference the prefetched block table
-    (``PrefetchScalarGridSpec`` -- the same scalar-prefetch contract as
-    kernels/schedule.py) so the DMA engine fetches physical page
-    ``tbl[b, c*pp + p]`` directly from the pool plane. Physical page order
-    is irrelevant to the math (shuffle-invariance is tested bitwise).
-    Table entries past a sequence's live pages must point at the null page
-    (0): their DMA is a cheap repeat and their compute is skipped.
+    Grid ``(B, ns, nb)`` (``paged_decode_geometry``): each KV split covers
+    ``nb`` blocks of ``ppb`` *logical* pages; a step copies the physical
+    pages ``tbl[b, page]`` its block holds for every KV head straight from
+    the pool planes (scalar-prefetched table and lengths, the same contract
+    as kernels/schedule.py), double-buffered across steps. Physical page
+    order is irrelevant to the math (shuffle-invariance is tested
+    bitwise). Table entries past a sequence's live pages should name the
+    null page (0); they are never copied, whatever they hold.
 
-    Returns per-split partials ``(o_parts (BHk, ns, G, D) f32,
-    lse_parts (BHk, ns, 1, G) f32)`` for ``combine_lse_outputs``.
+    Returns per-split partials ``(o_parts (B, ns, Hk, G, D) f32,
+    lse_parts (B, ns, Hk, 1, G) f32)`` for ``combine_lse_outputs``.
     """
     interpret = resolve_interpret(interpret)
-    BHk, G, D = q.shape
-    Hk, _, ps, _ = k_pages.shape
-    B, n_pages = block_table.shape
-    assert BHk == B * Hk, (BHk, B, Hk)
-    ns = max(1, min(num_splits, n_pages))
-    pp = -(-n_pages // ns)  # logical pages per split
-    ns = -(-n_pages // pp)
-    pad = ns * pp - n_pages
+    B, Hk, G, D = q.shape
+    _, _, ps, _ = k_pages.shape
+    n_pages = block_table.shape[1]
+    ns, nb, ppb = paged_decode_geometry(n_pages, ps, num_splits)
+    pad = ns * nb * ppb - n_pages
     tbl = block_table.astype(jnp.int32)
     if pad:
         # Padded table columns are logical positions >= n_pages*ps >= L:
-        # never active; the null page keeps their DMA well-defined.
+        # never live, never copied.
         tbl = jnp.pad(tbl, ((0, 0), (0, pad)))
     kernel = functools.partial(
-        _paged_decode_kernel, ps=ps, pp=pp, window=window, sink=sink,
+        _paged_decode_kernel, ps=ps, ppb=ppb, nb=nb, ns=ns, window=window,
+        sink=sink,
     )
+    # Capacity-based: the live work depends on the lengths, unknown here.
     cost = pl.CostEstimate(
-        flops=2 * BHk * G * n_pages * ps * D * 2,
+        flops=2 * B * Hk * G * n_pages * ps * D * 2,
         bytes_accessed=2 * B * n_pages * ps * D * k_pages.dtype.itemsize
         + 2 * q.size * q.dtype.itemsize,
-        transcendentals=BHk * G * n_pages * ps,
+        transcendentals=B * Hk * G * n_pages * ps,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block table + lengths
-        grid=(BHk, ns, pp),
+        grid=(B, ns, nb),
         in_specs=[
-            pl.BlockSpec((1, G, D), lambda bh, c, p, tbl_, len_: (bh, 0, 0)),
-            pl.BlockSpec(
-                (1, 1, ps, D),
-                lambda bh, c, p, tbl_, len_, h=Hk, n=pp: (
-                    bh % h, tbl_[bh // h, c * n + p], 0, 0
-                ),
-            ),
-            pl.BlockSpec(
-                (1, 1, ps, D),
-                lambda bh, c, p, tbl_, len_, h=Hk, n=pp: (
-                    bh % h, tbl_[bh // h, c * n + p], 0, 0
-                ),
-            ),
+            pl.BlockSpec((None, Hk, G, D), lambda b, c, j, *_: (b, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda bh, c, p, *_: (bh, c, 0, 0)),
-            _lse_spec(G, lambda bh, c, p, *_: (bh, c, 0, 0)),
+            pl.BlockSpec((None, None, Hk, G, D),
+                         lambda b, c, j, *_: (b, c, 0, 0, 0)),
+            pl.BlockSpec((None, None, Hk, 1, G),
+                         lambda b, c, j, *_: (b, c, 0, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((G, LANES), jnp.float32),
-            pltpu.VMEM((G, LANES), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
+            pltpu.VMEM((2, Hk, ppb * ps, D), k_pages.dtype),
+            pltpu.VMEM((2, Hk, ppb * ps, D), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32),
+            pltpu.VMEM((Hk, G, LANES), jnp.float32),
+            pltpu.VMEM((Hk, G, LANES), jnp.float32),
+            pltpu.VMEM((Hk, G, D), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((BHk, ns, G, D), jnp.float32),
-            jax.ShapeDtypeStruct((BHk, ns, 1, G), jnp.float32),
+            jax.ShapeDtypeStruct((B, ns, Hk, G, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, ns, Hk, 1, G), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # The walk prefetches across slots and splits: sequential.
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
         cost_estimate=cost,
         interpret=interpret,

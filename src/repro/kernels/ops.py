@@ -742,14 +742,16 @@ def flash_decode_paged_pallas(
     if scale is None:
         scale = 1.0 / math.sqrt(D)
     qh = (q.astype(jnp.float32) * scale).astype(q.dtype)
-    qh = qh.reshape(B, Hk, G, D).reshape(B * Hk, G, D)
-    lens = jnp.repeat(cache_length.astype(jnp.int32), Hk)
+    qh = qh.reshape(B, Hk, G, D)
     o_parts, lse_parts = _dec.flash_decode_paged_kernel(
-        qh, k_pages, v_pages, lens, block_table, num_splits=num_splits,
-        window=window, sink=sink, interpret=interpret,
+        qh, k_pages, v_pages, cache_length.astype(jnp.int32), block_table,
+        num_splits=num_splits, window=window, sink=sink, interpret=interpret,
     )
+    # (B, ns, Hk, ...) -> (ns, B*Hk, ...): the contiguous path's merge.
+    ns = o_parts.shape[1]
     o, lse = combine_lse_outputs(
-        jnp.moveaxis(o_parts, 1, 0), jnp.moveaxis(lse_parts[:, :, 0], 1, 0)
+        jnp.moveaxis(o_parts, 1, 0).reshape(ns, B * Hk, G, D),
+        jnp.moveaxis(lse_parts[:, :, :, 0], 1, 0).reshape(ns, B * Hk, G),
     )
     return (
         o.reshape(B, 1, Hq, D).astype(q.dtype),

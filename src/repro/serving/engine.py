@@ -31,7 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.core.attention import AttentionConfig
+from repro.core.attention import AttentionConfig, paged_decode_splits
+from repro.kernels.flash_decode import paged_decode_work
 from repro.launch.steps import (
     build_paged_admit_step,
     build_paged_serve_step,
@@ -97,7 +98,8 @@ class _EngineTelemetry:
     (DESIGN.md §9.2): ``engine.tick`` {live, queued} holds
     ``engine.schedule`` {picked}, one ``engine.admit`` {n, width,
     bucket, tokens, launched} per prefill launch, ``engine.decode``
-    {live} with ``.dispatch`` and ``.wait`` children, and
+    {live; paged on the Pallas kernel also kv_pages, kv_blocks,
+    kv_blocks_launched} with ``.dispatch`` and ``.wait`` children, and
     ``engine.bookkeep`` {retired}. Without one, no span is opened.
     """
 
@@ -165,11 +167,12 @@ class _EngineTelemetry:
         return self._span("engine.admit", n=n, width=width, bucket=bucket,
                           tokens=tokens, launched=launched)
 
-    def _decode(self, live: int, next_token, *tables):
+    def _decode(self, live: int, next_token, *tables, **work):
         """The decode call, then its tokens on the host: the
-        ``engine.decode`` span with ``.dispatch`` / ``.wait`` children.
-        Returns the device tokens and their host copy."""
-        with self._span("engine.decode", live=live):
+        ``engine.decode`` span (``live`` and ``work`` as args) with
+        ``.dispatch`` / ``.wait`` children. Returns the device tokens and
+        their host copy."""
+        with self._span("engine.decode", live=live, **work):
             with self._span("engine.decode.dispatch"):
                 tok, self.caches = self._step(
                     self.params, next_token, self.caches, *tables
@@ -472,6 +475,7 @@ class PagedServingEngine(_EngineTelemetry):
         self._obs_init(registry, tracer)
         self.pool.register_metrics(self.obs)
         self._c_page_oom = self.obs.counter("serving/page_oom")
+        self._splits: Optional[int] = None  # resolved on the first traced tick
         self.obs.gauge_fn("serving/preemptions", lambda: float(self.preemptions))
         # fraction of *allocated* page cells holding real KV
         self.obs.gauge_fn(
@@ -490,7 +494,7 @@ class PagedServingEngine(_EngineTelemetry):
 
     def active_kv_cells(self) -> int:
         """KV cells the decode step touches: live rows' allocated pages
-        only -- the page-level ``pl.when`` skip reads nothing else."""
+        only -- the kernel copies nothing else."""
         return int(sum(-(-int(l) // self.ps) * self.ps
                        for l in self.cache_len if int(l) > 0))
 
@@ -660,6 +664,21 @@ class PagedServingEngine(_EngineTelemetry):
                     continue
                 self.table[slot, len(self.pool.pages_of(req.rid)) - 1] = page
 
+    def _decode_work(self) -> Dict[str, int]:
+        """What this tick's paged decode kernel does in one layer without a
+        window (``flash_decode.paged_decode_work``: ``kv_pages``,
+        ``kv_blocks``, ``kv_blocks_launched``), counted from the lengths the
+        step hands it; nothing without a tracer or off the Pallas kernel."""
+        if self.tracer is None or self.attn.impl != "flash_pallas":
+            return {}
+        if self._splits is None:
+            self._splits = paged_decode_splits(
+                self.attn, self.n_max, self.ps, self.cfg.num_heads,
+                self.cfg.head_dim, self.cfg.dtype)
+        # the step attends over the new token too; empty slots over nothing
+        lens = np.where(self.cache_len > 0, self.cache_len + 1, 0)
+        return paged_decode_work(lens, self.n_max, self.ps, self._splits)
+
     # -------------------------------------------------------------- tick
     def tick(self):
         live = sum(s is not None for s in self.slots)
@@ -673,6 +692,7 @@ class PagedServingEngine(_EngineTelemetry):
                 jnp.asarray(self.next_token),
                 jnp.asarray(self.table),
                 jnp.asarray(self.cache_len),
+                **self._decode_work(),
             )
             self._note_decode_tick(live)
             with self._span("engine.bookkeep") as span:

@@ -4,9 +4,9 @@ The pool owns ``num_pages`` physical pages of ``page_size`` token slots
 each, shared by every layer (one block table per sequence; layer caches are
 parallel planes indexed by the same physical page ids -- vLLM's design).
 Page 0 is reserved as the *null page*: block-table entries of inactive
-slots and the not-yet-written tail all point at it, so the paged decode
-kernel's index map always names a real page while its compute skips the
-masked ones (kernels/flash_decode._paged_decode_kernel).
+slots and the not-yet-written tail all point at it, so every table entry
+names a real page; the paged decode kernel copies only the pages below a
+slot's length (kernels/flash_decode._paged_decode_kernel).
 
 Allocation is host-side and O(1) per page (a free-list stack); the device
 never sees the pool -- only the int32 block table the engine pushes each

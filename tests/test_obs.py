@@ -469,6 +469,54 @@ def test_engine_spans_reach_the_profiler(model, tmp_path):
         assert 0 < st["tokens"] <= st["launched"] and st["n"] <= st["width"]
 
 
+def test_paged_engine_decode_span_counts_kernel_work(model):
+    """On the Pallas kernel each ``engine.decode`` span carries that tick's
+    kernel work -- pages copied, live and launched grid steps -- equal to a
+    hand count from the lengths the decode step got, on the grid the kernel
+    launches."""
+    from repro.kernels.flash_decode import paged_decode_geometry
+    from repro.kernels.ops import flash_decode_paged_pallas
+
+    cfg, params = model
+    attn = AttentionConfig(impl="flash_pallas", decode_splits=2)
+    tracer = TraceRecorder(process="test-kv-work")
+    eng = PagedServingEngine(cfg, params, attn, max_batch=4, num_pages=32,
+                             page_size=4, pages_per_seq_max=8, prompt_pad=16,
+                             tracer=tracer)
+    step, seen = eng._step, []
+
+    def spy(params, token, caches, table, cache_len):
+        seen.append(np.array(cache_len))  # a copy: the host array moves on
+        return step(params, token, caches, table, cache_len)
+
+    eng._step = spy
+    for i, n in enumerate((5, 9, 20, 3)):
+        eng.submit(Request(rid=i, prompt=[2 + i] * n, max_new_tokens=6))
+    eng.run(max_ticks=100)
+    assert sorted(eng.finished) == list(range(4))
+    spans = [e["args"] for e in tracer.to_json()["traceEvents"]
+             if e["ph"] == "X" and e["name"] == "engine.decode"]
+    assert len(spans) == len(seen) > 0
+    # 8 pages of 4 tokens in 2 splits: one block of 4 pages a split
+    assert paged_decode_geometry(8, 4, 2) == (2, 1, 4)
+    for args, cache_len in zip(spans, seen):
+        pages = -(-np.where(cache_len > 0, cache_len + 1, 0) // 4)
+        assert args["kv_pages"] == pages.sum()  # each cached page once
+        assert args["kv_blocks"] == (-(-pages // 4)).sum()
+        assert args["kv_blocks_launched"] == 4 * 2
+    assert any(0 < a["kv_blocks"] < a["kv_blocks_launched"] for a in spans)
+
+    hd = cfg.head_dim
+    closed = jax.make_jaxpr(lambda q, kp, vp, lens, tbl: (
+        flash_decode_paged_pallas(q, kp, vp, lens, tbl, num_splits=2)))(
+        jnp.zeros((4, 1, cfg.num_heads, hd)),
+        jnp.zeros((cfg.num_kv_heads, 32, 4, hd)),
+        jnp.zeros((cfg.num_kv_heads, 32, 4, hd)),
+        jnp.zeros((4,), jnp.int32), jnp.zeros((4, 8), jnp.int32))
+    assert [e.params["grid_mapping"].grid for e in closed.jaxpr.eqns
+            if e.primitive.name == "pallas_call"] == [(4, 2, 1)]
+
+
 @pytest.mark.parametrize("paged", [True, False], ids=["paged", "fixed"])
 def test_engine_without_tracer_builds_no_annotation(model, monkeypatch,
                                                     paged):

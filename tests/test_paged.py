@@ -11,6 +11,7 @@ import pytest
 from repro.configs import registry
 from repro.core.attention import AttentionConfig
 from repro.core.decode import flash_decode_paged
+from repro.kernels.flash_decode import paged_decode_geometry, paged_decode_work
 from repro.kernels.ops import flash_decode_pallas, flash_decode_paged_pallas
 from repro.models import lm
 from repro.serving.engine import PagedServingEngine, Request, ServingEngine
@@ -64,19 +65,29 @@ def test_pool_pages_for_tokens():
 
 B, S, PS, Hq, Hk, D = 3, 128, 16, 8, 2, 64
 NPAGES = S // PS
+# Several blocks a split (kernels/flash_decode.paged_decode_geometry): 70
+# pages tile as 3 blocks of 24 at one split and 2 x 2 blocks of 18 at two,
+# neither evenly. Lengths end on a block edge at one split (768) and at two
+# (288), mid-page in the last block (1100); the empty slot reads nothing.
+# At two splits 288 leaves split 1 dead; a window of 200 with a sink of 16
+# skips the middle block between the sink and the window at 1100.
+B_LONG, NPAGES_LONG = 4, 70
+LENS_LONG = (768, 1100, 288, 0)
+WINDOW_SINK = {"short": (32, 8), "long": (200, 16)}
 
 
 def _paginate(kc, vc, seed=0):
     """Contiguous (B,S,Hk,D) caches -> shuffled physical page planes
     (Hk,P,ps,D) + block table, page 0 reserved null."""
     kc, vc = np.asarray(kc), np.asarray(vc)
-    P = B * NPAGES + 1
+    batch, npages = kc.shape[0], kc.shape[1] // PS
+    P = batch * npages + 1
     perm = np.random.default_rng(seed).permutation(P - 1) + 1
-    table = perm.reshape(B, NPAGES).astype(np.int32)
+    table = perm.reshape(batch, npages).astype(np.int32)
     k_pages = np.zeros((Hk, P, PS, D), kc.dtype)
     v_pages = np.zeros((Hk, P, PS, D), vc.dtype)
-    for b in range(B):
-        for i in range(NPAGES):
+    for b in range(batch):
+        for i in range(npages):
             phys = table[b, i]
             k_pages[:, phys] = kc[b, i * PS : (i + 1) * PS].transpose(1, 0, 2)
             v_pages[:, phys] = vc[b, i * PS : (i + 1) * PS].transpose(1, 0, 2)
@@ -91,6 +102,24 @@ def kv():
     q = jax.random.normal(ks[2], (B, 1, Hq, D))
     lens = jnp.array([128, 97, 37], jnp.int32)  # full / prime / odd-page
     return q, kc, vc, lens
+
+
+@pytest.fixture(scope="module")
+def kv_long():
+    ks = jax.random.split(jax.random.PRNGKey(6), 3)
+    kc = jax.random.normal(ks[0], (B_LONG, NPAGES_LONG * PS, Hk, D))
+    vc = jax.random.normal(ks[1], (B_LONG, NPAGES_LONG * PS, Hk, D))
+    q = jax.random.normal(ks[2], (B_LONG, 1, Hq, D))
+    assert paged_decode_geometry(NPAGES_LONG, PS, 1) == (1, 3, 24)
+    assert paged_decode_geometry(NPAGES_LONG, PS, 2) == (2, 2, 18)
+    return q, kc, vc, jnp.array(LENS_LONG, jnp.int32)
+
+
+@pytest.fixture(params=["short", "long"])
+def geometry(request):
+    """(name, the kv fixture): one page a block, or several blocks a split."""
+    return request.param, request.getfixturevalue(
+        "kv" if request.param == "short" else "kv_long")
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -110,12 +139,13 @@ def test_paged_bitwise_parity_one_page_per_split(kv, dtype):
     np.testing.assert_array_equal(np.asarray(lse_p), np.asarray(lse_c))
 
 
-def test_paged_multi_page_splits_match(kv):
-    """pp > 1 (several pages walked sequentially per split) changes the
-    reduction order, so parity is allclose, not bitwise."""
-    q, kc, vc, lens = kv
+def test_paged_multi_page_splits_match(geometry):
+    """pp > 1 (several pages a split, gathered into blocks walked in order)
+    changes the reduction order, so parity is allclose, not bitwise."""
+    q, kc, vc, lens = geometry[1]
     k_pages, v_pages, table = _paginate(kc, vc)
-    o_c, lse_c = flash_decode_pallas(q, kc, vc, lens, num_splits=NPAGES)
+    o_c, lse_c = flash_decode_pallas(q, kc, vc, lens,
+                                     num_splits=table.shape[1])
     for splits in (1, 2, 4):
         o_p, lse_p = flash_decode_paged_pallas(
             q, k_pages, v_pages, lens, table, num_splits=splits
@@ -153,14 +183,18 @@ def test_paged_window_sink_bitwise(kv):
     np.testing.assert_array_equal(np.asarray(lse_p), np.asarray(lse_c))
 
 
-def test_paged_xla_fallback_matches(kv):
-    q, kc, vc, lens = kv
+@pytest.mark.parametrize("windowed", [False, True], ids=["full", "window_sink"])
+def test_paged_xla_fallback_matches(geometry, windowed):
+    name, (q, kc, vc, lens) = geometry
     k_pages, v_pages, table = _paginate(kc, vc)
+    window, sink = WINDOW_SINK[name] if windowed else (None, 0)
     o_p, lse_p = flash_decode_paged_pallas(
-        q, k_pages, v_pages, lens, table, num_splits=4
+        q, k_pages, v_pages, lens, table, num_splits=4, window=window,
+        sink=sink,
     )
     o_x, lse_x = flash_decode_paged(
-        q, k_pages, v_pages, lens, table, num_splits=4
+        q, k_pages, v_pages, lens, table, num_splits=4, window=window,
+        sink=sink,
     )
     np.testing.assert_allclose(o_p, o_x, atol=5e-6, rtol=1e-5)
     np.testing.assert_allclose(lse_p, lse_x, atol=1e-5, rtol=1e-5)
@@ -188,6 +222,70 @@ def test_paged_empty_slot_masked(kv):
     )
     np.testing.assert_allclose(o[0], o_ref[0], atol=5e-6, rtol=1e-5)
     np.testing.assert_allclose(o[2], o_ref[2], atol=5e-6, rtol=1e-5)
+
+
+@pytest.mark.parametrize("splits", [1, 2])
+def test_paged_nan_past_live_pages(geometry, splits):
+    """What lies past a slot's cached tokens never reaches the output, not
+    even as 0 * NaN: NaN in the null page, in every page a table names past
+    a slot's live pages, and in the rows past the length of its last live
+    page leaves (o, lse) bitwise equal to a clean pool's."""
+    q, kc, vc, lens = geometry[1]
+    k_pages, v_pages, table = _paginate(kc, vc)
+    lens_np = np.asarray(lens)
+    kp, vp = np.array(k_pages), np.array(v_pages)
+    dead = np.arange(table.shape[1])[None, :] >= -(-lens_np[:, None] // PS)
+    for pages in (kp, vp):
+        pages[:, 0] = np.nan
+        pages[:, np.asarray(table)[dead]] = np.nan
+        for b, n in enumerate(lens_np):
+            if n % PS:
+                pages[:, table[b, n // PS], n % PS:] = np.nan
+    clean = flash_decode_paged_pallas(q, k_pages, v_pages, lens, table,
+                                      num_splits=splits)
+    nulled = flash_decode_paged_pallas(q, kp, vp, lens, table.at[dead].set(0),
+                                       num_splits=splits)
+    poisoned = flash_decode_paged_pallas(q, kp, vp, lens, table,
+                                         num_splits=splits)
+    for got in (nulled, poisoned):
+        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(clean[0]))
+        np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(clean[1]))
+
+
+@pytest.mark.parametrize("window,sink", [(None, 0), (200, 16), (40, 0)])
+@pytest.mark.parametrize("n_pages,ps,splits", [(70, 16, 1), (70, 16, 2),
+                                               (289, 16, 8), (8, 16, 8)])
+def test_paged_decode_work_matches_page_count(n_pages, ps, splits, window,
+                                              sink):
+    """The block-level skip is the page-level one lifted: a block is live
+    exactly when one of its pages is (each page tested alone, DESIGN.md
+    Section 5.1), and it copies its pages below the length -- every cached
+    page once, without a window."""
+    ns, nb, ppb = paged_decode_geometry(n_pages, ps, splits)
+    assert ns * nb * ppb >= n_pages and ppb * ps <= max(512, ps)
+    lens = np.random.default_rng(n_pages + splits).integers(
+        0, n_pages * ps + 1, size=64)
+    lens[:3] = (0, 1, n_pages * ps)
+    work = paged_decode_work(lens, n_pages, ps, splits, window=window,
+                             sink=sink)
+    blocks = pages = 0
+    for L in lens:
+        for blk in range(ns * nb):
+            page_live = []
+            for p in range(blk * ppb, (blk + 1) * ppb):
+                cols = np.arange(p * ps, (p + 1) * ps)
+                seen = cols < L
+                if window is not None:
+                    seen &= (cols >= L - window) | (cols < sink)
+                page_live.append(seen.any())
+            if any(page_live):
+                blocks += 1
+                pages += sum(p * ps < L for p in range(blk * ppb,
+                                                       (blk + 1) * ppb))
+    assert work == {"kv_pages": pages, "kv_blocks": blocks,
+                    "kv_blocks_launched": len(lens) * ns * nb}
+    if window is None:
+        assert pages == sum(-(-int(L) // ps) for L in lens)
 
 
 # ---------------------------------------------------------------------------

@@ -153,10 +153,12 @@ def test_decode_compiles(one_chip):
              _shape((batch,), one_chip, jnp.int32))
 
 
-def test_paged_decode_compiles(one_chip):
-    batch, ps = 8, 16
-    n_pages = S // ps
-    pool = batch * n_pages + 1  # + the null page
+@pytest.mark.parametrize("batch,n_pages,pool", [
+    (8, S // 16, 8 * (S // 16) + 1),  # every slot full, + the null page
+    (32, 289, 4609),  # the chat cell's engine: 32 slots, 289-page table
+], ids=["full", "chat"])
+def test_paged_decode_compiles(one_chip, batch, n_pages, pool):
+    ps = 16
 
     def dec(q, kp, vp, lens, tbl):
         return ops.flash_decode_paged_pallas(q, kp, vp, lens, tbl,
