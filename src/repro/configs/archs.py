@@ -1,4 +1,4 @@
-"""The 10 assigned architectures, exact configs from the public sources
+"""The assigned architectures, exact configs from the public sources
 cited in the assignment. One ``ModelConfig`` each; see registry.py for
 lookup, shape applicability, and input specs.
 
@@ -10,7 +10,14 @@ parallelism); the rest shard heads.
 
 from __future__ import annotations
 
-from repro.configs.base import EncoderConfig, ModelConfig, MoEConfig, SSMConfig
+from repro.configs.base import (
+    EncoderConfig,
+    MLAConfig,
+    ModelConfig,
+    MoEConfig,
+    SSMConfig,
+    YarnConfig,
+)
 
 WHISPER_BASE = ModelConfig(
     # [arXiv:2212.04356] enc-dec; conv/mel frontend stubbed to frame embeddings.
@@ -205,6 +212,34 @@ HYMBA_1_5B = ModelConfig(
     max_seq_len=524_288,
 )
 
+DEEPSEEK_V2_LITE = ModelConfig(
+    # [hf:deepseek-ai/DeepSeek-V2-Lite config.json; arXiv:2405.04434 S2.1]
+    # MLA without q-LoRA, YaRN rope, 1 dense layer then 26 MoE layers of 64
+    # routed experts (softmax over all, greedy top-6, no renormalisation)
+    # plus 2 shared experts.
+    name="deepseek-v2-lite",
+    family="moe",
+    num_layers=27,
+    d_model=2048,
+    num_heads=16,
+    num_kv_heads=16,
+    head_dim=192,  # q/k width: qk_nope_head_dim + qk_rope_head_dim
+    d_ff=10_944,  # the leading dense layer's MLP
+    vocab_size=102_400,
+    moe=MoEConfig(num_experts=64, top_k=6, d_expert=1408, norm_topk=False,
+                  routed_scale=1.0, num_shared=2),
+    first_dense_layers=1,
+    mla=MLAConfig(kv_lora_rank=512, qk_nope_head_dim=128,
+                  qk_rope_head_dim=64, v_head_dim=128),
+    rope_theta=10_000.0,
+    rope_scaling=YarnConfig(factor=40.0, original_max_position=4096,
+                            beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                            mscale_all_dim=0.707),
+    norm_eps=1e-6,
+    attn_sharding="heads",
+    max_seq_len=163_840,
+)
+
 ALL = [
     WHISPER_BASE,
     GRANITE_MOE_1B,
@@ -216,4 +251,5 @@ ALL = [
     FALCON_MAMBA_7B,
     INTERNVL2_76B,
     HYMBA_1_5B,
+    DEEPSEEK_V2_LITE,
 ]

@@ -33,6 +33,54 @@ class MoEConfig:
     d_expert: int  # per-expert FFN hidden size
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # Routing as published. ``norm_topk``: the top-k weights are
+    # renormalised to sum to 1 (Mixtral: a softmax over the top-k logits);
+    # without it they are the full softmax's own probabilities (DeepSeek-V2:
+    # softmax over all experts, greedy top-k), times ``routed_scale``.
+    norm_topk: bool = True
+    routed_scale: float = 1.0
+    # Shared experts: one dense SwiGLU of width num_shared * d_expert that
+    # every token passes through beside its routed experts.
+    num_shared: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2 Section 2.1), without a
+    query LoRA: K and V of every head are expanded from one shared
+    ``kv_lora_rank``-wide latent; a ``qk_rope_head_dim``-wide roped key is
+    shared by all heads. ``head_dim`` of the model is the query/key width
+    ``qk_nope_head_dim + qk_rope_head_dim``."""
+
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of one latent token: c_kv then the roped k_pe."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def cache_dim(self) -> int:
+        """Width of one cached latent token: ``latent_dim`` zero-padded to
+        a multiple of 128 lanes (576 -> 640). The TPU's tiled memory layout
+        pads a row to whole 128-lane tiles anyway; holding the pad
+        explicitly keeps each page's copy lane-aligned."""
+        return -(-self.latent_dim // 128) * 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (DeepSeek-V2's ``rope_scaling`` of type yarn)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,6 +126,10 @@ class ModelConfig:
     embed_scale_by_dim: bool = False  # gemma: embeddings *= sqrt(d_model)
     meta_tokens: int = 0  # hymba: learnable always-visible prefix (sinks)
     moe: Optional[MoEConfig] = None
+    # leading layers whose MLP stays dense (width d_ff) before the MoE ones
+    first_dense_layers: int = 0
+    mla: Optional[MLAConfig] = None
+    rope_scaling: Optional[YarnConfig] = None
     ssm: Optional[SSMConfig] = None
     encoder: Optional[EncoderConfig] = None
     max_seq_len: int = 524_288
@@ -104,12 +156,18 @@ class ModelConfig:
         return len(self.layer_pattern)
 
     @property
+    def lead_pattern(self) -> Tuple[str, ...]:
+        """Kinds of the leading dense-MLP layers (unrolled, before the
+        scanned groups)."""
+        return self.layer_pattern[:1] * self.first_dense_layers
+
+    @property
     def num_groups(self) -> int:
-        return self.num_layers // self.group_size
+        return (self.num_layers - self.first_dense_layers) // self.group_size
 
     @property
     def tail_pattern(self) -> Tuple[str, ...]:
-        rem = self.num_layers % self.group_size
+        rem = (self.num_layers - self.first_dense_layers) % self.group_size
         return self.layer_pattern[:rem]
 
     @property
@@ -122,7 +180,8 @@ class ModelConfig:
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """Kind of every layer, in order."""
-        kinds = self.layer_pattern * self.num_groups + self.tail_pattern
+        kinds = (self.lead_pattern + self.layer_pattern * self.num_groups
+                 + self.tail_pattern)
         assert len(kinds) == self.num_layers
         return kinds
 
@@ -141,6 +200,13 @@ class ModelConfig:
             assert self.window is not None, f"{self.name}: window required"
         if self.family == "moe":
             assert self.moe is not None
+        if self.first_dense_layers:
+            assert self.moe is not None and self.d_ff > 0
+        if self.mla is not None:
+            m = self.mla
+            assert self.head_dim == m.qk_nope_head_dim + m.qk_rope_head_dim
+            assert self.num_kv_heads == self.num_heads
+            assert m.qk_rope_head_dim % 2 == 0
         if self.family == "encdec":
             assert self.encoder is not None
         assert self.padded_vocab % self.vocab_pad_to == 0

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import archs
-from repro.configs.base import SHAPES, ModelConfig, MoEConfig, SSMConfig, ShapeConfig
+from repro.configs.base import SHAPES, ModelConfig, SSMConfig, ShapeConfig
 
 _BY_NAME = {c.name: c for c in archs.ALL}
 
@@ -47,6 +47,7 @@ _PURE_FULL_ATTN = {
     "internvl2-76b",
     "granite-moe-1b-a400m",
     "whisper-base",
+    "deepseek-v2-lite",
 }
 
 
@@ -111,6 +112,9 @@ def input_specs(cfg: ModelConfig, shape: ShapeConfig) -> Dict[str, Any]:
 
 
 def _layer_cache_spec(kind: str, cfg: ModelConfig, B: int, cache: int, act):
+    if cfg.mla is not None:
+        # one latent "KV head": [c_kv, rope(k_pe)] per token
+        return {"latent": _sds((B, cache, 1, cfg.mla.cache_dim), act)}
     kv = {
         "k": _sds((B, cache, cfg.num_kv_heads, cfg.head_dim), act),
         "v": _sds((B, cache, cfg.num_kv_heads, cfg.head_dim), act),
@@ -150,6 +154,10 @@ def cache_specs(cfg: ModelConfig, B: int, cache: int, enc_frames: int = 1500):
         }
         return _stack(per_layer, cfg.num_layers)
     caches: Dict[str, Any] = {}
+    if cfg.lead_pattern:
+        caches["lead"] = [
+            _layer_cache_spec(k, cfg, B, cache, act) for k in cfg.lead_pattern
+        ]
     if cfg.num_groups:
         group = {
             f"slot_{u}": _layer_cache_spec(k, cfg, B, cache, act)
@@ -174,7 +182,13 @@ def paged_cache_specs(cfg: ModelConfig, num_pages: int, page_size: int):
     lm.decode_step's scan machinery is unchanged; the leaf layout is
     kernel-native for kernels/flash_decode.flash_decode_paged_kernel (the
     contiguous path's per-step (B,S,Hk,D) -> head-major transpose is gone).
-    Attention-only: a page holds no recurrent SSM state."""
+    Attention-only: a page holds no recurrent SSM state.
+
+    Latent attention (``cfg.mla``) pages one plane per layer,
+    ``(1, num_pages, page_size, mla.cache_dim)``: the normalised latent
+    c_kv, the roped shared key k_pe, then zeros to a whole 128-lane tile,
+    which the latent decode kernel reads once as K and, by its first
+    ``kv_lora_rank`` lanes, as V."""
     act = jnp.dtype(cfg.dtype)
     kinds = tuple(cfg.layer_pattern) + tuple(cfg.tail_pattern)
     assert cfg.family != "encdec" and cfg.ssm is None and all(
@@ -182,10 +196,15 @@ def paged_cache_specs(cfg: ModelConfig, num_pages: int, page_size: int):
     ), "paged caches serve attention-only decoder configs"
 
     def layer_spec():
+        if cfg.mla is not None:
+            shape = (1, num_pages, page_size, cfg.mla.cache_dim)
+            return {"latent": _sds(shape, act)}
         shape = (cfg.num_kv_heads, num_pages, page_size, cfg.head_dim)
         return {"kv": {"k": _sds(shape, act), "v": _sds(shape, act)}}
 
     caches: Dict[str, Any] = {}
+    if cfg.lead_pattern:
+        caches["lead"] = [layer_spec() for _ in cfg.lead_pattern]
     if cfg.num_groups:
         group = {f"slot_{u}": layer_spec()
                  for u in range(len(cfg.layer_pattern))}
@@ -224,6 +243,15 @@ def reduce_config(cfg: ModelConfig) -> ModelConfig:
         dtype="float32",
         num_patches=4 if cfg.num_patches else 0,
     )
+    if cfg.mla is not None:
+        from repro.configs.base import MLAConfig
+
+        kw["mla"] = MLAConfig(kv_lora_rank=32, qk_nope_head_dim=16,
+                              qk_rope_head_dim=8, v_head_dim=16)
+        kw["head_dim"] = 24
+        kw["num_kv_heads"] = heads
+    lead = min(cfg.first_dense_layers, 1)
+    kw["first_dense_layers"] = lead
     unit = cfg.layer_pattern
     if len(unit) == cfg.num_layers:  # unrolled pattern (hymba): shrink it
         kinds = sorted(set(unit), reverse=True)
@@ -232,9 +260,10 @@ def reduce_config(cfg: ModelConfig) -> ModelConfig:
         kw["num_layers"] = 4
     else:
         kw["layer_pattern"] = unit
-        kw["num_layers"] = len(unit) * 2 + (1 if cfg.tail_pattern else 0)
+        kw["num_layers"] = lead + len(unit) * 2 + (1 if cfg.tail_pattern else 0)
     if cfg.moe:
-        kw["moe"] = MoEConfig(
+        kw["moe"] = dataclasses.replace(
+            cfg.moe,
             num_experts=min(cfg.moe.num_experts, 8),
             top_k=min(cfg.moe.top_k, 2),
             d_expert=64,

@@ -229,3 +229,36 @@ def decode_attention_paged(
         q, k_pages, v_pages, cache_length, block_table,
         window=window, sink=sink, scale=scale, num_splits=splits,
     )[0]
+
+
+def decode_attention_latent_paged(
+    q: jnp.ndarray,  # (B, 1, H, W) absorbed latent queries
+    latent_pages: jnp.ndarray,  # (1, P, page_size, W) pool planes
+    cache_length: jnp.ndarray,  # (B,) int32 logical lengths
+    block_table: jnp.ndarray,  # (B, n_pages) int32
+    cfg: AttentionConfig = AttentionConfig(),
+    *,
+    v_dim: int,
+    scale: float,
+) -> jnp.ndarray:
+    """Latent-attention decode against a paged latent cache. Returns
+    (B, 1, H, v_dim): every head reads each cached latent token of width W
+    once as its key, and its first ``v_dim`` lanes as its value
+    (models/mla.py). The Pallas path is the paged decode kernel's latent
+    mode (device events ``mla_decode_paged``); the XLA path runs the paged
+    decode with the value lanes past ``v_dim`` zeroed."""
+    splits = paged_decode_splits(cfg, block_table.shape[1],
+                                 latent_pages.shape[2], q.shape[2],
+                                 q.shape[3], q.dtype)
+    if cfg.impl == "flash_pallas":
+        from repro.kernels.ops import mla_decode_paged_pallas
+
+        return mla_decode_paged_pallas(
+            q, latent_pages, cache_length, block_table, v_dim=v_dim,
+            scale=scale, num_splits=splits, interpret=cfg.interpret,
+        )[0]
+    v = jnp.where(jnp.arange(latent_pages.shape[-1]) < v_dim, latent_pages, 0)
+    return _decode.flash_decode_paged(
+        q, latent_pages, v, cache_length, block_table, scale=scale,
+        num_splits=splits,
+    )[0][..., :v_dim]

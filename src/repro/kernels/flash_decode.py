@@ -227,23 +227,22 @@ def paged_decode_work(lengths, n_pages: int, ps: int, num_splits: int, *,
 
 
 def _paged_decode_kernel(
-    tbl_ref,  # scalar prefetch: (B, ns*nb*ppb) int32 block table
-    len_ref,  # scalar prefetch: (B,) int32 logical lengths
-    q_ref,    # (Hk, G, D) -- the slot's queries, every KV head
-    k_hbm,    # (Hk, P, ps, D) page planes, left in HBM
-    v_hbm,
-    o_ref,    # (Hk, G, D)
-    lse_ref,  # (Hk, 1, G)
-    k_buf,    # VMEM (2, Hk, ppb*ps, D): the block being computed + the next
-    v_buf,
-    sems,     # DMA (2, 2): [buffer, k|v]
-    walk,     # SMEM (2,) int32: [buffer of this block, a block was started]
-    m_scr,    # VMEM (Hk, G, LANES) f32
-    l_scr,    # VMEM (Hk, G, LANES) f32
-    acc_scr,  # VMEM (Hk, G, D) f32
-    *, ps: int, ppb: int, nb: int, ns: int, window: Optional[int], sink: int,
+    *refs, ps: int, ppb: int, nb: int, ns: int, window: Optional[int],
+    sink: int, v_dim: Optional[int],
 ):
     """One (slot, split, block) step of the page-indirect decode.
+
+    Refs, in order: scalar prefetch ``tbl_ref`` (B, ns*nb*ppb) int32 block
+    table and ``len_ref`` (B,) int32 logical lengths; ``q_ref`` (Hk, G, D),
+    the slot's queries for every KV head; the page planes ``k_hbm`` and
+    ``v_hbm`` (Hk, P, ps, D), left in HBM; ``o_ref`` (Hk, G, Dv) and
+    ``lse_ref`` (Hk, 1, G); scratch ``k_buf`` and ``v_buf`` VMEM
+    (2, Hk, ppb*ps, D) (the block being computed + the next), ``sems`` DMA
+    (2, 2) [buffer, k|v], ``walk`` SMEM (2,) int32 [buffer of this block, a
+    block was started], ``m_scr`` / ``l_scr`` VMEM (Hk, G, LANES) f32 and
+    ``acc_scr`` (Hk, G, Dv) f32. Latent mode (``v_dim``, latent attention)
+    has no V plane, buffer or semaphore: V is the first ``v_dim`` lanes of
+    the K block, so every cached byte is copied once.
 
     The grid walks slots, then splits, then blocks, in order. A block is
     ``ppb`` logical pages; a live one (``_visible``) gathers its cached
@@ -256,6 +255,14 @@ def _paged_decode_kernel(
     op-for-op the contiguous kernel's chunk math (bitwise-equal partials
     when one split == one page -- tests/test_paged.py pins it).
     """
+    if v_dim is None:
+        (tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, lse_ref, k_buf, v_buf,
+         sems, walk, m_scr, l_scr, acc_scr) = refs
+        planes = ((k_hbm, k_buf), (v_hbm, v_buf))
+    else:
+        (tbl_ref, len_ref, q_ref, k_hbm, o_ref, lse_ref, k_buf, sems, walk,
+         m_scr, l_scr, acc_scr) = refs
+        planes = ((k_hbm, k_buf),)
     b, c, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     B = pl.num_programs(0)
     Hk, G, D = q_ref.shape
@@ -278,7 +285,7 @@ def _paged_decode_kernel(
         def _(i):
             phys = tbl_ref[s, page0 + i]
             rows = pl.ds(pl.multiple_of(i * ps, ps), ps)
-            for x, (src, dst) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+            for x, (src, dst) in enumerate(planes):
                 getattr(pltpu.make_async_copy(src.at[:, phys],
                                               dst.at[buf, :, rows],
                                               sems.at[buf, x]), act)()
@@ -328,11 +335,13 @@ def _paged_decode_kernel(
             valid = valid & in_win
         # Rows past the length hold stale VMEM (pages not copied) or stale
         # pool rows: their p is 0, but 0 * NaN is NaN, so zero them.
-        cached = jax.lax.broadcasted_iota(jnp.int32, (bk, D), 0) + base < L
+        Dv = acc_scr.shape[-1]
+        cached = jax.lax.broadcasted_iota(jnp.int32, (bk, Dv), 0) + base < L
         for h in range(Hk):
             q = q_ref[h]  # (G, D)
             k = k_buf[buf, h]  # (bk, D)
-            v = jnp.where(cached, v_buf[buf, h], 0)
+            v = k[:, :v_dim] if v_dim is not None else v_buf[buf, h]
+            v = jnp.where(cached, v, 0)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -369,13 +378,14 @@ def _paged_decode_kernel(
 def flash_decode_paged_kernel(
     q: jnp.ndarray,  # (B, Hk, G, D) pre-scaled
     k_pages: jnp.ndarray,  # (Hk, P, ps, D) physical page planes
-    v_pages: jnp.ndarray,
+    v_pages: Optional[jnp.ndarray],  # same, or None in latent mode
     lengths: jnp.ndarray,  # (B,) int32 logical lengths
     block_table: jnp.ndarray,  # (B, n_pages) int32 logical -> physical page
     *,
     num_splits: int = 8,
     window: Optional[int] = None,
     sink: int = 0,
+    v_dim: Optional[int] = None,
     interpret: Optional[bool] = None,
 ):
     """Split-KV decode that never sees a contiguous cache.
@@ -389,8 +399,13 @@ def flash_decode_paged_kernel(
     bitwise). Table entries past a sequence's live pages should name the
     null page (0); they are never copied, whatever they hold.
 
-    Returns per-split partials ``(o_parts (B, ns, Hk, G, D) f32,
-    lse_parts (B, ns, Hk, 1, G) f32)`` for ``combine_lse_outputs``.
+    Latent mode (``v_pages=None``, ``v_dim`` given; latent attention,
+    models/mla.py) reads one plane as K and its first ``v_dim`` lanes as
+    V, under the kernel name ``mla_decode_paged``.
+
+    Returns per-split partials ``(o_parts (B, ns, Hk, G, Dv) f32,
+    lse_parts (B, ns, Hk, 1, G) f32)`` for ``combine_lse_outputs``, Dv the
+    V width.
     """
     interpret = resolve_interpret(interpret)
     B, Hk, G, D = q.shape
@@ -403,46 +418,47 @@ def flash_decode_paged_kernel(
         # Padded table columns are logical positions >= n_pages*ps >= L:
         # never live, never copied.
         tbl = jnp.pad(tbl, ((0, 0), (0, pad)))
+    latent = v_pages is None
+    Dv = v_dim if latent else D
+    n_planes = 1 if latent else 2
     kernel = functools.partial(
         _paged_decode_kernel, ps=ps, ppb=ppb, nb=nb, ns=ns, window=window,
-        sink=sink,
+        sink=sink, v_dim=v_dim if latent else None,
     )
     # Capacity-based: the live work depends on the lengths, unknown here.
     cost = pl.CostEstimate(
-        flops=2 * B * Hk * G * n_pages * ps * D * 2,
-        bytes_accessed=2 * B * n_pages * ps * D * k_pages.dtype.itemsize
+        flops=2 * B * Hk * G * n_pages * ps * (D + Dv),
+        bytes_accessed=n_planes * B * n_pages * ps * D * k_pages.dtype.itemsize
         + 2 * q.size * q.dtype.itemsize,
         transcendentals=B * Hk * G * n_pages * ps,
     )
+    buf = pltpu.VMEM((2, Hk, ppb * ps, D), k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block table + lengths
         grid=(B, ns, nb),
         in_specs=[
             pl.BlockSpec((None, Hk, G, D), lambda b, c, j, *_: (b, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
+        ] + [pl.BlockSpec(memory_space=pl.ANY)] * n_planes,
         out_specs=[
-            pl.BlockSpec((None, None, Hk, G, D),
+            pl.BlockSpec((None, None, Hk, G, Dv),
                          lambda b, c, j, *_: (b, c, 0, 0, 0)),
             pl.BlockSpec((None, None, Hk, 1, G),
                          lambda b, c, j, *_: (b, c, 0, 0, 0)),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((2, Hk, ppb * ps, D), k_pages.dtype),
-            pltpu.VMEM((2, Hk, ppb * ps, D), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
+        scratch_shapes=[buf] * n_planes + [
+            pltpu.SemaphoreType.DMA((2, n_planes)),
             pltpu.SMEM((2,), jnp.int32),
             pltpu.VMEM((Hk, G, LANES), jnp.float32),
             pltpu.VMEM((Hk, G, LANES), jnp.float32),
-            pltpu.VMEM((Hk, G, D), jnp.float32),
+            pltpu.VMEM((Hk, G, Dv), jnp.float32),
         ],
     )
+    planes = (k_pages,) if latent else (k_pages, v_pages)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, ns, Hk, G, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, ns, Hk, G, Dv), jnp.float32),
             jax.ShapeDtypeStruct((B, ns, Hk, 1, G), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
@@ -451,5 +467,5 @@ def flash_decode_paged_kernel(
         ),
         cost_estimate=cost,
         interpret=interpret,
-        name="fa2_decode_paged",
-    )(tbl, lengths.astype(jnp.int32), q, k_pages, v_pages)
+        name="mla_decode_paged" if latent else "fa2_decode_paged",
+    )(tbl, lengths.astype(jnp.int32), q, *planes)

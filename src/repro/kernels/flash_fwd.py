@@ -362,10 +362,11 @@ def _fwd_kernel_partitioned(
         _finalize_state(o_ref, lse_ref, m_scr, l_scr, acc_scr)
 
 
-def _fwd_cost(BH, n_vis, block_q, block_kv, D, q, k):
+def _fwd_cost(BH, n_vis, block_q, block_kv, D, q, k, Dv=None):
     """Roofline-honest cost: count only visible tiles (block skipping)."""
-    flops_per_tile = 2 * block_q * block_kv * D * 2  # QK^T + PV
-    kv_tile_bytes = 2 * block_kv * D * k.dtype.itemsize  # K + V tiles streamed
+    Dv = D if Dv is None else Dv
+    flops_per_tile = 2 * block_q * block_kv * (D + Dv)  # QK^T + PV
+    kv_tile_bytes = block_kv * (D + Dv) * k.dtype.itemsize  # K + V tiles streamed
     return pl.CostEstimate(
         flops=BH * n_vis * flops_per_tile,
         bytes_accessed=2 * q.size * q.dtype.itemsize + BH * n_vis * kv_tile_bytes,
@@ -390,7 +391,9 @@ def flash_fwd(
     num_q_bands: int = 1,
     kv_splits: int = 1,
 ):
-    """FA2 forward on prepped (head-major, padded) tensors.
+    """FA2 forward on prepped (head-major, padded) tensors. V may be
+    narrower than Q/K (latent attention: Dv = ``v.shape[-1]``); the output
+    takes V's width.
 
     ``num_q_bands`` / ``kv_splits`` (compact schedule only) apply the
     paper's Section 3.2 forward partitioning: the grid grows a *parallel*
@@ -405,6 +408,7 @@ def flash_fwd(
     interpret = resolve_interpret(interpret)
     BH, Sq, D = q.shape
     BHk, Skp, _ = k.shape
+    Dv = v.shape[-1]
     assert Sq % block_q == 0 and Skp % block_kv == 0
     t_q, t_kv = Sq // block_q, Skp // block_kv
     has_segments = q_seg is not None
@@ -421,15 +425,15 @@ def flash_fwd(
     from repro.core.flash import _visible_pairs
 
     n_vis = len(_visible_pairs(spec, t_q, t_kv, block_q, block_kv)[0])
-    cost = _fwd_cost(BH, n_vis, block_q, block_kv, D, q, k)
+    cost = _fwd_cost(BH, n_vis, block_q, block_kv, D, q, k, Dv)
     out_shape = [
-        jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
+        jax.ShapeDtypeStruct((BH, Sq, Dv), q.dtype),
         jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),  # lane-major lse
     ]
     scratch_shapes = [
         pltpu.VMEM((block_q, LANES), jnp.float32),
         pltpu.VMEM((block_q, LANES), jnp.float32),
-        pltpu.VMEM((block_q, D), jnp.float32),
+        pltpu.VMEM((block_q, Dv), jnp.float32),
     ]
 
     if schedule == "dense":
@@ -440,7 +444,7 @@ def flash_fwd(
         in_specs = [
             pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((1, block_kv, D), lambda bh, i, j, g=group: (bh // g, j, 0)),
-            pl.BlockSpec((1, block_kv, D), lambda bh, i, j, g=group: (bh // g, j, 0)),
+            pl.BlockSpec((1, block_kv, Dv), lambda bh, i, j, g=group: (bh // g, j, 0)),
         ]
         inputs = [q, k, v]
         if has_segments:
@@ -455,7 +459,7 @@ def flash_fwd(
             grid=(BH, t_q, t_kv),
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
+                pl.BlockSpec((1, block_q, Dv), lambda bh, i, j: (bh, i, 0)),
                 lane_spec(block_q, lambda bh, i, j: (bh, i)),
             ],
             out_shape=out_shape,
@@ -490,7 +494,7 @@ def flash_fwd(
             (1, block_kv, D), lambda bh, s, o_, i_, f_, *_, g=group: (bh // g, i_[s], 0)
         ),
         pl.BlockSpec(
-            (1, block_kv, D), lambda bh, s, o_, i_, f_, *_, g=group: (bh // g, i_[s], 0)
+            (1, block_kv, Dv), lambda bh, s, o_, i_, f_, *_, g=group: (bh // g, i_[s], 0)
         ),
     ]
     scalar_args = [
@@ -512,7 +516,7 @@ def flash_fwd(
         grid=(BH, sched.n_steps),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, s, o_, i_, f_, *_: (bh, o_[s], 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda bh, s, o_, i_, f_, *_: (bh, o_[s], 0)),
             lane_spec(block_q, lambda bh, s, o_, i_, f_, *_: (bh, o_[s])),
         ],
         scratch_shapes=scratch_shapes,
@@ -541,6 +545,7 @@ def _flash_fwd_partitioned(
     docstring for the return contract).
     """
     BH, Sq, D = q.shape
+    Dv = v.shape[-1]
     has_segments = q_seg is not None
     sched = build_partitioned_schedule(
         spec, t_q, t_kv, block_q, block_kv, kv_valid, num_q_bands, kv_splits
@@ -560,7 +565,7 @@ def _flash_fwd_partitioned(
             lambda bh, p, s, o_, i_, f_, k_, *_, g=group: (bh // g, i_[p, s], 0),
         ),
         pl.BlockSpec(
-            (1, block_kv, D),
+            (1, block_kv, Dv),
             lambda bh, p, s, o_, i_, f_, k_, *_, g=group: (bh // g, i_[p, s], 0),
         ),
     ]
@@ -589,12 +594,12 @@ def _flash_fwd_partitioned(
         # (each q row runs its unchanged kv visit sequence, just on a
         # different parallel grid cell).
         out_shape = [
-            jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
+            jax.ShapeDtypeStruct((BH, Sq, Dv), q.dtype),
             jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),
         ]
         out_specs = [
             pl.BlockSpec(
-                (1, block_q, D), lambda bh, p, s, o_, i_, f_, k_, *_: (bh, o_[p, s], 0)
+                (1, block_q, Dv), lambda bh, p, s, o_, i_, f_, k_, *_: (bh, o_[p, s], 0)
             ),
             lane_spec(
                 block_q, lambda bh, p, s, o_, i_, f_, k_, *_: (bh, o_[p, s])
@@ -607,12 +612,12 @@ def _flash_fwd_partitioned(
         # into the leading axis (row bh*ks + split) to keep the kernel's
         # output refs rank-identical to the unsplit path.
         out_shape = [
-            jax.ShapeDtypeStruct((BH * ks, Sq, D), jnp.float32),
+            jax.ShapeDtypeStruct((BH * ks, Sq, Dv), jnp.float32),
             jax.ShapeDtypeStruct((BH * ks, 1, Sq), jnp.float32),
         ]
         out_specs = [
             pl.BlockSpec(
-                (1, block_q, D),
+                (1, block_q, Dv),
                 lambda bh, p, s, o_, i_, f_, k_, *_, n=ks: (bh * n + k_[p], o_[p, s], 0),
             ),
             lane_spec(
@@ -628,7 +633,7 @@ def _flash_fwd_partitioned(
         scratch_shapes=[
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
     )
     name = "fa2_fwd_splitkv" if ks > 1 else "fa2_fwd_banded"
@@ -645,4 +650,4 @@ def _flash_fwd_partitioned(
     )(*scalar_args, *inputs)
     if ks == 1:
         return o, lse
-    return o.reshape(BH, ks, Sq, D), lse.reshape(BH, ks, 1, Sq)
+    return o.reshape(BH, ks, Sq, Dv), lse.reshape(BH, ks, 1, Sq)

@@ -757,3 +757,26 @@ def flash_decode_paged_pallas(
         o.reshape(B, 1, Hq, D).astype(q.dtype),
         lse.reshape(B, Hq, 1),
     )
+
+
+def mla_decode_paged_pallas(
+    q, latent_pages, cache_length, block_table, *, v_dim: int, scale: float,
+    num_splits: int = 8, interpret: Optional[bool] = None,
+):
+    """Latent-attention decode through the paged kernel's latent mode.
+    q (B,1,H,W) absorbed latent queries; latent_pages (1,P,ps,W) pool
+    planes; returns (o (B,1,H,v_dim), lse (B,H,1)): every head reads each
+    cached latent token once, as K, and its first ``v_dim`` lanes as V."""
+    B, one, H, W = q.shape
+    assert one == 1 and latent_pages.shape[0] == 1
+    qh = (q.astype(jnp.float32) * scale).astype(q.dtype).reshape(B, 1, H, W)
+    o_parts, lse_parts = _dec.flash_decode_paged_kernel(
+        qh, latent_pages, None, cache_length.astype(jnp.int32), block_table,
+        num_splits=num_splits, v_dim=v_dim, interpret=interpret,
+    )
+    ns = o_parts.shape[1]
+    o, lse = combine_lse_outputs(
+        jnp.moveaxis(o_parts, 1, 0).reshape(ns, B, H, v_dim),
+        jnp.moveaxis(lse_parts[:, :, :, 0], 1, 0).reshape(ns, B, H),
+    )
+    return o.reshape(B, 1, H, v_dim).astype(q.dtype), lse.reshape(B, H, 1)

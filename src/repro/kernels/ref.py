@@ -6,7 +6,9 @@ It deliberately materializes the N x N score matrix -- O(N^2) memory --
 and is also the "standard attention" baseline of the paper's benchmarks.
 
 Layout convention (whole repo): q (B, Sq, Hq, D); k, v (B, Skv, Hkv, D)
-with Hq % Hkv == 0 (GQA). Output (B, Sq, Hq, D); LSE (B, Hq, Sq).
+with Hq % Hkv == 0 (GQA). Output (B, Sq, Hq, D); LSE (B, Hq, Sq). The
+forward also takes V of another width Dv (latent attention): its output is
+then (B, Sq, Hq, Dv).
 """
 
 from __future__ import annotations
@@ -75,7 +77,7 @@ def attention_reference(
     o = jnp.einsum("bhgqk,bkhd->bqhgd", p / l_safe[..., None], vf)
     lse = jnp.where(l == 0.0, -jnp.inf, m_safe + jnp.log(l_safe))
     return (
-        o.reshape(B, Sq, Hq, D).astype(q.dtype),
+        o.reshape(B, Sq, Hq, vf.shape[-1]).astype(q.dtype),
         lse.reshape(B, Hk * G, Sq),
     )
 

@@ -90,6 +90,9 @@ def main():
     ap.add_argument("--page-size", type=int, default=16)
     ap.add_argument("--pages-per-seq", type=int, default=None,
                     help="paged: block-table width; default cache/page_size")
+    ap.add_argument("--prefill-token-budget", type=int, default=None,
+                    help="paged: most prompt tokens (width x bucket) one "
+                         "prefill launch may hold; default unlimited")
     ap.add_argument("--arrival-rate", type=float, default=None,
                     help="Poisson arrivals (requests per expected tick); "
                          "default submits every request upfront")
@@ -123,7 +126,9 @@ def main():
         engine = PagedServingEngine(
             cfg, params, attn_cfg, max_batch=args.max_batch,
             num_pages=num_pages, page_size=args.page_size,
-            pages_per_seq_max=n_max, registry=obs_registry, tracer=tracer,
+            pages_per_seq_max=n_max,
+            prefill_token_budget=args.prefill_token_budget,
+            registry=obs_registry, tracer=tracer,
         )
     else:
         engine = ServingEngine(cfg, params, attn_cfg, max_batch=args.max_batch,

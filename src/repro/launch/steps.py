@@ -151,15 +151,17 @@ def build_paged_serve_step(cfg: ModelConfig, attn_cfg: AttentionConfig):
     (max_batch, pages_per_seq_max, page_size) only -- never of which
     requests are resident -- so the jitted step compiles exactly once and
     requests join/leave with zero recompiles (pinned by
-    tests/test_paged.py)."""
+    tests/test_paged.py). An MoE config also returns its MoE layers'
+    routing counters, (n_moe_layers, 3) int32 (models/moe.STATS)."""
+    with_stats = cfg.moe is not None
 
     def paged_serve_step(params, token, caches, block_table, cache_len):
-        logits, new_caches = lm.decode_step(
+        out = lm.decode_step(
             cfg, params, token, caches, cache_len, attn_cfg,
-            block_table=block_table,
+            block_table=block_table, with_stats=with_stats,
         )
-        next_token = jnp.argmax(logits[..., : cfg.vocab_size], axis=-1).astype(jnp.int32)
-        return next_token, new_caches
+        next_token = jnp.argmax(out[0][..., : cfg.vocab_size], axis=-1).astype(jnp.int32)
+        return (next_token,) + tuple(out[1:])
 
     return paged_serve_step
 
@@ -174,7 +176,9 @@ def build_paged_admit_step(cfg: ModelConfig, attn_cfg: AttentionConfig,
     (W,) true lengths, ``dest`` (W, pad_to_pages) int32 physical page per
     logical prefill page (0 = the null page for rows/pages that must not
     land anywhere -- width-padding rows and overflow). Shapes depend only
-    on (pad_to, W), so jit compiles once per (bucket, admission width)."""
+    on (pad_to, W), so jit compiles once per (bucket, admission width).
+    An MoE config also returns the routing counters, as the decode step."""
+    with_stats = cfg.moe is not None
 
     def scatter(paged, contig, dest):
         # contig (..., W, S, Hk, hd) -> pages (..., Hk, W, NP, ps, hd)
@@ -187,8 +191,9 @@ def build_paged_admit_step(cfg: ModelConfig, attn_cfg: AttentionConfig,
     def admit_step(params, batch, caches, dest):
         tokens = batch["inputs"]
         cache_size = -(-tokens.shape[1] // page_size) * page_size
-        h_last, prefill_caches, lens_total = lm.prefill(
+        h_last, prefill_caches, lens_total, *stats = lm.prefill(
             cfg, params, tokens, attn_cfg, cache_size, lens=batch.get("lens"),
+            with_stats=with_stats,
         )
         logits = lm.logits_from_hidden(cfg, params, h_last)
         next_token = jnp.argmax(
@@ -197,6 +202,6 @@ def build_paged_admit_step(cfg: ModelConfig, attn_cfg: AttentionConfig,
         new_caches = jax.tree.map(
             functools.partial(scatter, dest=dest), caches, prefill_caches
         )
-        return next_token, lens_total, new_caches
+        return (next_token, lens_total, new_caches, *stats)
 
     return admit_step

@@ -66,6 +66,59 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndar
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
+def yarn_inv_freq(dim: int, theta: float, yarn) -> jnp.ndarray:
+    """YaRN inverse frequencies of a ``dim``-wide rotary embedding, as
+    DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding`` computes them: each
+    pair's frequency blends the interpolated ``theta^(-2i/dim) / factor``
+    and the original one by a linear ramp over the pairs between the
+    correction dims of ``beta_fast`` and ``beta_slow`` rotations in
+    ``original_max_position`` tokens (interpolated below, original above).
+    Without ``yarn``, the plain ``theta^(-2i/dim)``."""
+    extra = 1.0 / theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    if yarn is None:
+        return extra
+
+    def corr(rot):
+        return dim * math.log(yarn.original_max_position / (rot * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(corr(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / (high - low),
+                    0.0, 1.0)
+    keep = 1.0 - ramp  # 1: the original frequency
+    return extra / yarn.factor * (1.0 - keep) + extra * keep
+
+
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor ``0.1 * mscale * ln(scale) + 1``."""
+    if scale <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(scale) + 1.0
+
+
+def apply_rope_interleaved(x: jnp.ndarray, positions: jnp.ndarray,
+                           inv_freq: jnp.ndarray, cos_scale: float = 1.0
+                           ) -> jnp.ndarray:
+    """Rotary embedding on interleaved pairs ``(x[2i], x[2i+1])`` at angle
+    ``position * inv_freq[i]`` (DeepSeek-V2's layout); cos and sin scaled
+    by ``cos_scale`` (YaRN's mscale ratio).
+
+    x: (B, S, H, D); positions: (B, S) or (S,).
+    """
+    if positions.ndim == 1:
+        positions = positions[None, :]
+    ang = positions[..., None].astype(jnp.float32) * inv_freq  # (B, S, D/2)
+    cos = (jnp.cos(ang) * cos_scale)[:, :, None, :]
+    sin = (jnp.sin(ang) * cos_scale)[:, :, None, :]
+    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
+    x1, x2 = xf[..., 0], xf[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
 # ----------------------------------------------------------------------- mlp
 
 

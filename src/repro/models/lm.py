@@ -10,9 +10,13 @@ tail layers (unrolled) -> final norm. Heads:
   decode_step()  one token in, logits + updated caches
 
 Layer kinds (configs.base.LAYER_KINDS) pick the mixer: FA2 attention
-(global or SWA), Mamba, or Hymba hybrid. MoE replaces the MLP when
-cfg.moe is set. All masks are MaskSpec-symbolic; meta tokens become a
-`sink` prefix for windowed layers.
+(global or SWA; latent attention when cfg.mla is set), Mamba, or Hymba
+hybrid. MoE replaces the MLP when cfg.moe is set, except in the
+``cfg.first_dense_layers`` leading layers (params["lead"], unrolled before
+the groups). Training routes MoE with capacity; prefill and decode route
+dropless (models/moe.py) and, with ``with_stats``, return each MoE
+layer's routing counters. All masks are MaskSpec-symbolic; meta tokens
+become a `sink` prefix for windowed layers.
 """
 
 from __future__ import annotations
@@ -41,7 +45,8 @@ from repro.models.hybrid import (
     prefill_hybrid,
 )
 from repro.models.mamba import apply_mamba, decode_mamba_step, init_mamba
-from repro.models.moe import apply_moe, init_moe
+from repro.models.mla import apply_mla, decode_mla_step, init_mla, prefill_mla
+from repro.models.moe import STATS, apply_moe, apply_moe_dropless, init_moe
 
 
 # ---------------------------------------------------------------------------
@@ -65,10 +70,12 @@ def _has_mlp(cfg, kind: str) -> bool:
     return kind != "mamba"
 
 
-def init_layer(kind: str, key, cfg, dtype) -> dict:
+def init_layer(kind: str, key, cfg, dtype, dense: bool = False) -> dict:
     ks = jax.random.split(key, 4)
     p: Dict[str, Any] = {"ln1": L.init_norm(cfg, dtype)}
-    if kind in ("attn", "attn_local"):
+    if kind in ("attn", "attn_local") and cfg.mla is not None:
+        p["mixer"] = init_mla(ks[0], cfg, dtype)
+    elif kind in ("attn", "attn_local"):
         p["mixer"] = init_attention(ks[0], cfg, dtype)
     elif kind == "mamba":
         p["mixer"] = init_mamba(ks[0], cfg, dtype)
@@ -78,19 +85,42 @@ def init_layer(kind: str, key, cfg, dtype) -> dict:
         raise ValueError(kind)
     if _has_mlp(cfg, kind):
         p["ln2"] = L.init_norm(cfg, dtype)
-        if cfg.moe is not None:
+        if cfg.moe is not None and not dense:
             p["mlp"] = init_moe(ks[1], cfg, dtype)
         else:
             p["mlp"] = L.init_mlp(ks[1], cfg, cfg.d_ff, dtype)
     return p
 
 
+def _is_moe(p) -> bool:
+    return "router" in p["mlp"]
+
+
 def _apply_mlp_block(p, cfg, x):
-    """Second residual sub-block; returns (delta, aux)."""
+    """Second residual sub-block (training: capacity routing); returns
+    (delta, aux)."""
     h = L.apply_norm(p["ln2"], x, cfg.norm_eps, cfg.norm)
-    if cfg.moe is not None:
+    if _is_moe(p):
         return apply_moe(p["mlp"], cfg, h)
     return L.apply_mlp(p["mlp"], h, cfg.mlp), jnp.zeros((), jnp.float32)
+
+
+def _serve_mlp_block(p, cfg, x, valid):
+    """Second residual sub-block of prefill and decode: dropless routing
+    over the ``valid`` tokens. Returns (delta, stats (3,) int32 or None
+    for a dense MLP)."""
+    h = L.apply_norm(p["ln2"], x, cfg.norm_eps, cfg.norm)
+    if _is_moe(p):
+        return apply_moe_dropless(p["mlp"], cfg, h, valid)
+    return L.apply_mlp(p["mlp"], h, cfg.mlp), None
+
+
+def _stack_stats(stats):
+    """Routing counters of the MoE layers, (n, len(STATS)) int32."""
+    stats = [s for s in stats if s is not None]
+    if not stats:
+        return jnp.zeros((0, len(STATS)), jnp.int32)
+    return jnp.concatenate([s.reshape(-1, len(STATS)) for s in stats])
 
 
 def apply_layer(
@@ -98,7 +128,10 @@ def apply_layer(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     h = L.apply_norm(p["ln1"], x, cfg.norm_eps, cfg.norm)
     spec = _spec_for(cfg, kind)
-    if kind in ("attn", "attn_local"):
+    if kind in ("attn", "attn_local") and cfg.mla is not None:
+        mix = apply_mla(p["mixer"], cfg, h, positions, spec, attn_cfg,
+                        segment_ids=segment_ids)
+    elif kind in ("attn", "attn_local"):
         mix = apply_attention(
             p["mixer"], cfg, h, positions, spec, attn_cfg,
             rope_theta=_theta_for(cfg, kind), segment_ids=segment_ids,
@@ -124,10 +157,14 @@ def apply_layer(
     return constrain(x, "batch", "seq", "embed"), aux
 
 
-def prefill_layer(kind, p, cfg, x, positions, attn_cfg, cache_size):
+def prefill_layer(kind, p, cfg, x, positions, attn_cfg, cache_size, valid=None):
+    """-> (x, cache, MoE stats or None); ``valid`` (B, S) marks real tokens."""
     h = L.apply_norm(p["ln1"], x, cfg.norm_eps, cfg.norm)
     spec = _spec_for(cfg, kind)
-    if kind in ("attn", "attn_local"):
+    if kind in ("attn", "attn_local") and cfg.mla is not None:
+        mix, cache = prefill_mla(p["mixer"], cfg, h, positions, spec, attn_cfg,
+                                 cache_size=cache_size)
+    elif kind in ("attn", "attn_local"):
         mix, cache = prefill_attention(
             p["mixer"], cfg, h, positions, spec, attn_cfg,
             rope_theta=_theta_for(cfg, kind), cache_size=cache_size,
@@ -142,17 +179,22 @@ def prefill_layer(kind, p, cfg, x, positions, attn_cfg, cache_size):
             rope_theta=_theta_for(cfg, kind), cache_size=cache_size, remat=cfg.remat,
         )
     x = x + mix
+    stats = None
     if _has_mlp(cfg, kind):
-        delta, _ = _apply_mlp_block(p, cfg, x)
+        delta, stats = _serve_mlp_block(p, cfg, x, valid)
         x = x + delta
-    return constrain(x, "batch", "seq", "embed"), cache
+    return constrain(x, "batch", "seq", "embed"), cache, stats
 
 
 def decode_layer(kind, p, cfg, x, cache, cache_len, attn_cfg, block_table=None):
+    """-> (x, new cache, MoE stats or None)."""
     h = L.apply_norm(p["ln1"], x, cfg.norm_eps, cfg.norm)
     spec = _spec_for(cfg, kind)
     theta = _theta_for(cfg, kind)
-    if kind in ("attn", "attn_local"):
+    if kind in ("attn", "attn_local") and cfg.mla is not None:
+        mix, new_cache = decode_mla_step(p["mixer"], cfg, h, cache, cache_len,
+                                         attn_cfg, block_table=block_table)
+    elif kind in ("attn", "attn_local"):
         mix, kv = decode_attention_step(
             p["mixer"], cfg, h, cache["kv"], cache_len, attn_cfg,
             rope_theta=theta, window=spec.window, sink=spec.sink,
@@ -174,10 +216,12 @@ def decode_layer(kind, p, cfg, x, cache, cache_len, attn_cfg, block_table=None):
             rope_theta=theta, window=spec.window, sink=spec.sink,
         )
     x = x + mix
+    stats = None
     if _has_mlp(cfg, kind):
-        delta, _ = _apply_mlp_block(p, cfg, x)
+        # empty slots (cache_len 0) route to no expert
+        delta, stats = _serve_mlp_block(p, cfg, x, (cache_len > 0)[:, None])
         x = x + delta
-    return x, new_cache
+    return x, new_cache, stats
 
 
 # ---------------------------------------------------------------------------
@@ -196,6 +240,10 @@ def init_lm(cfg, key, dtype=None) -> dict:
     if cfg.meta_tokens:
         params["meta"] = L._normal(keys[1], (cfg.meta_tokens, cfg.d_model), 0.02, dtype)
 
+    if cfg.lead_pattern:
+        lks = jax.random.split(keys[4], len(cfg.lead_pattern))
+        params["lead"] = [init_layer(kind, lks[i], cfg, dtype, dense=True)
+                          for i, kind in enumerate(cfg.lead_pattern)]
     U, NG = cfg.group_size, cfg.num_groups
     if NG:
         def init_group(gkey):
@@ -253,6 +301,9 @@ def _run_groups(cfg, params, h, positions, attn_cfg, segment_ids=None):
         return (x, aux), None
 
     body = jax.checkpoint(group_body, prevent_cse=False) if cfg.remat else group_body
+    for i, kind in enumerate(cfg.lead_pattern):
+        h, a = apply_layer(kind, params["lead"][i], cfg, h, positions, attn_cfg, segment_ids)
+        aux0 = aux0 + a
     if cfg.num_groups:
         if cfg.scan_layers and cfg.num_groups > 1:
             (h, aux0), _ = jax.lax.scan(body, (h, aux0), params["groups"])
@@ -292,64 +343,103 @@ def logits_from_hidden(cfg, params, hidden):
 
 
 def prefill(cfg, params, tokens, attn_cfg: AttentionConfig, cache_size: int,
-            patches=None, lens=None):
-    """-> (hidden_last (B,1,d), caches, lens_total (B,) int32). Caches are
-    per-layer trees stacked over groups; cache_size is the padded KV
-    capacity.
+            patches=None, lens=None, with_stats: bool = False):
+    """-> (hidden_last (B,1,d), caches, lens_total (B,) int32[, stats]).
+    Caches are per-layer trees stacked over groups; cache_size is the
+    padded KV capacity.
 
     ``lens`` (B,) int32 marks the true token count per row when ``tokens``
     is right-padded to a bucket length (serving admission): the returned
     hidden is taken at each row's last *real* position and ``lens_total``
     counts only real tokens (+ any prefix). Causality keeps padding out of
-    the real positions' attention, and the caller masks the padded cache
-    tail via its per-slot cache length. Not supported for SSM/hybrid
-    configs (recurrent state would consume the padding).
+    the real positions' attention, dropless MoE layers route no padding,
+    and the caller masks the padded cache tail via its per-slot cache
+    length. Not supported for SSM/hybrid configs (recurrent state would
+    consume the padding). ``with_stats`` also returns the MoE layers'
+    routing counters over the real tokens, (n_moe_layers, 3) int32
+    (models/moe.STATS).
     """
     if lens is not None and cfg.ssm is not None:
         raise ValueError("lens-padded prefill needs attention-only configs "
                          "(SSM state crosses the padding)")
     h, positions, n_prefix = _embed_inputs(cfg, params, tokens, patches)
+    valid = None
+    if lens is not None:
+        valid = positions[None, :] < (n_prefix + lens.astype(jnp.int32))[:, None]
+
+    def layer(kind, p, x):
+        return prefill_layer(kind, p, cfg, x, positions, attn_cfg, cache_size,
+                             valid)
 
     def group_body(x, gp):
-        caches = {}
+        caches, stats = {}, []
         for u, kind in enumerate(cfg.layer_pattern):
-            x, c = prefill_layer(kind, gp[f"slot_{u}"], cfg, x, positions, attn_cfg, cache_size)
-            caches[f"slot_{u}"] = c
-        return x, caches
+            x, caches[f"slot_{u}"], st = layer(kind, gp[f"slot_{u}"], x)
+            stats.append(st)
+        return x, (caches, _stack_stats(stats))
 
-    caches: Dict[str, Any] = {}
-    if cfg.num_groups:
-        if cfg.scan_layers and cfg.num_groups > 1:
-            h, caches["groups"] = jax.lax.scan(group_body, h, params["groups"])
-        else:
-            caches["groups"] = []
-            for gp in params["groups"]:
-                h, c = group_body(h, gp)
-                caches["groups"].append(c)
-    if cfg.tail_pattern:
-        caches["tail"] = []
-        for i, kind in enumerate(cfg.tail_pattern):
-            h, c = prefill_layer(kind, params["tail"][i], cfg, h, positions, attn_cfg, cache_size)
-            caches["tail"].append(c)
+    h, caches, stats = _serve_layers(cfg, params, h, layer, group_body)
     h = L.apply_norm(params["ln_f"], h, cfg.norm_eps, cfg.norm)
     B = h.shape[0]
     if lens is None:
-        return h[:, -1:], caches, jnp.full((B,), h.shape[1], jnp.int32)
-    lens = lens.astype(jnp.int32)
-    last = (n_prefix + lens - 1)[:, None, None]  # (B,1,1) last real position
-    h_last = jnp.take_along_axis(h, jnp.broadcast_to(last, (B, 1, h.shape[2])), axis=1)
-    return h_last, caches, n_prefix + lens
+        out = (h[:, -1:], caches, jnp.full((B,), h.shape[1], jnp.int32))
+    else:
+        lens = lens.astype(jnp.int32)
+        last = (n_prefix + lens - 1)[:, None, None]  # (B,1,1) last real position
+        h_last = jnp.take_along_axis(
+            h, jnp.broadcast_to(last, (B, 1, h.shape[2])), axis=1)
+        out = (h_last, caches, n_prefix + lens)
+    return out + (stats,) if with_stats else out
+
+
+def _serve_layers(cfg, params, h, layer, group_body, caches_in=None):
+    """Run lead, grouped and tail layers of prefill (``caches_in`` None)
+    or decode: ``layer(kind, p, x[, cache])`` -> (x, cache, stats) and
+    ``group_body`` the scan body over groups. Returns (h, caches, stats
+    (n_moe_layers, 3))."""
+    caches: Dict[str, Any] = {}
+    stats = []
+    with_cache = caches_in is not None
+    if cfg.lead_pattern:
+        caches["lead"] = []
+        for i, kind in enumerate(cfg.lead_pattern):
+            args = (caches_in["lead"][i],) if with_cache else ()
+            h, c, st = layer(kind, params["lead"][i], h, *args)
+            caches["lead"].append(c)
+            stats.append(st)
+    if cfg.num_groups:
+        xs = (params["groups"], caches_in["groups"]) if with_cache else params["groups"]
+        if cfg.scan_layers and cfg.num_groups > 1:
+            h, (caches["groups"], st) = jax.lax.scan(group_body, h, xs)
+            stats.append(st)
+        else:
+            caches["groups"] = []
+            for g in range(cfg.num_groups):
+                x_g = (xs[0][g], xs[1][g]) if with_cache else xs[g]
+                h, (c, st) = group_body(h, x_g)
+                caches["groups"].append(c)
+                stats.append(st)
+    if cfg.tail_pattern:
+        caches["tail"] = []
+        for i, kind in enumerate(cfg.tail_pattern):
+            args = (caches_in["tail"][i],) if with_cache else ()
+            h, c, st = layer(kind, params["tail"][i], h, *args)
+            caches["tail"].append(c)
+            stats.append(st)
+    return h, caches, _stack_stats(stats)
 
 
 def decode_step(cfg, params, token, caches, cache_len, attn_cfg: AttentionConfig,
-                block_table=None):
+                block_table=None, with_stats: bool = False):
     """token (B,1) int32; cache_len (B,) valid entries per sequence.
-    -> (logits (B,1,V), new_caches).
+    -> (logits (B,1,V), new_caches[, stats]).
 
     ``block_table`` (B, n_pages) int32 switches every attention layer to
     the paged cache path (pool page planes instead of per-slot contiguous
     caches -- see attention_layer.decode_attention_step); the table is
-    shared by all layers."""
+    shared by all layers. ``with_stats`` also returns the MoE layers'
+    routing counters over the live rows (cache_len > 0), (n_moe_layers, 3)
+    int32 (models/moe.STATS)."""
     h = L.embed_tokens(params["embed"], token)
     if cfg.embed_scale_by_dim:
         h = (h.astype(jnp.float32) * (cfg.d_model ** 0.5)).astype(h.dtype)
@@ -357,36 +447,21 @@ def decode_step(cfg, params, token, caches, cache_len, attn_cfg: AttentionConfig
         pos_e = jnp.take(params["embed"]["positions"], cache_len, axis=0)[:, None]
         h = h + pos_e.astype(h.dtype)
 
+    def layer(kind, p, x, cache):
+        return decode_layer(kind, p, cfg, x, cache, cache_len, attn_cfg,
+                            block_table)
+
     def group_body(x, gp_cache):
         gp, cache = gp_cache
-        new_caches = {}
+        new_caches, stats = {}, []
         for u, kind in enumerate(cfg.layer_pattern):
-            x, nc = decode_layer(
-                kind, gp[f"slot_{u}"], cfg, x, cache[f"slot_{u}"], cache_len,
-                attn_cfg, block_table,
-            )
-            new_caches[f"slot_{u}"] = nc
-        return x, new_caches
+            x, new_caches[f"slot_{u}"], st = layer(
+                kind, gp[f"slot_{u}"], x, cache[f"slot_{u}"])
+            stats.append(st)
+        return x, (new_caches, _stack_stats(stats))
 
-    new_caches: Dict[str, Any] = {}
-    if cfg.num_groups:
-        if cfg.scan_layers and cfg.num_groups > 1:
-            h, new_caches["groups"] = jax.lax.scan(
-                group_body, h, (params["groups"], caches["groups"])
-            )
-        else:
-            new_caches["groups"] = []
-            for gp, c in zip(params["groups"], caches["groups"]):
-                h, nc = group_body(h, (gp, c))
-                new_caches["groups"].append(nc)
-    if cfg.tail_pattern:
-        new_caches["tail"] = []
-        for i, kind in enumerate(cfg.tail_pattern):
-            h, nc = decode_layer(
-                kind, params["tail"][i], cfg, h, caches["tail"][i], cache_len,
-                attn_cfg, block_table,
-            )
-            new_caches["tail"].append(nc)
+    h, new_caches, stats = _serve_layers(cfg, params, h, layer, group_body,
+                                         caches)
     h = L.apply_norm(params["ln_f"], h, cfg.norm_eps, cfg.norm)
     logits = logits_from_hidden(cfg, params, h)
-    return logits, new_caches
+    return (logits, new_caches, stats) if with_stats else (logits, new_caches)

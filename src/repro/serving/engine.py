@@ -63,12 +63,15 @@ class Request:
         return self.prompt + self.generated
 
 
-_CACHE_BASE_NDIM = {"k": 4, "v": 4, "h": 3, "conv": 3}  # (B, ...) leaf ranks
+# (B, ...) leaf ranks; a latent cache is one (B, S, 1, W) latent "head"
+_CACHE_BASE_NDIM = {"k": 4, "v": 4, "latent": 4, "h": 3, "conv": 3}
 
 # Fixed buckets for the admission-size histogram (prompt pad buckets are
 # prompt_pad multiples clamped to capacity; pow2 bounds cover both engines)
 ADMIT_BUCKETS = (16.0, 32.0, 64.0, 128.0, 256.0, 512.0, 1024.0)
 QUEUE_WAIT_S_BUCKETS = (0.001, 0.01, 0.1, 0.5, 1.0, 2.0, 5.0, 10.0, 30.0, 60.0)
+# tokens on the busiest expert of one MoE layer in one launch
+EXPERT_LOAD_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 256.0, 1024.0, 4096.0)
 # The engine's own trace track; request tracks use tid = rid, so it takes a
 # tid no request id reaches (the largest int32, as trace viewers read tids).
 ENGINE_TID = 2**31 - 1
@@ -101,6 +104,13 @@ class _EngineTelemetry:
     {live; paged on the Pallas kernel also kv_pages, kv_blocks,
     kv_blocks_launched} with ``.dispatch`` and ``.wait`` children, and
     ``engine.bookkeep`` {retired}. Without one, no span is opened.
+
+    A paged MoE model's steps also return their routing counters
+    (models/moe.STATS), fetched with the tokens: ``engine.admit`` and
+    ``engine.decode`` then carry ``experts_touched`` (summed over the MoE
+    layers) and ``expert_load_max`` (the most over them), and the registry
+    counts ``serving/moe_experts_touched`` (counter) and
+    ``serving/moe_expert_load_max`` (histogram, one observation per layer).
     """
 
     def _obs_init(self, registry: Optional[MetricsRegistry],
@@ -172,13 +182,27 @@ class _EngineTelemetry:
         ``engine.decode`` span (``live`` and ``work`` as args) with
         ``.dispatch`` / ``.wait`` children. Returns the device tokens and
         their host copy."""
-        with self._span("engine.decode", live=live, **work):
+        with self._span("engine.decode", live=live, **work) as span:
             with self._span("engine.decode.dispatch"):
-                tok, self.caches = self._step(
+                tok, self.caches, *stats = self._step(
                     self.params, next_token, self.caches, *tables
                 )
             with self._span("engine.decode.wait"):
-                return tok, np.asarray(tok)
+                tok_host, *stats = jax.device_get((tok, *stats))
+            self._note_routing(span, *stats)
+            return tok, tok_host
+
+    def _note_routing(self, span: dict, stats=None):
+        """Routing counters of one launch (rows of models/moe.STATS, one
+        per MoE layer) into the registry and the launch's span."""
+        if stats is None or not len(stats):
+            return
+        touched = int(stats[:, 1].sum())
+        self._c_touched.inc(touched)
+        for load in stats[:, 2]:
+            self._h_load.observe(float(load))
+        span["experts_touched"] = touched
+        span["expert_load_max"] = int(stats[:, 2].max())
 
     # --------------------------------------------------- lifecycle hooks
     def _note_submit(self, req: Request, *, resumed: bool = False):
@@ -444,6 +468,7 @@ class PagedServingEngine(_EngineTelemetry):
         page_size: int = 16,
         pages_per_seq_max: int = 16,
         prompt_pad: int = 64,
+        prefill_token_budget: Optional[int] = None,
         registry: Optional[MetricsRegistry] = None,
         tracer: Optional[TraceRecorder] = None,
     ):
@@ -451,6 +476,7 @@ class PagedServingEngine(_EngineTelemetry):
         self.params = params
         self.attn = attn_cfg
         self.B = max_batch
+        self.prefill_token_budget = prefill_token_budget
         self.ps = page_size
         self.n_max = pages_per_seq_max
         self.prompt_pad = prompt_pad
@@ -473,6 +499,10 @@ class PagedServingEngine(_EngineTelemetry):
         self._seq = 0  # admission order, for preempt-youngest
         self._slot_seq = np.zeros((max_batch,), np.int64)
         self._obs_init(registry, tracer)
+        if cfg.moe is not None:
+            self._c_touched = self.obs.counter("serving/moe_experts_touched")
+            self._h_load = self.obs.histogram("serving/moe_expert_load_max",
+                                              EXPERT_LOAD_BUCKETS)
         self.pool.register_metrics(self.obs)
         self._c_page_oom = self.obs.counter("serving/page_oom")
         self._splits: Optional[int] = None  # resolved on the first traced tick
@@ -522,19 +552,34 @@ class PagedServingEngine(_EngineTelemetry):
         pad = -(-L // self.prompt_pad) * self.prompt_pad
         return min(max(pad, self.prompt_pad), self.n_max * self.ps)
 
+    def admit_widths(self, bucket: int) -> List[int]:
+        """The admission widths a group of ``bucket``-token prompts can
+        launch at: powers of two up to ``max_batch`` whose launch stays
+        within ``prefill_token_budget`` (one row always can)."""
+        cap = self.B
+        if self.prefill_token_budget is not None:
+            cap = min(cap, max(1, self.prefill_token_budget // bucket))
+        return [1 << i for i in range(cap.bit_length()) if 1 << i <= cap]
+
     def _admit_tick(self):
-        """Strict-FIFO admission, then ONE batched prefill per bucket."""
+        """Strict-FIFO admission, then one batched prefill per bucket, a
+        bucket's group split into launches within the token budget."""
         with self._span("engine.schedule") as span:
             picks = self._pick()
             span["picked"] = len(picks)
-        # Group by bucket; one batched admit call per bucket.
+        # Group by bucket; one batched admit call per bucket and width cap.
         by_bucket: Dict[int, List[Tuple[int, Request, List[int]]]] = {}
         for pick in picks:
             by_bucket.setdefault(self._bucket(len(pick[1].feed)), []).append(pick)
+        launches = []
         for pad_to, group in sorted(by_bucket.items()):
+            cap = self.admit_widths(pad_to)[-1]
+            launches += [(pad_to, group[i: i + cap])
+                         for i in range(0, len(group), cap)]
+        for pad_to, group in launches:
             W = min(_next_pow2(len(group)), self.B)
             tokens = sum(len(req.feed) for _, req, _ in group)
-            with self._admit_span(len(group), W, pad_to, tokens):
+            with self._admit_span(len(group), W, pad_to, tokens) as span:
                 npb = -(-pad_to // self.ps)
                 inputs = np.zeros((W, pad_to), np.int32)
                 lens = np.ones((W,), np.int32)  # dummy rows: 1 token, null dest
@@ -546,14 +591,15 @@ class PagedServingEngine(_EngineTelemetry):
                     n_dest = min(-(-len(feed) // self.ps), npb)
                     dest[i, :n_dest] = pages[:n_dest]
                 t_pref0 = self._now_us()
-                tok, lens_total, self.caches = self._admit(
+                tok, lens_total, self.caches, *stats = self._admit(
                     self.params,
                     {"inputs": jnp.asarray(inputs), "lens": jnp.asarray(lens)},
                     self.caches,
                     jnp.asarray(dest),
                 )
-                tok_host = np.asarray(tok)
+                tok_host, *stats = jax.device_get((tok, *stats))
                 t_pref1 = self._now_us()
+                self._note_routing(span, *stats)
             for i, (slot, req, pages) in enumerate(group):
                 self._note_admission(req, pad_to, t_pref0, t_pref1)
                 self.table[slot] = 0
@@ -672,9 +718,11 @@ class PagedServingEngine(_EngineTelemetry):
         if self.tracer is None or self.attn.impl != "flash_pallas":
             return {}
         if self._splits is None:
+            width = (self.cfg.head_dim if self.cfg.mla is None
+                     else self.cfg.mla.latent_dim)  # the kernel's q width
             self._splits = paged_decode_splits(
                 self.attn, self.n_max, self.ps, self.cfg.num_heads,
-                self.cfg.head_dim, self.cfg.dtype)
+                width, self.cfg.dtype)
         # the step attends over the new token too; empty slots over nothing
         lens = np.where(self.cache_len > 0, self.cache_len + 1, 0)
         return paged_decode_work(lens, self.n_max, self.ps, self._splits)

@@ -87,6 +87,8 @@ _ASSIGNED = {
     "falcon-mamba-7b": dict(num_layers=64, d_model=4096),
     "internvl2-76b": dict(num_layers=80, d_model=8192, num_heads=64, d_ff=28672),
     "hymba-1.5b": dict(num_layers=32, d_model=1600, num_heads=25, num_kv_heads=5),
+    "deepseek-v2-lite": dict(num_layers=27, d_model=2048, num_heads=16,
+                             d_ff=10944, vocab_size=102400, first_dense_layers=1),
 }
 
 
@@ -106,10 +108,12 @@ def test_moe_configs():
     assert (g.num_experts, g.top_k) == (32, 8)
     m = registry.get("mixtral-8x22b").moe
     assert (m.num_experts, m.top_k) == (8, 2)
+    d = registry.get("deepseek-v2-lite").moe
+    assert (d.num_experts, d.top_k, d.num_shared, d.norm_topk) == (64, 6, 2, False)
 
 
 def test_every_cell_has_specs_or_skip():
-    """All 40 (arch x shape) cells: either a skip reason or well-formed
+    """All 44 (arch x shape) cells: either a skip reason or well-formed
     ShapeDtypeStruct specs with the cell's batch/seq."""
     n_ok = n_skip = 0
     for arch in registry.names():
@@ -126,4 +130,4 @@ def test_every_cell_has_specs_or_skip():
                 assert specs["token"].shape == (shape.global_batch, 1)
                 leaves = jax.tree.leaves(specs["caches"])
                 assert leaves, f"{arch}: empty cache specs"
-    assert n_ok + n_skip == 40 and n_skip == 6
+    assert n_ok + n_skip == 44 and n_skip == 7

@@ -169,3 +169,60 @@ def test_paged_decode_compiles(one_chip, batch, n_pages, pool):
              _shape((HK, pool, ps, D), one_chip),
              _shape((batch,), one_chip, jnp.int32),
              _shape((batch, n_pages), one_chip, jnp.int32))
+
+
+# DeepSeek-V2-Lite's widths (the dsv2lite-serve.longctx cell): 16 heads of
+# q/k width 192 and v width 128 in prefill; in decode one latent head of
+# 512 + 64 lanes (padded to 640) read by 16 absorbed queries; 64 experts
+# of width 1408.
+MLA_H, MLA_QK, MLA_V, MLA_W, MLA_R = 16, 192, 128, 640, 512
+
+
+def test_forward_narrow_v_compiles(one_chip):
+    """The prefill forward with V narrower than Q/K."""
+    def fwd(q, k, v):
+        return ops.flash_attention_pallas_with_lse(
+            q, k, v, MaskSpec(causal=True), interpret=False)
+
+    compiled = _compile(
+        fwd, _shape((1, S, MLA_H, MLA_QK), one_chip),
+        _shape((1, S, MLA_H, MLA_QK), one_chip),
+        _shape((1, S, MLA_H, MLA_V), one_chip))
+    assert "fa2_fwd" in compiled.as_text()
+
+
+def test_mla_decode_paged_compiles(one_chip):
+    """The paged kernel's latent mode at the cell's engine: 32 slots, a
+    1,857-page table, a 24,001-page pool."""
+    batch, n_pages, pool, ps = 32, 1857, 24001, 16
+
+    def dec(q, lat, lens, tbl):
+        return ops.mla_decode_paged_pallas(q, lat, lens, tbl, v_dim=MLA_R,
+                                           scale=0.1, interpret=False)
+
+    compiled = _compile(dec, _shape((batch, 1, MLA_H, MLA_W), one_chip),
+                        _shape((1, pool, ps, MLA_W), one_chip),
+                        _shape((batch,), one_chip, jnp.int32),
+                        _shape((batch, n_pages), one_chip, jnp.int32))
+    assert "mla_decode_paged" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", [32 * 6, 28672 * 6], ids=["decode", "prefill"])
+def test_moe_gmm_compiles(one_chip, rows):
+    """The grouped matmul of the dropless MoE: gate/up (d -> d_expert) and
+    down (d_expert -> d), at a decode step's and a full prefill launch's
+    routed rows."""
+    from repro.kernels.moe_gmm import gmm, tiling
+
+    d, de, E = 2048, 1408, 64
+    m = -(-rows // tiling(rows, d, de)[0]) * tiling(rows, d, de)[0]
+
+    def ffn(x, wg, wd, sizes):
+        h = gmm(x, wg, sizes, interpret=False)
+        return gmm(h, wd, sizes, interpret=False)
+
+    compiled = _compile(ffn, _shape((m, d), one_chip),
+                        _shape((E, d, de), one_chip),
+                        _shape((E, de, d), one_chip),
+                        _shape((E,), one_chip, jnp.int32))
+    assert "moe_gmm" in compiled.as_text()
