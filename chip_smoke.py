@@ -209,10 +209,13 @@ def kernel_phase(seed: int, *, B=1, batch_decode=8, page=16) -> None:
     table_np = (rng.permutation(Bd * n_pages) + 1).reshape(Bd, n_pages)
     table = jnp.asarray(table_np.astype(np.int32))
 
-    def to_pages(c):  # (B, S, Hk, D) -> (Hk, 1 + B*n_pages, page, D)
+    def to_pages(c):  # (B, S, Hk, D) -> layer 1 of (2, Hk, 1 + B*n_pages, page, D)
         pages = c.reshape(Bd * n_pages, page, HK, HD).transpose(2, 0, 1, 3)
         pool = jnp.zeros((HK, 1 + Bd * n_pages, page, HD), c.dtype)
-        return pool.at[:, table.reshape(-1)].set(pages)
+        pool = pool.at[:, table.reshape(-1)].set(pages)
+        # the kernel reads layer 1 of the stack; NaN in layer 0 shows a
+        # read of the wrong layer
+        return jnp.stack([jnp.full_like(pool, jnp.nan), pool])
 
     kp, vp = to_pages(kc), to_pages(vc)
     with jax.default_matmul_precision("highest"):
@@ -223,7 +226,7 @@ def kernel_phase(seed: int, *, B=1, batch_decode=8, page=16) -> None:
         ("contiguous", lambda q, k, v, n: ops.flash_decode_pallas(q, k, v, n),
          (qd, kc, vc, lens)),
         (f"paged ps={page}",
-         lambda q, k, v, n, t: ops.flash_decode_paged_pallas(q, k, v, n, t),
+         lambda q, k, v, n, t: ops.flash_decode_paged_pallas(q, k, v, n, t, 1),
          (qd, kp, vp, lens, table)),
     ):
         compiled, secs = compile_kernel(fn, *args)

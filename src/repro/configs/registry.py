@@ -175,14 +175,16 @@ def cache_specs(cfg: ModelConfig, B: int, cache: int, enc_frames: int = 1500):
 
 
 def paged_cache_specs(cfg: ModelConfig, num_pages: int, page_size: int):
-    """Cache tree for the *paged* serving engine: per-layer physical page
-    planes ``(Hkv, num_pages, page_size, head_dim)`` shared by all resident
-    sequences through one block table (serving/kv_pool.py). Same tree
-    structure as :func:`cache_specs` (scan-stacked groups + tail) so
-    lm.decode_step's scan machinery is unchanged; the leaf layout is
-    kernel-native for kernels/flash_decode.flash_decode_paged_kernel (the
-    contiguous path's per-step (B,S,Hk,D) -> head-major transpose is gone).
-    Attention-only: a page holds no recurrent SSM state.
+    """Cache tree for the *paged* serving engine: physical page planes
+    ``(Hkv, num_pages, page_size, head_dim)`` per layer, shared by all
+    resident sequences through one block table (serving/kv_pool.py). Same
+    tree structure as :func:`cache_specs`, but every leaf is a stack of
+    layer planes ``(layers, Hkv, num_pages, page_size, head_dim)``: the
+    scanned groups' stacked, a lead, tail or unscanned layer's a stack of
+    one. kernels/flash_decode.flash_decode_paged_kernel reads a stack at a
+    layer index, and lm.decode_step carries the groups' stacks through its
+    scan (DESIGN.md Section 5.3). Attention-only: a page holds no
+    recurrent SSM state.
 
     Latent attention (``cfg.mla``) pages one plane per layer,
     ``(1, num_pages, page_size, mla.cache_dim)``: the normalised latent
@@ -195,25 +197,26 @@ def paged_cache_specs(cfg: ModelConfig, num_pages: int, page_size: int):
         k in ("attn", "attn_local") for k in kinds
     ), "paged caches serve attention-only decoder configs"
 
-    def layer_spec():
+    def planes(n: int):  # n layers' planes, stacked
         if cfg.mla is not None:
-            shape = (1, num_pages, page_size, cfg.mla.cache_dim)
+            shape = (n, 1, num_pages, page_size, cfg.mla.cache_dim)
             return {"latent": _sds(shape, act)}
-        shape = (cfg.num_kv_heads, num_pages, page_size, cfg.head_dim)
+        shape = (n, cfg.num_kv_heads, num_pages, page_size, cfg.head_dim)
         return {"kv": {"k": _sds(shape, act), "v": _sds(shape, act)}}
+
+    def group(n: int):
+        return {f"slot_{u}": planes(n) for u in range(len(cfg.layer_pattern))}
 
     caches: Dict[str, Any] = {}
     if cfg.lead_pattern:
-        caches["lead"] = [layer_spec() for _ in cfg.lead_pattern]
+        caches["lead"] = [planes(1) for _ in cfg.lead_pattern]
     if cfg.num_groups:
-        group = {f"slot_{u}": layer_spec()
-                 for u in range(len(cfg.layer_pattern))}
         if cfg.scan_layers and cfg.num_groups > 1:
-            caches["groups"] = _stack(group, cfg.num_groups)
+            caches["groups"] = group(cfg.num_groups)
         else:
-            caches["groups"] = [group for _ in range(cfg.num_groups)]
+            caches["groups"] = [group(1) for _ in range(cfg.num_groups)]
     if cfg.tail_pattern:
-        caches["tail"] = [layer_spec() for _ in cfg.tail_pattern]
+        caches["tail"] = [planes(1) for _ in cfg.tail_pattern]
     return caches
 
 

@@ -197,68 +197,72 @@ def paged_decode_splits(cfg: AttentionConfig, n_pages: int, page_size: int,
 
 def decode_attention_paged(
     q: jnp.ndarray,  # (B, 1, Hq, D)
-    k_pages: jnp.ndarray,  # (Hkv, P, page_size, D) pool planes
+    k_pages: jnp.ndarray,  # (layers, Hkv, P, page_size, D) stacked planes
     v_pages: jnp.ndarray,
     cache_length: jnp.ndarray,  # (B,) int32 logical lengths
     block_table: jnp.ndarray,  # (B, n_pages) int32
     cfg: AttentionConfig = AttentionConfig(),
     *,
+    layer=0,  # int32 scalar: the layer of the stack to read
     window: Optional[int] = None,
     sink: int = 0,
     scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Single-token decode against a *paged* KV cache. Returns (B,1,Hq,D).
 
-    The cache is the pool's physical page planes plus a per-sequence block
-    table (serving/kv_pool.py); rows with ``cache_length == 0`` (all-null
+    The cache is the pool's physical page planes, stacked over layers and
+    read at ``layer``, plus a per-sequence block table
+    (serving/kv_pool.py); rows with ``cache_length == 0`` (all-null
     table) read no KV at all on the Pallas path. ``cfg.decode_splits=None``
     resolves the split fan-out from the tuned cache keyed on the *logical*
     capacity ``n_pages * page_size`` and the page size
     (kernels/autotune.resolve_decode_splits)."""
-    splits = paged_decode_splits(cfg, block_table.shape[1], k_pages.shape[2],
+    splits = paged_decode_splits(cfg, block_table.shape[1], k_pages.shape[3],
                                  q.shape[2], q.shape[3], q.dtype)
     if cfg.impl == "flash_pallas":
         from repro.kernels.ops import flash_decode_paged_pallas
 
         return flash_decode_paged_pallas(
-            q, k_pages, v_pages, cache_length, block_table,
+            q, k_pages, v_pages, cache_length, block_table, layer,
             window=window, sink=sink, scale=scale, num_splits=splits,
             interpret=cfg.interpret,
         )[0]
     return _decode.flash_decode_paged(
-        q, k_pages, v_pages, cache_length, block_table,
+        q, k_pages, v_pages, cache_length, block_table, layer,
         window=window, sink=sink, scale=scale, num_splits=splits,
     )[0]
 
 
 def decode_attention_latent_paged(
     q: jnp.ndarray,  # (B, 1, H, W) absorbed latent queries
-    latent_pages: jnp.ndarray,  # (1, P, page_size, W) pool planes
+    latent_pages: jnp.ndarray,  # (layers, 1, P, page_size, W) stacked planes
     cache_length: jnp.ndarray,  # (B,) int32 logical lengths
     block_table: jnp.ndarray,  # (B, n_pages) int32
     cfg: AttentionConfig = AttentionConfig(),
     *,
     v_dim: int,
     scale: float,
+    layer=0,  # int32 scalar: the layer of the stack to read
 ) -> jnp.ndarray:
     """Latent-attention decode against a paged latent cache. Returns
     (B, 1, H, v_dim): every head reads each cached latent token of width W
     once as its key, and its first ``v_dim`` lanes as its value
     (models/mla.py). The Pallas path is the paged decode kernel's latent
     mode (device events ``mla_decode_paged``); the XLA path runs the paged
-    decode with the value lanes past ``v_dim`` zeroed."""
+    decode with the planes as V and keeps the first ``v_dim`` lanes."""
     splits = paged_decode_splits(cfg, block_table.shape[1],
-                                 latent_pages.shape[2], q.shape[2],
+                                 latent_pages.shape[3], q.shape[2],
                                  q.shape[3], q.dtype)
     if cfg.impl == "flash_pallas":
         from repro.kernels.ops import mla_decode_paged_pallas
 
         return mla_decode_paged_pallas(
-            q, latent_pages, cache_length, block_table, v_dim=v_dim,
+            q, latent_pages, cache_length, block_table, layer, v_dim=v_dim,
             scale=scale, num_splits=splits, interpret=cfg.interpret,
         )[0]
-    v = jnp.where(jnp.arange(latent_pages.shape[-1]) < v_dim, latent_pages, 0)
+    # An output lane reads only its own V lane: the lanes past ``v_dim``
+    # are cut after, so the planes serve as V whole.
     return _decode.flash_decode_paged(
-        q, latent_pages, v, cache_length, block_table, scale=scale,
-        num_splits=splits,
+        q, latent_pages, latent_pages, cache_length, block_table, layer,
+        scale=scale, num_splits=splits,
     )[0][..., :v_dim]

@@ -102,10 +102,11 @@ def flash_decode(
 
 def flash_decode_paged(
     q: jnp.ndarray,  # (B, 1, Hq, D)
-    k_pages: jnp.ndarray,  # (Hkv, P, page_size, D) physical page planes
+    k_pages: jnp.ndarray,  # (layers, Hkv, P, page_size, D) stacked planes
     v_pages: jnp.ndarray,
     cache_length: jnp.ndarray,  # (B,) int32 logical lengths
     block_table: jnp.ndarray,  # (B, n_pages) int32 logical -> physical page
+    layer=0,  # int32 scalar: the layer of the stack to read
     *,
     window: Optional[int] = None,
     sink: int = 0,
@@ -113,20 +114,47 @@ def flash_decode_paged(
     num_splits: int = 8,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """XLA fallback for page-indirect decode: gather the block table's
-    pages into a contiguous (B, n_pages*ps, Hkv, D) view, then run the
-    plain split-KV decode. Functionally the oracle for the Pallas kernel
-    (tests assert parity); positions >= cache_length are masked, so stale
-    or null-page contents never contribute."""
+    pages of ``layer`` into a contiguous (B, n_pages*ps, Hkv, D) view, then
+    run the plain split-KV decode. Functionally the oracle for the Pallas
+    kernel (tests assert parity); positions >= cache_length are masked, so
+    stale or null-page contents never contribute."""
     B = q.shape[0]
-    Hk, _, ps, D = k_pages.shape
+    _, Hk, _, ps, D = k_pages.shape
     n_pages = block_table.shape[1]
     tbl = block_table.astype(jnp.int32)
-    # (Hk, B, n_pages, ps, D) -> (B, n_pages*ps, Hk, D)
+    # (B, n_pages, Hk, ps, D) -> (B, n_pages*ps, Hk, D)
     def gather(pages):
-        g = pages[:, tbl]
-        return jnp.moveaxis(g, 0, 3).reshape(B, n_pages * ps, Hk, D)
+        g = pages[layer, :, tbl]
+        return jnp.swapaxes(g, 2, 3).reshape(B, n_pages * ps, Hk, D)
 
     return flash_decode(
         q, gather(k_pages), gather(v_pages), cache_length,
         window=window, sink=sink, scale=scale, num_splits=num_splits,
     )
+
+
+def paged_write(planes, layer, page, new, off=None):
+    """Write new cache entries into the stacked pool planes
+    ``(layers, H, P, ps, D)``, in place once the caller's pool is donated.
+
+    ``off`` None writes whole pages: ``new`` (..., H, ps, D) at physical
+    ``page`` of ``layer``; else token rows: ``new`` (..., H, D) at offset
+    ``off`` of ``page``. ``layer``, ``page`` and ``off`` broadcast to
+    ``new``'s leading dims. The write is a scatter of whole leading-axis
+    slices of a flat view of the planes, ``(layers*H*P, ps, D)`` or
+    ``(layers*H*P*ps, D)`` (a bitcast of their default layout), which XLA
+    applies to the buffer as it is laid out; a scatter indexed by page and
+    offset together makes XLA lay the planes out otherwise and copy them to
+    and from that layout around the decode kernel (DESIGN.md Section 5.3).
+    """
+    L, H, P, ps, D = planes.shape
+    idx = (jnp.asarray(layer)[..., None] * H + jnp.arange(H)) * P
+    idx = idx + jnp.asarray(page)[..., None]
+    if off is None:
+        flat = planes.reshape(L * H * P, ps, D)
+    else:
+        flat = planes.reshape(L * H * P * ps, D)
+        idx = idx * ps + jnp.asarray(off)[..., None]
+    flat = flat.at[idx.reshape(-1)].set(
+        new.reshape((-1,) + flat.shape[1:]).astype(planes.dtype))
+    return flat.reshape(planes.shape)

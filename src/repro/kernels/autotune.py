@@ -464,7 +464,8 @@ def sweep_decode_shape(
 
 def _paged_fixture(seq, heads, head_dim, batch, page_size, dt):
     """Random paged-decode operands at full logical occupancy, with the
-    physical pages deliberately shuffled (the serving steady state)."""
+    physical pages deliberately shuffled (the serving steady state). The
+    planes are a stack of one layer, read at layer 0."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -473,8 +474,8 @@ def _paged_fixture(seq, heads, head_dim, batch, page_size, dt):
     P = batch * n_pages + 1  # + the reserved null page 0
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(2), 3)
     q = jax.random.normal(kq, (batch, 1, heads, head_dim), jnp.float32).astype(dt)
-    kp = jax.random.normal(kk, (heads, P, page_size, head_dim), jnp.float32).astype(dt)
-    vp = jax.random.normal(kv, (heads, P, page_size, head_dim), jnp.float32).astype(dt)
+    kp = jax.random.normal(kk, (1, heads, P, page_size, head_dim), jnp.float32).astype(dt)
+    vp = jax.random.normal(kv, (1, heads, P, page_size, head_dim), jnp.float32).astype(dt)
     perm = np.random.default_rng(0).permutation(P - 1) + 1
     tbl = jnp.asarray(perm.reshape(batch, n_pages), jnp.int32)
     lens = jnp.full((batch,), seq, jnp.int32)

@@ -233,9 +233,10 @@ def _paged_decode_kernel(
     """One (slot, split, block) step of the page-indirect decode.
 
     Refs, in order: scalar prefetch ``tbl_ref`` (B, ns*nb*ppb) int32 block
-    table and ``len_ref`` (B,) int32 logical lengths; ``q_ref`` (Hk, G, D),
-    the slot's queries for every KV head; the page planes ``k_hbm`` and
-    ``v_hbm`` (Hk, P, ps, D), left in HBM; ``o_ref`` (Hk, G, Dv) and
+    table, ``len_ref`` (B,) int32 logical lengths and ``layer_ref`` (1,)
+    int32, the layer whose planes are read; ``q_ref`` (Hk, G, D), the
+    slot's queries for every KV head; the stacked page planes ``k_hbm`` and
+    ``v_hbm`` (layers, Hk, P, ps, D), left in HBM; ``o_ref`` (Hk, G, Dv) and
     ``lse_ref`` (Hk, 1, G); scratch ``k_buf`` and ``v_buf`` VMEM
     (2, Hk, ppb*ps, D) (the block being computed + the next), ``sems`` DMA
     (2, 2) [buffer, k|v], ``walk`` SMEM (2,) int32 [buffer of this block, a
@@ -256,12 +257,12 @@ def _paged_decode_kernel(
     when one split == one page -- tests/test_paged.py pins it).
     """
     if v_dim is None:
-        (tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, lse_ref, k_buf, v_buf,
-         sems, walk, m_scr, l_scr, acc_scr) = refs
+        (tbl_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref, lse_ref,
+         k_buf, v_buf, sems, walk, m_scr, l_scr, acc_scr) = refs
         planes = ((k_hbm, k_buf), (v_hbm, v_buf))
     else:
-        (tbl_ref, len_ref, q_ref, k_hbm, o_ref, lse_ref, k_buf, sems, walk,
-         m_scr, l_scr, acc_scr) = refs
+        (tbl_ref, len_ref, layer_ref, q_ref, k_hbm, o_ref, lse_ref, k_buf,
+         sems, walk, m_scr, l_scr, acc_scr) = refs
         planes = ((k_hbm, k_buf),)
     b, c, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     B = pl.num_programs(0)
@@ -280,13 +281,14 @@ def _paged_decode_kernel(
         pages into buffer ``buf``: one descriptor per page and tensor, all
         KV heads at once."""
         s, page0 = u // per_slot, (u % per_slot) * ppb
+        layer = layer_ref[0]
 
         @pl.loop(0, _block_pages(len_ref[s], page0, ps, ppb))
         def _(i):
             phys = tbl_ref[s, page0 + i]
             rows = pl.ds(pl.multiple_of(i * ps, ps), ps)
             for x, (src, dst) in enumerate(planes):
-                getattr(pltpu.make_async_copy(src.at[:, phys],
+                getattr(pltpu.make_async_copy(src.at[layer, :, phys],
                                               dst.at[buf, :, rows],
                                               sems.at[buf, x]), act)()
 
@@ -377,10 +379,11 @@ def _paged_decode_kernel(
 
 def flash_decode_paged_kernel(
     q: jnp.ndarray,  # (B, Hk, G, D) pre-scaled
-    k_pages: jnp.ndarray,  # (Hk, P, ps, D) physical page planes
+    k_pages: jnp.ndarray,  # (layers, Hk, P, ps, D) stacked page planes
     v_pages: Optional[jnp.ndarray],  # same, or None in latent mode
     lengths: jnp.ndarray,  # (B,) int32 logical lengths
     block_table: jnp.ndarray,  # (B, n_pages) int32 logical -> physical page
+    layer=0,  # int32 scalar: the layer of the stack to read
     *,
     num_splits: int = 8,
     window: Optional[int] = None,
@@ -394,7 +397,11 @@ def flash_decode_paged_kernel(
     ``nb`` blocks of ``ppb`` *logical* pages; a step copies the physical
     pages ``tbl[b, page]`` its block holds for every KV head straight from
     the pool planes (scalar-prefetched table and lengths, the same contract
-    as kernels/schedule.py), double-buffered across steps. Physical page
+    as kernels/schedule.py), double-buffered across steps. The planes are
+    the pool's stack of every layer's planes, read at ``layer`` (a third
+    scalar prefetch), so a layer scan hands the kernel the stack it carries
+    and no layer's plane is sliced out (DESIGN.md Section 5.3); one
+    layer's planes are passed as a stack of one at layer 0. Physical page
     order is irrelevant to the math (shuffle-invariance is tested
     bitwise). Table entries past a sequence's live pages should name the
     null page (0); they are never copied, whatever they hold.
@@ -409,7 +416,7 @@ def flash_decode_paged_kernel(
     """
     interpret = resolve_interpret(interpret)
     B, Hk, G, D = q.shape
-    _, _, ps, _ = k_pages.shape
+    ps = k_pages.shape[3]
     n_pages = block_table.shape[1]
     ns, nb, ppb = paged_decode_geometry(n_pages, ps, num_splits)
     pad = ns * nb * ppb - n_pages
@@ -434,7 +441,7 @@ def flash_decode_paged_kernel(
     )
     buf = pltpu.VMEM((2, Hk, ppb * ps, D), k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block table + lengths
+        num_scalar_prefetch=3,  # block table, lengths, layer
         grid=(B, ns, nb),
         in_specs=[
             pl.BlockSpec((None, Hk, G, D), lambda b, c, j, *_: (b, 0, 0, 0)),
@@ -468,4 +475,5 @@ def flash_decode_paged_kernel(
         cost_estimate=cost,
         interpret=interpret,
         name="mla_decode_paged" if latent else "fa2_decode_paged",
-    )(tbl, lengths.astype(jnp.int32), q, *planes)
+    )(tbl, lengths.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      q, *planes)

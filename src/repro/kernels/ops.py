@@ -726,18 +726,19 @@ def flash_decode_pallas(
 
 
 def flash_decode_paged_pallas(
-    q, k_pages, v_pages, cache_length, block_table, *,
+    q, k_pages, v_pages, cache_length, block_table, layer=0, *,
     window: Optional[int] = None, sink: int = 0, scale: Optional[float] = None,
     num_splits: int = 8, interpret: Optional[bool] = None,
 ):
-    """Page-indirect split-KV decode. q (B,1,Hq,D); k/v_pages (Hkv,P,ps,D);
-    cache_length (B,) logical lengths; block_table (B, n_pages) int32
-    physical page ids (0 = the reserved null page). Returns (o, lse) with
-    the same contract as :func:`flash_decode_pallas` -- the serving engine
-    swaps a contiguous cache for pool planes without touching the merge."""
+    """Page-indirect split-KV decode. q (B,1,Hq,D); k/v_pages the stacked
+    pool planes (layers, Hkv, P, ps, D), read at ``layer``; cache_length
+    (B,) logical lengths; block_table (B, n_pages) int32 physical page ids
+    (0 = the reserved null page). Returns (o, lse) with the same contract
+    as :func:`flash_decode_pallas` -- the serving engine swaps a contiguous
+    cache for pool planes without touching the merge."""
     B, one, Hq, D = q.shape
     assert one == 1
-    Hk = k_pages.shape[0]
+    Hk = k_pages.shape[1]
     G = Hq // Hk
     if scale is None:
         scale = 1.0 / math.sqrt(D)
@@ -745,7 +746,8 @@ def flash_decode_paged_pallas(
     qh = qh.reshape(B, Hk, G, D)
     o_parts, lse_parts = _dec.flash_decode_paged_kernel(
         qh, k_pages, v_pages, cache_length.astype(jnp.int32), block_table,
-        num_splits=num_splits, window=window, sink=sink, interpret=interpret,
+        layer, num_splits=num_splits, window=window, sink=sink,
+        interpret=interpret,
     )
     # (B, ns, Hk, ...) -> (ns, B*Hk, ...): the contiguous path's merge.
     ns = o_parts.shape[1]
@@ -760,19 +762,20 @@ def flash_decode_paged_pallas(
 
 
 def mla_decode_paged_pallas(
-    q, latent_pages, cache_length, block_table, *, v_dim: int, scale: float,
-    num_splits: int = 8, interpret: Optional[bool] = None,
+    q, latent_pages, cache_length, block_table, layer=0, *, v_dim: int,
+    scale: float, num_splits: int = 8, interpret: Optional[bool] = None,
 ):
     """Latent-attention decode through the paged kernel's latent mode.
-    q (B,1,H,W) absorbed latent queries; latent_pages (1,P,ps,W) pool
-    planes; returns (o (B,1,H,v_dim), lse (B,H,1)): every head reads each
-    cached latent token once, as K, and its first ``v_dim`` lanes as V."""
+    q (B,1,H,W) absorbed latent queries; latent_pages the stacked pool
+    planes (layers, 1, P, ps, W), read at ``layer``; returns (o (B,1,H,
+    v_dim), lse (B,H,1)): every head reads each cached latent token once,
+    as K, and its first ``v_dim`` lanes as V."""
     B, one, H, W = q.shape
-    assert one == 1 and latent_pages.shape[0] == 1
+    assert one == 1 and latent_pages.shape[1] == 1
     qh = (q.astype(jnp.float32) * scale).astype(q.dtype).reshape(B, 1, H, W)
     o_parts, lse_parts = _dec.flash_decode_paged_kernel(
         qh, latent_pages, None, cache_length.astype(jnp.int32), block_table,
-        num_splits=num_splits, v_dim=v_dim, interpret=interpret,
+        layer, num_splits=num_splits, v_dim=v_dim, interpret=interpret,
     )
     ns = o_parts.shape[1]
     o, lse = combine_lse_outputs(

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.attention import AttentionConfig
+from repro.core.decode import paged_write
 from repro.models import lm, whisper
 from repro.training.losses import chunked_cross_entropy
 from repro.training.optimizer import AdamWConfig, OptState, apply_updates
@@ -169,8 +170,10 @@ def build_paged_serve_step(cfg: ModelConfig, attn_cfg: AttentionConfig):
 def build_paged_admit_step(cfg: ModelConfig, attn_cfg: AttentionConfig,
                            page_size: int):
     """Batched admission: one lens-masked bucketed prefill for a whole
-    same-bucket group, its contiguous caches scattered straight into the
-    pool's page planes at the ``dest`` physical pages.
+    same-bucket group, its contiguous caches written straight into the
+    pool's page planes at the ``dest`` physical pages, every layer's in
+    one page write (core.decode.paged_write, in place once the pool is
+    donated).
 
     ``batch['inputs']`` (W, pad_to) right-padded prompts, ``batch['lens']``
     (W,) true lengths, ``dest`` (W, pad_to_pages) int32 physical page per
@@ -181,12 +184,14 @@ def build_paged_admit_step(cfg: ModelConfig, attn_cfg: AttentionConfig,
     with_stats = cfg.moe is not None
 
     def scatter(paged, contig, dest):
-        # contig (..., W, S, Hk, hd) -> pages (..., Hk, W, NP, ps, hd)
-        *lead, W, S, Hk, hd = contig.shape
-        NP = S // page_size
-        v = contig.reshape(*lead, W, NP, page_size, Hk, hd)
-        v = jnp.moveaxis(v, -2, -5)  # head plane first, like the pool
-        return paged.at[..., dest, :, :].set(v.astype(paged.dtype))
+        # contig ([L,] W, S, Hk, hd) -> pages (L, W, NP, Hk, ps, hd) for the
+        # pool's stack of L layers' planes
+        L = paged.shape[0]
+        W, S, Hk, hd = contig.shape[-4:]
+        pages = contig.reshape(L, W, S // page_size, page_size, Hk, hd)
+        pages = jnp.moveaxis(pages, 4, 3)
+        return paged_write(paged, jnp.arange(L)[:, None, None], dest[None],
+                           pages)
 
     def admit_step(params, batch, caches, dest):
         tokens = batch["inputs"]

@@ -20,6 +20,7 @@ from repro.core.attention import (
     decode_attention,
     decode_attention_paged,
 )
+from repro.core.decode import paged_write
 from repro.core.masks import MaskSpec
 from repro.distributed import sharding as shd
 from repro.distributed.context_parallel import gather_kv
@@ -176,7 +177,7 @@ def prefill_attention(
 def decode_attention_step(
     p, cfg, x_new: jnp.ndarray, cache: dict, cache_len: jnp.ndarray,
     attn_cfg: AttentionConfig, *, rope_theta=None, window=None, sink: int = 0,
-    block_table: Optional[jnp.ndarray] = None,
+    block_table: Optional[jnp.ndarray] = None, layer=0,
 ) -> Tuple[jnp.ndarray, dict]:
     """One decode step. x_new (B,1,d); cache_len (B,) = number of valid
     entries BEFORE this token.
@@ -185,9 +186,11 @@ def decode_attention_step(
     KV is inserted at position cache_len.
 
     Paged cache (``block_table`` (B, n_pages) int32): cache k/v are the
-    pool's physical page planes (Hk, P, page_size, hd); the new KV scatters
-    into page ``table[b, L // ps]`` at offset ``L % ps`` and attention runs
-    page-indirect (core.attention.decode_attention_paged). Rows with
+    pool's physical page planes stacked over layers (L, Hk, P, page_size,
+    hd), this layer's at index ``layer``; the new KV is written into page
+    ``table[b, L // ps]`` at offset ``L % ps`` of that layer
+    (core.decode.paged_write, in place) and attention runs page-indirect on
+    the stack (core.attention.decode_attention_paged). Rows with
     cache_len == 0 are *inactive slots* (a real sequence always has a
     non-empty prompt): their write lands in the reserved null page 0 and
     their attention length is forced to 0, so a free/finished slot costs no
@@ -201,20 +204,17 @@ def decode_attention_step(
         k_new = apply_rope(k_new, pos, rope_theta)
 
     if block_table is not None:
-        ps = cache["k"].shape[2]
+        ps = cache["k"].shape[3]
         page = jnp.take_along_axis(
             block_table, (cache_len // ps)[:, None], axis=1
         )[:, 0]  # (B,) physical page of the write position
         off = cache_len % ps
-        def scatter(planes, new):
-            vals = new[:, 0].transpose(1, 0, 2)  # (Hk, B, hd)
-            return planes.at[:, page, off].set(vals.astype(planes.dtype))
-        k_pages = scatter(cache["k"], k_new)
-        v_pages = scatter(cache["v"], v_new)
+        k_pages = paged_write(cache["k"], layer, page, k_new[:, 0], off)
+        v_pages = paged_write(cache["v"], layer, page, v_new[:, 0], off)
         lengths = jnp.where(cache_len > 0, cache_len + 1, 0)
         o = decode_attention_paged(
             q, k_pages, v_pages, lengths, block_table, attn_cfg,
-            window=window, sink=sink,
+            window=window, sink=sink, layer=layer,
         )
         return _out(p, cfg, o), {"k": k_pages, "v": v_pages}
 

@@ -186,19 +186,22 @@ def prefill_layer(kind, p, cfg, x, positions, attn_cfg, cache_size, valid=None):
     return constrain(x, "batch", "seq", "embed"), cache, stats
 
 
-def decode_layer(kind, p, cfg, x, cache, cache_len, attn_cfg, block_table=None):
-    """-> (x, new cache, MoE stats or None)."""
+def decode_layer(kind, p, cfg, x, cache, cache_len, attn_cfg, block_table=None,
+                 layer=0):
+    """-> (x, new cache, MoE stats or None). Paged (``block_table``), the
+    cache leaves are stacks of layer planes and this layer's is ``layer``."""
     h = L.apply_norm(p["ln1"], x, cfg.norm_eps, cfg.norm)
     spec = _spec_for(cfg, kind)
     theta = _theta_for(cfg, kind)
     if kind in ("attn", "attn_local") and cfg.mla is not None:
         mix, new_cache = decode_mla_step(p["mixer"], cfg, h, cache, cache_len,
-                                         attn_cfg, block_table=block_table)
+                                         attn_cfg, block_table=block_table,
+                                         layer=layer)
     elif kind in ("attn", "attn_local"):
         mix, kv = decode_attention_step(
             p["mixer"], cfg, h, cache["kv"], cache_len, attn_cfg,
             rope_theta=theta, window=spec.window, sink=spec.sink,
-            block_table=block_table,
+            block_table=block_table, layer=layer,
         )
         new_cache = {"kv": kv}
     elif kind == "mamba":
@@ -392,11 +395,17 @@ def prefill(cfg, params, tokens, attn_cfg: AttentionConfig, cache_size: int,
     return out + (stats,) if with_stats else out
 
 
-def _serve_layers(cfg, params, h, layer, group_body, caches_in=None):
+def _serve_layers(cfg, params, h, layer, group_body, caches_in=None,
+                  carry=False):
     """Run lead, grouped and tail layers of prefill (``caches_in`` None)
     or decode: ``layer(kind, p, x[, cache])`` -> (x, cache, stats) and
     ``group_body`` the scan body over groups. Returns (h, caches, stats
-    (n_moe_layers, 3))."""
+    (n_moe_layers, 3)).
+
+    ``carry`` (paged decode): the scanned groups' caches ride in the scan's
+    carry, whole, with the group index ``g``, and ``group_body(x, (gp,
+    caches), g)`` updates them at ``g``: no group's planes are sliced out
+    as ``xs`` or written back as ``ys``."""
     caches: Dict[str, Any] = {}
     stats = []
     with_cache = caches_in is not None
@@ -409,7 +418,16 @@ def _serve_layers(cfg, params, h, layer, group_body, caches_in=None):
             stats.append(st)
     if cfg.num_groups:
         xs = (params["groups"], caches_in["groups"]) if with_cache else params["groups"]
-        if cfg.scan_layers and cfg.num_groups > 1:
+        if cfg.scan_layers and cfg.num_groups > 1 and carry:
+            def body(c, gp):
+                x, stack, g = c
+                x, (stack, st) = group_body(x, (gp, stack), g)
+                return (x, stack, g + 1), st
+
+            (h, caches["groups"], _), st = jax.lax.scan(
+                body, (h, caches_in["groups"], jnp.int32(0)), params["groups"])
+            stats.append(st)
+        elif cfg.scan_layers and cfg.num_groups > 1:
             h, (caches["groups"], st) = jax.lax.scan(group_body, h, xs)
             stats.append(st)
         else:
@@ -437,9 +455,13 @@ def decode_step(cfg, params, token, caches, cache_len, attn_cfg: AttentionConfig
     ``block_table`` (B, n_pages) int32 switches every attention layer to
     the paged cache path (pool page planes instead of per-slot contiguous
     caches -- see attention_layer.decode_attention_step); the table is
-    shared by all layers. ``with_stats`` also returns the MoE layers'
-    routing counters over the live rows (cache_len > 0), (n_moe_layers, 3)
-    int32 (models/moe.STATS)."""
+    shared by all layers. The pool is then updated in place: the scanned
+    groups' stacked planes are carried through the scan, each layer
+    writing its new rows into them and reading them at its group index,
+    and a lead, tail or unscanned layer's stack of one is read at index 0
+    (registry.paged_cache_specs, DESIGN.md Section 5.3). ``with_stats``
+    also returns the MoE layers' routing counters over the live rows
+    (cache_len > 0), (n_moe_layers, 3) int32 (models/moe.STATS)."""
     h = L.embed_tokens(params["embed"], token)
     if cfg.embed_scale_by_dim:
         h = (h.astype(jnp.float32) * (cfg.d_model ** 0.5)).astype(h.dtype)
@@ -447,21 +469,21 @@ def decode_step(cfg, params, token, caches, cache_len, attn_cfg: AttentionConfig
         pos_e = jnp.take(params["embed"]["positions"], cache_len, axis=0)[:, None]
         h = h + pos_e.astype(h.dtype)
 
-    def layer(kind, p, x, cache):
+    def layer(kind, p, x, cache, g=0):
         return decode_layer(kind, p, cfg, x, cache, cache_len, attn_cfg,
-                            block_table)
+                            block_table, g)
 
-    def group_body(x, gp_cache):
+    def group_body(x, gp_cache, g=0):
         gp, cache = gp_cache
         new_caches, stats = {}, []
         for u, kind in enumerate(cfg.layer_pattern):
             x, new_caches[f"slot_{u}"], st = layer(
-                kind, gp[f"slot_{u}"], x, cache[f"slot_{u}"])
+                kind, gp[f"slot_{u}"], x, cache[f"slot_{u}"], g)
             stats.append(st)
         return x, (new_caches, _stack_stats(stats))
 
     h, new_caches, stats = _serve_layers(cfg, params, h, layer, group_body,
-                                         caches)
+                                         caches, carry=block_table is not None)
     h = L.apply_norm(params["ln_f"], h, cfg.norm_eps, cfg.norm)
     logits = logits_from_hidden(cfg, params, h)
     return (logits, new_caches, stats) if with_stats else (logits, new_caches)

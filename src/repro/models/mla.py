@@ -36,6 +36,7 @@ from repro.core.attention import (
     decode_attention,
     decode_attention_latent_paged,
 )
+from repro.core.decode import paged_write
 from repro.core.masks import MaskSpec
 from repro.distributed.sharding import constrain
 from repro.models.layers import (
@@ -160,12 +161,13 @@ def prefill_mla(p, cfg, x, positions, spec, attn_cfg, *,
 
 def decode_mla_step(p, cfg, x_new, cache: dict, cache_len: jnp.ndarray,
                     attn_cfg: AttentionConfig, *,
-                    block_table: Optional[jnp.ndarray] = None
+                    block_table: Optional[jnp.ndarray] = None, layer=0
                     ) -> Tuple[jnp.ndarray, dict]:
     """One absorbed-form decode step. x_new (B,1,d); cache_len (B,) entries
     before this token. The new latent token is written at ``cache_len``
-    (contiguous ``(B,S,1,w)`` cache, or paged planes ``(1,P,ps,w)`` through
-    ``block_table``, inactive rows to the null page as in
+    (contiguous ``(B,S,1,w)`` cache, or through ``block_table`` into the
+    paged planes stacked over layers ``(L,1,P,ps,w)`` at ``layer``,
+    inactive rows to the null page as in
     attention_layer.decode_attention_step), then the latent query attends
     to the cache."""
     m = cfg.mla
@@ -179,13 +181,14 @@ def decode_mla_step(p, cfg, x_new, cache: dict, cache_len: jnp.ndarray,
     scale = softmax_scale(cfg)
     lat = cache["latent"]
     if block_table is not None:
-        ps = lat.shape[2]
+        ps = lat.shape[3]
         page = jnp.take_along_axis(block_table, (cache_len // ps)[:, None],
                                    axis=1)[:, 0]
-        lat = lat.at[0, page, cache_len % ps].set(new[:, 0].astype(lat.dtype))
+        lat = paged_write(lat, layer, page, new, cache_len % ps)
         lengths = jnp.where(cache_len > 0, cache_len + 1, 0)
         o_lat = decode_attention_latent_paged(
-            q, lat, lengths, block_table, attn_cfg, v_dim=r, scale=scale)
+            q, lat, lengths, block_table, attn_cfg, v_dim=r, scale=scale,
+            layer=layer)
     else:
         lat = jax.vmap(lambda b, n, i: jax.lax.dynamic_update_slice_in_dim(
             b, n, i, axis=0))(lat, new[:, :, None].astype(lat.dtype), cache_len)

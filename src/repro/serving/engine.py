@@ -92,7 +92,8 @@ class _EngineTelemetry:
                  prefill_launched_tokens (width x bucket per launch)}
       gauge_fns  serving/{active_slots, slot_utilization, queue_depth,
                  kv_cells_active, kv_cells_capacity, token_occupancy}
-                 (+ kv_pool/* and serving/{preemptions,page_oom} paged)
+                 (+ kv_pool/* and serving/{preemptions, page_oom,
+                 pool_in_place_calls, pool_copied_calls} paged)
       histograms serving/admit_bucket (admitted pad bucket, tokens),
                  serving/queue_wait_s (submit -> admission, seconds)
 
@@ -104,6 +105,12 @@ class _EngineTelemetry:
     {live; paged on the Pallas kernel also kv_pages, kv_blocks,
     kv_blocks_launched} with ``.dispatch`` and ``.wait`` children, and
     ``engine.bookkeep`` {retired}. Without one, no span is opened.
+
+    The paged engine's steps consume the pool they are given (donated):
+    ``serving/pool_in_place_calls`` counts the calls that did so in place
+    (every leaf of the pool passed in deleted after the call) and
+    ``serving/pool_copied_calls`` the others, and ``engine.decode`` and
+    ``engine.admit`` carry ``pool_in_place`` (1 or 0).
 
     A paged MoE model's steps also return their routing counters
     (models/moe.STATS), fetched with the tokens: ``engine.admit`` and
@@ -184,13 +191,20 @@ class _EngineTelemetry:
         their host copy."""
         with self._span("engine.decode", live=live, **work) as span:
             with self._span("engine.decode.dispatch"):
+                pool = self.caches
                 tok, self.caches, *stats = self._step(
-                    self.params, next_token, self.caches, *tables
+                    self.params, next_token, pool, *tables
                 )
+                self._note_pool(span, pool)
             with self._span("engine.decode.wait"):
                 tok_host, *stats = jax.device_get((tok, *stats))
             self._note_routing(span, *stats)
             return tok, tok_host
+
+    def _note_pool(self, span: dict, pool):
+        """A step took the caches ``pool``: nothing to count here; the
+        paged engine, whose steps consume the pool, counts whether they
+        did so in place."""
 
     def _note_routing(self, span: dict, stats=None):
         """Routing counters of one launch (rows of models/moe.STATS, one
@@ -484,9 +498,14 @@ class PagedServingEngine(_EngineTelemetry):
         from repro.configs.registry import paged_cache_specs
 
         spec = paged_cache_specs(cfg, num_pages, page_size)  # asserts attn-only
+        # The engine holds the only pool; each step consumes it (donated)
+        # and returns the updated one, which replaces it (DESIGN.md
+        # Section 5.3): a pool held across a call is a deleted array.
         self.caches = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), spec)
-        self._step = jax.jit(build_paged_serve_step(cfg, attn_cfg))
-        self._admit = jax.jit(build_paged_admit_step(cfg, attn_cfg, page_size))
+        self._step = jax.jit(build_paged_serve_step(cfg, attn_cfg),
+                             donate_argnums=(2,))
+        self._admit = jax.jit(build_paged_admit_step(cfg, attn_cfg, page_size),
+                              donate_argnums=(2,))
         # Host-side scheduler state, pushed to device every tick.
         self.table = np.zeros((max_batch, pages_per_seq_max), np.int32)
         self.cache_len = np.zeros((max_batch,), np.int32)
@@ -505,6 +524,8 @@ class PagedServingEngine(_EngineTelemetry):
                                               EXPERT_LOAD_BUCKETS)
         self.pool.register_metrics(self.obs)
         self._c_page_oom = self.obs.counter("serving/page_oom")
+        self._c_pool_in_place = self.obs.counter("serving/pool_in_place_calls")
+        self._c_pool_copied = self.obs.counter("serving/pool_copied_calls")
         self._splits: Optional[int] = None  # resolved on the first traced tick
         self.obs.gauge_fn("serving/preemptions", lambda: float(self.preemptions))
         # fraction of *allocated* page cells holding real KV
@@ -515,6 +536,13 @@ class PagedServingEngine(_EngineTelemetry):
         )
 
     # ----------------------------------------------------------- metrics
+    def _note_pool(self, span: dict, pool):
+        """Whether the step that took ``pool`` updated it in place: the
+        donation was honoured when every leaf is deleted after the call."""
+        in_place = all(a.is_deleted() for a in jax.tree.leaves(pool))
+        (self._c_pool_in_place if in_place else self._c_pool_copied).inc()
+        span["pool_in_place"] = int(in_place)
+
     @property
     def admit_compiles(self) -> int:
         return self._admit._cache_size()
@@ -591,12 +619,14 @@ class PagedServingEngine(_EngineTelemetry):
                     n_dest = min(-(-len(feed) // self.ps), npb)
                     dest[i, :n_dest] = pages[:n_dest]
                 t_pref0 = self._now_us()
+                pool = self.caches
                 tok, lens_total, self.caches, *stats = self._admit(
                     self.params,
                     {"inputs": jnp.asarray(inputs), "lens": jnp.asarray(lens)},
-                    self.caches,
+                    pool,
                     jnp.asarray(dest),
                 )
+                self._note_pool(span, pool)
                 tok_host, *stats = jax.device_get((tok, *stats))
                 t_pref1 = self._now_us()
                 self._note_routing(span, *stats)

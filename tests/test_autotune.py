@@ -476,3 +476,24 @@ def test_trajectory_load_tolerant_and_dedupes(tmp_path, capsys):
     assert sorted((r["bench"], r["us_per_call"]) for r in out) == [
         ("a", 2.0), ("b", 3.0),
     ]
+
+
+# ---------------------------------------------------------------------------
+# The paged-decode sweep and drift check drive the serving kernel's contract
+# ---------------------------------------------------------------------------
+
+PAGED_SMOKE = next(s for s in autotune.SMOKE_SHAPES if s[0] == "paged_decode")
+
+
+def test_paged_decode_sweep_runs_on_the_smoke_shape():
+    """The CLI's `--smoke` and `--smoke --check` paths build their paged
+    operands with `_paged_fixture`; both must call the kernel with the pool
+    contract it takes (stacked planes read at a layer index)."""
+    key, entry = autotune._sweep_one(PAGED_SMOKE, 1, None)
+    assert key == autotune.cache_key(
+        "flash_decode_paged32", True, 128, 2, 32, "float32")
+    assert entry["num_splits"] in (1, 2, 4)
+    autotune.validate_doc(autotune.new_doc("cpu/test", {key: entry}))
+    failures = autotune.check_cache([PAGED_SMOKE], iters=1,
+                                    tol=float("inf"), log=lambda *_: None)
+    assert failures == [], failures

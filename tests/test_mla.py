@@ -164,7 +164,8 @@ def test_prefill_then_paged_decode_matches_the_reference(small):
 def test_paged_engine_serves_the_reference_greedy_tokens(small):
     """The normal serving path: PagedServingEngine (bucketed admission
     split by the token budget, latent pages, absorbed decode) emits the
-    reference's greedy tokens; its routing counters reach the registry."""
+    reference's greedy tokens; its routing counters reach the registry,
+    and every step updated the donated latent pool in place."""
     from repro.obs import TraceRecorder
 
     cfg, params = small
@@ -193,6 +194,11 @@ def test_paged_engine_serves_the_reference_greedy_tokens(small):
     snap = eng.snapshot()
     assert snap["serving/moe_experts_touched"] > 0
     assert snap["serving/moe_expert_load_max/count"] > 0
+    calls = [e["args"]["pool_in_place"] for e in eng.tracer.events
+             if e["name"] in ("engine.admit", "engine.decode")]
+    assert calls and set(calls) == {1}
+    assert snap["serving/pool_in_place_calls"] == len(calls)
+    assert snap["serving/pool_copied_calls"] == 0
 
 
 @pytest.mark.parametrize("schedule,splits", [("compact", 1), ("dense", 1),
@@ -210,34 +216,42 @@ def test_forward_with_narrow_v_matches_reference(schedule, splits):
     np.testing.assert_allclose(np.asarray(o), np.asarray(want), atol=2e-6)
 
 
-def test_latent_paged_kernel_matches_reference_and_ignores_unowned_pages():
+@pytest.mark.parametrize("layer", [0, 2])
+def test_latent_paged_kernel_matches_reference_and_ignores_unowned_pages(layer):
     """The paged kernel's latent mode (one 1-head plane read as K, its
-    first r lanes as V) against the dense reference on the gathered cache;
-    NaN in every page no slot owns leaves the output bitwise equal."""
+    first r lanes as V) at ``layer`` of a stack of three layers' planes,
+    against the dense reference on that layer's gathered cache and bitwise
+    the call on that layer's planes alone; NaN in every page no slot owns,
+    and in the stack's other layers, leaves the output bitwise equal."""
     B, H, W, r, ps, n_pages, pool = 3, 4, 128, 64, 8, 6, 24
     k = jax.random.split(jax.random.PRNGKey(4), 2)
     q = jax.random.normal(k[0], (B, 1, H, W))
-    planes = jax.random.normal(k[1], (1, pool, ps, W))
+    planes = jax.random.normal(k[1], (3, 1, pool, ps, W))
     lengths = jnp.array([37, 0, 9], jnp.int32)
     table = np.zeros((B, n_pages), np.int32)
     owned = np.random.default_rng(2).permutation(np.arange(1, pool))
     table[0, :5] = owned[:5]
     table[2, :2] = owned[5:7]
-    run = lambda pl_: ops.mla_decode_paged_pallas(
-        q, pl_, lengths, jnp.asarray(table), v_dim=r, scale=0.3, num_splits=2)
+    run = lambda pl_, i=layer: ops.mla_decode_paged_pallas(
+        q, pl_, lengths, jnp.asarray(table), i, v_dim=r, scale=0.3,
+        num_splits=2)
     o, _ = run(planes)
-    cache = planes[0][table].reshape(B, n_pages * ps, 1, W)
+    cache = planes[layer, 0][table].reshape(B, n_pages * ps, 1, W)
     want = attention_reference(q, cache, cache[..., :r], kv_length=lengths,
                                scale=0.3)[0]
     np.testing.assert_allclose(np.asarray(o), np.asarray(want), atol=1e-5)
+    assert np.array_equal(np.asarray(run(planes[layer][None], 0)[0]),
+                          np.asarray(o))
     assert not np.asarray(o[1]).any()  # an empty slot reads nothing
-    mine = np.zeros(pool, bool)
-    mine[owned[:7]] = True
+    mine = np.zeros((3, pool), bool)
+    mine[layer, owned[:7]] = True
     # stale rows past a length inside owned pages too
-    poisoned = jnp.where(jnp.asarray(mine)[None, :, None, None], planes, jnp.nan)
+    poisoned = jnp.where(jnp.asarray(mine)[:, None, :, None, None], planes,
+                         jnp.nan)
     tail = np.zeros((pool, ps), bool)
     tail[owned[4], 37 % ps:] = True
     tail[owned[6], 9 % ps:] = True
-    poisoned = jnp.where(jnp.asarray(tail)[None, :, :, None], jnp.nan, poisoned)
+    poisoned = jnp.where(jnp.asarray(tail)[None, None, :, :, None], jnp.nan,
+                         poisoned)
     o2, _ = run(poisoned)
     assert np.array_equal(np.asarray(o2), np.asarray(o))

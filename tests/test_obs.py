@@ -426,9 +426,9 @@ def _profiled_host_events(tmp_path, fn, prefix="repro."):
 ENGINE_SPANS = {  # span -> (parent span, its args)
     "engine.tick": (None, {"live", "queued"}),
     "engine.schedule": ("engine.tick", {"picked"}),
-    "engine.admit": ("engine.tick",
-                     {"n", "width", "bucket", "tokens", "launched"}),
-    "engine.decode": ("engine.tick", {"live"}),
+    "engine.admit": ("engine.tick", {"n", "width", "bucket", "tokens",
+                                     "launched", "pool_in_place"}),
+    "engine.decode": ("engine.tick", {"live", "pool_in_place"}),
     "engine.decode.dispatch": ("engine.decode", set()),
     "engine.decode.wait": ("engine.decode", set()),
     "engine.bookkeep": ("engine.tick", {"retired"}),
@@ -510,8 +510,8 @@ def test_paged_engine_decode_span_counts_kernel_work(model):
     closed = jax.make_jaxpr(lambda q, kp, vp, lens, tbl: (
         flash_decode_paged_pallas(q, kp, vp, lens, tbl, num_splits=2)))(
         jnp.zeros((4, 1, cfg.num_heads, hd)),
-        jnp.zeros((cfg.num_kv_heads, 32, 4, hd)),
-        jnp.zeros((cfg.num_kv_heads, 32, 4, hd)),
+        jnp.zeros((cfg.num_layers, cfg.num_kv_heads, 32, 4, hd)),
+        jnp.zeros((cfg.num_layers, cfg.num_kv_heads, 32, 4, hd)),
         jnp.zeros((4,), jnp.int32), jnp.zeros((4, 8), jnp.int32))
     assert [e.params["grid_mapping"].grid for e in closed.jaxpr.eqns
             if e.primitive.name == "pallas_call"] == [(4, 2, 1)]

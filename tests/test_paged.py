@@ -14,6 +14,7 @@ from repro.core.decode import flash_decode_paged
 from repro.kernels.flash_decode import paged_decode_geometry, paged_decode_work
 from repro.kernels.ops import flash_decode_pallas, flash_decode_paged_pallas
 from repro.models import lm
+from repro.obs import TraceRecorder
 from repro.serving.engine import PagedServingEngine, Request, ServingEngine
 from repro.serving.kv_pool import NULL_PAGE, KVPagePool
 
@@ -76,21 +77,28 @@ LENS_LONG = (768, 1100, 288, 0)
 WINDOW_SINK = {"short": (32, 8), "long": (200, 16)}
 
 
-def _paginate(kc, vc, seed=0):
-    """Contiguous (B,S,Hk,D) caches -> shuffled physical page planes
-    (Hk,P,ps,D) + block table, page 0 reserved null."""
+# The pool's planes are a stack of every layer's (LAYERS, Hk, P, ps, D);
+# the kernel reads one layer of it.
+LAYERS = 3
+
+
+def _paginate(kc, vc, seed=0, layer=0):
+    """Contiguous (B,S,Hk,D) caches -> shuffled physical page planes at
+    ``layer`` of a (LAYERS,Hk,P,ps,D) stack whose other layers hold NaN,
+    + block table, page 0 reserved null."""
     kc, vc = np.asarray(kc), np.asarray(vc)
     batch, npages = kc.shape[0], kc.shape[1] // PS
     P = batch * npages + 1
     perm = np.random.default_rng(seed).permutation(P - 1) + 1
     table = perm.reshape(batch, npages).astype(np.int32)
-    k_pages = np.zeros((Hk, P, PS, D), kc.dtype)
-    v_pages = np.zeros((Hk, P, PS, D), vc.dtype)
+    k_pages = np.full((LAYERS, Hk, P, PS, D), np.nan, kc.dtype)
+    v_pages = np.full((LAYERS, Hk, P, PS, D), np.nan, vc.dtype)
+    k_pages[layer] = v_pages[layer] = 0
     for b in range(batch):
         for i in range(npages):
             phys = table[b, i]
-            k_pages[:, phys] = kc[b, i * PS : (i + 1) * PS].transpose(1, 0, 2)
-            v_pages[:, phys] = vc[b, i * PS : (i + 1) * PS].transpose(1, 0, 2)
+            k_pages[layer, :, phys] = kc[b, i * PS: (i + 1) * PS].transpose(1, 0, 2)
+            v_pages[layer, :, phys] = vc[b, i * PS: (i + 1) * PS].transpose(1, 0, 2)
     return jnp.asarray(k_pages), jnp.asarray(v_pages), jnp.asarray(table)
 
 
@@ -122,21 +130,30 @@ def geometry(request):
         "kv" if request.param == "short" else "kv_long")
 
 
+@pytest.mark.parametrize("layer", [0, 1])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_paged_bitwise_parity_one_page_per_split(kv, dtype):
+def test_paged_bitwise_parity_one_page_per_split(kv, dtype, layer):
     """One split == one page makes the paged kernel's per-split math and
     merge tree identical to the contiguous kernel's -> (o, lse) must be
     BITWISE equal, independent of physical page placement. GQA (Hq=8 over
-    Hk=2) and ragged prime/odd lengths included."""
+    Hk=2) and ragged prime/odd lengths included. Read at ``layer`` of the
+    stack, it is bitwise the call on that layer's planes alone (a stack of
+    one at layer 0), and no other layer's NaN reaches it."""
     q, kc, vc = (t.astype(dtype) for t in kv[:3])
     lens = kv[3]
-    k_pages, v_pages, table = _paginate(kc, vc)
+    k_pages, v_pages, table = _paginate(kc, vc, layer=layer)
     o_c, lse_c = flash_decode_pallas(q, kc, vc, lens, num_splits=NPAGES)
     o_p, lse_p = flash_decode_paged_pallas(
-        q, k_pages, v_pages, lens, table, num_splits=NPAGES
+        q, k_pages, v_pages, lens, table, layer, num_splits=NPAGES
     )
     np.testing.assert_array_equal(np.asarray(o_p), np.asarray(o_c))
     np.testing.assert_array_equal(np.asarray(lse_p), np.asarray(lse_c))
+    o_1, lse_1 = flash_decode_paged_pallas(
+        q, k_pages[layer][None], v_pages[layer][None], lens, table, 0,
+        num_splits=NPAGES
+    )
+    np.testing.assert_array_equal(np.asarray(o_p), np.asarray(o_1))
+    np.testing.assert_array_equal(np.asarray(lse_p), np.asarray(lse_1))
 
 
 def test_paged_multi_page_splits_match(geometry):
@@ -154,16 +171,18 @@ def test_paged_multi_page_splits_match(geometry):
         np.testing.assert_allclose(lse_p, lse_c, atol=1e-5, rtol=1e-5)
 
 
-def test_paged_shuffle_invariance(kv):
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_shuffle_invariance(kv, layer):
     """The physical placement of pages is pure bookkeeping: two different
-    shuffles must produce BITWISE identical results."""
+    shuffles must produce BITWISE identical results, at any layer of the
+    stack."""
     q, kc, vc, lens = kv
     outs = []
     for seed in (0, 1):
-        k_pages, v_pages, table = _paginate(kc, vc, seed=seed)
+        k_pages, v_pages, table = _paginate(kc, vc, seed=seed, layer=layer)
         outs.append(
             flash_decode_paged_pallas(
-                q, k_pages, v_pages, lens, table, num_splits=4
+                q, k_pages, v_pages, lens, table, layer, num_splits=4
             )
         )
     np.testing.assert_array_equal(np.asarray(outs[0][0]), np.asarray(outs[1][0]))
@@ -207,8 +226,8 @@ def test_paged_empty_slot_masked(kv):
     q, kc, vc, _ = kv
     k_pages, v_pages, table = _paginate(kc, vc)
     # poison the null page: masked-out reads would show up immediately
-    k_pages = k_pages.at[:, 0].set(1e9)
-    v_pages = v_pages.at[:, 0].set(1e9)
+    k_pages = k_pages.at[:, :, 0].set(1e9)
+    v_pages = v_pages.at[:, :, 0].set(1e9)
     lens = jnp.array([128, 0, 37], jnp.int32)
     table = table.at[1].set(0)
     o, lse = flash_decode_paged_pallas(
@@ -224,28 +243,30 @@ def test_paged_empty_slot_masked(kv):
     np.testing.assert_allclose(o[2], o_ref[2], atol=5e-6, rtol=1e-5)
 
 
+@pytest.mark.parametrize("layer", [0, 1])
 @pytest.mark.parametrize("splits", [1, 2])
-def test_paged_nan_past_live_pages(geometry, splits):
+def test_paged_nan_past_live_pages(geometry, splits, layer):
     """What lies past a slot's cached tokens never reaches the output, not
     even as 0 * NaN: NaN in the null page, in every page a table names past
     a slot's live pages, and in the rows past the length of its last live
-    page leaves (o, lse) bitwise equal to a clean pool's."""
+    page leaves (o, lse) bitwise equal to a clean pool's (the stack's other
+    layers NaN throughout)."""
     q, kc, vc, lens = geometry[1]
-    k_pages, v_pages, table = _paginate(kc, vc)
+    k_pages, v_pages, table = _paginate(kc, vc, layer=layer)
     lens_np = np.asarray(lens)
     kp, vp = np.array(k_pages), np.array(v_pages)
     dead = np.arange(table.shape[1])[None, :] >= -(-lens_np[:, None] // PS)
     for pages in (kp, vp):
-        pages[:, 0] = np.nan
-        pages[:, np.asarray(table)[dead]] = np.nan
+        pages[layer, :, 0] = np.nan
+        pages[layer, :, np.asarray(table)[dead]] = np.nan
         for b, n in enumerate(lens_np):
             if n % PS:
-                pages[:, table[b, n // PS], n % PS:] = np.nan
-    clean = flash_decode_paged_pallas(q, k_pages, v_pages, lens, table,
+                pages[layer, :, table[b, n // PS], n % PS:] = np.nan
+    clean = flash_decode_paged_pallas(q, k_pages, v_pages, lens, table, layer,
                                       num_splits=splits)
     nulled = flash_decode_paged_pallas(q, kp, vp, lens, table.at[dead].set(0),
-                                       num_splits=splits)
-    poisoned = flash_decode_paged_pallas(q, kp, vp, lens, table,
+                                       layer, num_splits=splits)
+    poisoned = flash_decode_paged_pallas(q, kp, vp, lens, table, layer,
                                          num_splits=splits)
     for got in (nulled, poisoned):
         np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(clean[0]))
@@ -302,6 +323,18 @@ def model():
     return cfg, params
 
 
+def _assert_pool_in_place(eng):
+    """Every decode and admit call consumed the donated pool in place:
+    each call's span says so, and the engine counted every one of them
+    in place and none copied."""
+    spans = [e["args"] for e in eng.tracer.events
+             if e["name"] in ("engine.decode", "engine.admit")]
+    snap = eng.snapshot()
+    assert spans and all(a["pool_in_place"] == 1 for a in spans)
+    assert snap["serving/pool_in_place_calls"] == len(spans)
+    assert snap["serving/pool_copied_calls"] == 0
+
+
 def _sequential_refs(cfg, params, prompts, max_new):
     """Oracle: each request alone through the fixed-slot engine."""
     refs = {}
@@ -316,14 +349,16 @@ def _sequential_refs(cfg, params, prompts, max_new):
 def test_paged_engine_token_parity_and_compiles(model):
     """Requests joining and leaving mid-flight through the paged engine
     generate exactly the sequential-oracle tokens; the decode step compiles
-    ONCE for the whole run and admission compiles once per (bucket, width)."""
+    ONCE for the whole run and admission compiles once per (bucket, width);
+    every step updates the donated pool in place."""
     cfg, params = model
     rng = np.random.default_rng(0)
     prompts = [list(map(int, rng.integers(1, 100, rng.integers(2, 20))))
                for _ in range(5)]
     refs = _sequential_refs(cfg, params, prompts, max_new=6)
     eng = PagedServingEngine(cfg, params, ATTN, max_batch=2, num_pages=17,
-                             page_size=8, pages_per_seq_max=8, prompt_pad=16)
+                             page_size=8, pages_per_seq_max=8, prompt_pad=16,
+                             tracer=TraceRecorder(process="t"))
     for i, p in enumerate(prompts):
         eng.submit(Request(rid=i, prompt=list(p), max_new_tokens=6))
     done = eng.run(max_ticks=400)
@@ -337,6 +372,7 @@ def test_paged_engine_token_parity_and_compiles(model):
     # free-on-retire returned every page
     assert eng.pool.used_pages == 0
     assert eng.pool.free_pages == eng.pool.usable_pages
+    _assert_pool_in_place(eng)
 
 
 def test_paged_engine_batched_admission_one_compile(model):
@@ -345,7 +381,8 @@ def test_paged_engine_batched_admission_one_compile(model):
     one admit trace, and slot reuse later sticks to it."""
     cfg, params = model
     eng = PagedServingEngine(cfg, params, ATTN, max_batch=4, num_pages=33,
-                             page_size=8, pages_per_seq_max=8, prompt_pad=16)
+                             page_size=8, pages_per_seq_max=8, prompt_pad=16,
+                             tracer=TraceRecorder(process="t"))
     for i, L in enumerate((3, 7, 11)):
         eng.submit(Request(rid=i, prompt=[2 + i] * L, max_new_tokens=4))
     eng.tick()  # admits all three in one call (width padded to 4)
@@ -358,6 +395,7 @@ def test_paged_engine_batched_admission_one_compile(model):
     # however requests trickle in (here widths 4, then 1/2 as slots free)
     assert eng.admit_compiles <= 3
     assert eng.decode_compiles == 1
+    _assert_pool_in_place(eng)
 
 
 def test_paged_engine_preemption_resume(model):
@@ -369,7 +407,8 @@ def test_paged_engine_preemption_resume(model):
     prompts = [list(map(int, rng.integers(1, 100, 6))) for _ in range(4)]
     refs = _sequential_refs(cfg, params, prompts, max_new=24)
     eng = PagedServingEngine(cfg, params, ATTN, max_batch=4, num_pages=14,
-                             page_size=4, pages_per_seq_max=8, prompt_pad=16)
+                             page_size=4, pages_per_seq_max=8, prompt_pad=16,
+                             tracer=TraceRecorder(process="t"))
     for i, p in enumerate(prompts):
         eng.submit(Request(rid=i, prompt=list(p), max_new_tokens=24))
     done = eng.run(max_ticks=1000)
@@ -378,6 +417,7 @@ def test_paged_engine_preemption_resume(model):
         assert done[i].generated == refs[i], i
     assert eng.preemptions > 0, "pool was sized to force preemption"
     assert eng.decode_compiles == 1  # preemption churn never recompiles
+    _assert_pool_in_place(eng)
 
 
 def test_paged_engine_rejects_oversized(model):
