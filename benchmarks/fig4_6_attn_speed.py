@@ -12,6 +12,7 @@ Derived column: TFLOPs/s using the paper's formula
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, List
 
 import jax
@@ -20,6 +21,7 @@ import jax.numpy as jnp
 from benchmarks.timing import interleaved_timeit, time_min
 from repro.core.attention import AttentionConfig, attention
 from repro.core.masks import MaskSpec
+from repro.kernels.ops import flash_attention_pallas
 
 TOKENS = 4096  # batch * seq held constant, like the paper's 16k
 HEADS, HEAD_DIM = 4, 64
@@ -57,20 +59,18 @@ def _mk_qkv(key, seq: int, batch: int):
 
 
 def _time_pair(
-    csv: List[str], names, cfg: AttentionConfig, spec: MaskSpec,
-    q, k, v, seq: int, batch: int, causal: bool,
+    csv: List[str], names, attn: Callable, q, k, v, seq: int, batch: int,
+    causal: bool,
 ) -> None:
-    """Time fwd and fwd+bwd for one config; append one CSV row each.
+    """Time fwd and fwd+bwd of ``attn(q, k, v)``; append one CSV row each.
 
     names = (fwd_row_name, fwdbwd_row_name) -- everything left of the first
     comma in the emitted rows. The two are timed INTERLEAVED min-of-N
     (shared helper): they will be compared, so drift must hit both equally
     -- fwd > fwd+bwd in the output is a timing bug, not a measurement.
     """
-    fwd = jax.jit(lambda q, k, v, cfg=cfg: attention(q, k, v, spec, cfg))
-    loss = jax.jit(
-        jax.grad(lambda q, k, v, cfg=cfg: attention(q, k, v, spec, cfg).sum())
-    )
+    fwd = jax.jit(attn)
+    loss = jax.jit(jax.grad(lambda q, k, v: attn(q, k, v).sum()))
     best = interleaved_timeit({"fwd": fwd, "fwdbwd": loss}, q, k, v)
     t_f, t_b = best["fwd"], best["fwdbwd"]
     csv.append(
@@ -100,7 +100,8 @@ def run(csv: List[str]) -> None:
                 tag = f"{impl}/causal={int(causal)}/seq={seq}"
                 _time_pair(
                     csv, (f"fig5_fwd/{tag}", f"fig4_fwdbwd/{tag}"),
-                    cfg, spec, q, k, v, seq, batch, causal,
+                    functools.partial(attention, spec=spec, cfg=cfg),
+                    q, k, v, seq, batch, causal,
                 )
 
     schedule_comparison(csv, key)
@@ -122,13 +123,14 @@ def schedule_comparison(csv: List[str], key=None) -> None:
     spec = MaskSpec(causal=True)
     q, k, v = _mk_qkv(jax.random.fold_in(key, 7), seq, batch)
     for schedule in ("dense", "compact"):
-        cfg = AttentionConfig(
-            impl="flash_pallas", block_q=64, block_kv=64, schedule=schedule
+        attn = functools.partial(
+            flash_attention_pallas, spec=spec, block_q=64, block_kv=64,
+            schedule=schedule,
         )
         tag = f"flash_pallas/schedule={schedule}/causal=1/seq={seq}"
         _time_pair(
             csv, (f"sched_cmp_fwd/{tag}", f"sched_cmp_fwdbwd/{tag}"),
-            cfg, spec, q, k, v, seq, batch, True,
+            attn, q, k, v, seq, batch, True,
         )
 
 

@@ -66,8 +66,7 @@ import sys
 import time
 
 ALL = ("fig4_6_attn_speed", "nonmatmul_census", "table1_e2e", "roofline",
-       "ring_accounting", "occupancy_sweep", "autotune_sweep",
-       "serving_sweep")
+       "ring_accounting", "occupancy_sweep", "serving_sweep")
 
 
 def _records(csv_rows):

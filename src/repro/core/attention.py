@@ -9,6 +9,9 @@ the backend is selected by config, never by model code:
                          off-TPU; kernels/compat.resolve_interpret)
 
 All three are exact and interchangeable; tests assert pairwise agreement.
+The config carries only model-facing choices; the Pallas kernels' tile
+schedule, backward and forward partitions resolve per shape in
+``kernels/ops.resolve_pallas_knobs``.
 """
 
 from __future__ import annotations
@@ -26,28 +29,13 @@ from repro.core.masks import MaskSpec
 @dataclasses.dataclass(frozen=True)
 class AttentionConfig:
     impl: str = "flash_xla"  # 'ref' | 'flash_xla' | 'flash_pallas'
-    # None -> tuned cache (kernels/autotune), then shape-aware defaults
-    # (kernels/ops.default_block_sizes) on the Pallas path; the XLA scan
-    # path falls back to its fixed 512.
+    # None -> shape-aware defaults (kernels/ops.default_block_sizes) on the
+    # Pallas path; the XLA scan path falls back to its fixed 512.
     block_q: Optional[int] = None
     block_kv: Optional[int] = None
     mode: str = "auto"  # tile schedule for flash_xla: 'dense' | 'packed' | 'auto'
-    # flash_pallas tile schedule / backward: None -> tuned cache, then
-    # 'compact' / 'fused'. Explicit strings override everywhere.
-    schedule: Optional[str] = None  # 'compact' | 'dense'
-    bwd: Optional[str] = None  # 'fused' (one-pass) | 'split'
-    # Forward occupancy partitioning (flash_pallas, compact schedule):
-    # None -> tuned cache, then shape-aware auto
-    # (kernels/ops.default_forward_partitions); explicit ints override
-    # (1 disables).
-    num_q_bands: Optional[int] = None
-    kv_splits: Optional[int] = None
-    # Split-KV decode fan-out: None -> tuned cache
-    # (kernels/autotune.resolve_decode_splits), then 8.
-    decode_splits: Optional[int] = None
-    # Tuned-knob cache switch: None -> env REPRO_TUNED_CACHE (on by
-    # default); False forces pure-heuristic knob resolution.
-    use_tuned: Optional[bool] = None
+    # Split-KV fan-out of the contiguous and the paged decodes.
+    decode_splits: int = 8
     # Pallas interpret mode: None = auto (off on real TPUs, on elsewhere --
     # resolved in one place, kernels/compat.resolve_interpret).
     interpret: Optional[bool] = None
@@ -93,9 +81,6 @@ def attention(
         return ring_flash_attention(
             q, k, v, spec, impl=cfg.impl, scale=scale, block_q=cfg.block_q,
             block_kv=cfg.block_kv, interpret=cfg.interpret,
-            schedule=cfg.schedule, bwd=cfg.bwd,
-            num_q_bands=cfg.num_q_bands, kv_splits=cfg.kv_splits,
-            use_tuned=cfg.use_tuned,
         )
     if cfg.impl == "ref":
         from repro.kernels.ref import attention_reference
@@ -113,17 +98,12 @@ def attention(
             return flash_attention_pallas_varlen(
                 q, k, v, segment_ids, spec, scale=scale, block_q=cfg.block_q,
                 block_kv=cfg.block_kv, interpret=cfg.interpret,
-                schedule=cfg.schedule, bwd=cfg.bwd,
-                num_q_bands=cfg.num_q_bands, kv_splits=cfg.kv_splits,
-                use_tuned=cfg.use_tuned,
             )
         from repro.kernels.ops import flash_attention_pallas
 
         return flash_attention_pallas(
             q, k, v, spec, scale=scale, block_q=cfg.block_q, block_kv=cfg.block_kv,
-            interpret=cfg.interpret, schedule=cfg.schedule, bwd=cfg.bwd,
-            num_q_bands=cfg.num_q_bands, kv_splits=cfg.kv_splits,
-            use_tuned=cfg.use_tuned,
+            interpret=cfg.interpret,
         )
     raise ValueError(f"unknown attention impl: {cfg.impl}")
 
@@ -145,23 +125,9 @@ def decode_attention(
 
     kv_segment_ids (B, S) + q_segment (B,) restrict the query to its own
     segment of a packed cache (see flash_decode / flash_decode_pallas).
-
-    ``cfg.decode_splits=None`` resolves the split-KV fan-out from the tuned
-    cache (keyed on the static padded cache size) with the same precedence
-    as the training knobs: explicit > tuned > default (8).
+    The split-KV fan-out is ``cfg.decode_splits``.
     """
     splits = cfg.decode_splits
-    if splits is None:
-        from repro.kernels import autotune
-
-        splits = autotune.resolve_decode_splits(
-            k_cache.shape[1], q.shape[2], q.shape[3], q.dtype,
-            use_tuned=cfg.use_tuned,
-        )
-    else:
-        from repro.obs.metrics import count_knob
-
-        count_knob("flash_decode", "explicit")
     if cfg.impl == "flash_pallas":
         from repro.kernels.ops import flash_decode_pallas
 
@@ -175,24 +141,6 @@ def decode_attention(
         num_splits=splits, kv_segment_ids=kv_segment_ids,
         q_segment=q_segment,
     )[0]
-
-
-def paged_decode_splits(cfg: AttentionConfig, n_pages: int, page_size: int,
-                        heads: int, head_dim: int, dtype) -> int:
-    """Split-KV fan-out of the paged decode over ``n_pages`` logical pages:
-    ``cfg.decode_splits``, else resolved (and counted) by
-    ``autotune.resolve_decode_splits`` on the logical capacity."""
-    if cfg.decode_splits is not None:
-        from repro.obs.metrics import count_knob
-
-        count_knob(f"flash_decode_paged{page_size}", "explicit")
-        return cfg.decode_splits
-    from repro.kernels import autotune
-
-    return autotune.resolve_decode_splits(
-        n_pages * page_size, heads, head_dim, dtype,
-        page_size=page_size, use_tuned=cfg.use_tuned,
-    )
 
 
 def decode_attention_paged(
@@ -213,12 +161,9 @@ def decode_attention_paged(
     The cache is the pool's physical page planes, stacked over layers and
     read at ``layer``, plus a per-sequence block table
     (serving/kv_pool.py); rows with ``cache_length == 0`` (all-null
-    table) read no KV at all on the Pallas path. ``cfg.decode_splits=None``
-    resolves the split fan-out from the tuned cache keyed on the *logical*
-    capacity ``n_pages * page_size`` and the page size
-    (kernels/autotune.resolve_decode_splits)."""
-    splits = paged_decode_splits(cfg, block_table.shape[1], k_pages.shape[3],
-                                 q.shape[2], q.shape[3], q.dtype)
+    table) read no KV at all on the Pallas path. The split-KV fan-out is
+    ``cfg.decode_splits``."""
+    splits = cfg.decode_splits
     if cfg.impl == "flash_pallas":
         from repro.kernels.ops import flash_decode_paged_pallas
 
@@ -250,9 +195,7 @@ def decode_attention_latent_paged(
     (models/mla.py). The Pallas path is the paged decode kernel's latent
     mode (device events ``mla_decode_paged``); the XLA path runs the paged
     decode with the planes as V and keeps the first ``v_dim`` lanes."""
-    splits = paged_decode_splits(cfg, block_table.shape[1],
-                                 latent_pages.shape[3], q.shape[2],
-                                 q.shape[3], q.dtype)
+    splits = cfg.decode_splits
     if cfg.impl == "flash_pallas":
         from repro.kernels.ops import mla_decode_paged_pallas
 

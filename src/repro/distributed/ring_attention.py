@@ -81,11 +81,10 @@ class _RingMeta(NamedTuple):
     block_kv: Optional[int]
     scale: Optional[float]
     interpret: Optional[bool]
-    schedule: Optional[str]      # None -> tuned cache / 'compact' per rect
+    schedule: Optional[str]      # None -> 'compact' per rect
     bwd: Optional[str]           # Pallas backward: 'fused' | 'split' | None
     num_q_bands: Optional[int]   # fwd occupancy partitioning of each
-    kv_splits: Optional[int]     # rectangle kernel (None -> tuned/shape auto)
-    use_tuned: Optional[bool] = None  # tuned-knob cache switch (rect kernels)
+    kv_splits: Optional[int]     # rectangle kernel (None -> shape auto)
 
 
 # ---------------------------------------------------------------------------
@@ -194,7 +193,7 @@ def _rect_fwd(q, k, v, spec: MaskSpec, meta: _RingMeta):
             q, k, v, spec, scale=meta.scale, block_q=meta.block_q,
             block_kv=meta.block_kv, interpret=meta.interpret,
             schedule=meta.schedule, num_q_bands=meta.num_q_bands,
-            kv_splits=meta.kv_splits, use_tuned=meta.use_tuned,
+            kv_splits=meta.kv_splits,
         )
     from repro.core.flash import flash_attention_with_lse
 
@@ -213,7 +212,7 @@ def _rect_bwd(q, k, v, o, lse, do, spec: MaskSpec, meta: _RingMeta):
         return flash_attention_pallas_shard_bwd(
             q, k, v, o, lse, do, spec, scale=meta.scale, block_q=meta.block_q,
             block_kv=meta.block_kv, interpret=meta.interpret,
-            schedule=meta.schedule, bwd=meta.bwd, use_tuned=meta.use_tuned,
+            schedule=meta.schedule, bwd=meta.bwd,
             out_dtype=jnp.float32,
         )
     from repro.core.flash import FlashConfig, _bwd_impl
@@ -398,8 +397,8 @@ def _local_bwd(q_loc, k_loc, v_loc, o_loc, lse_loc, do_loc, *, meta: _RingMeta):
 
 
 # ---------------------------------------------------------------------------
-# Telemetry (host-side, trace time — mirrors kernels/ops.count_knob: each
-# jit trace counts once, cached executions don't re-resolve)
+# Telemetry (host-side, at trace time: each jit trace counts once, cached
+# executions don't re-count)
 # ---------------------------------------------------------------------------
 
 _RING_TRACE_TID = 3  # dedicated Perfetto track for ring-schedule structure
@@ -516,7 +515,6 @@ def ring_flash_attention(
     bwd: Optional[str] = None,
     num_q_bands: Optional[int] = None,
     kv_splits: Optional[int] = None,
-    use_tuned: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Differentiable ring flash attention over the ``axis`` mesh axis.
 
@@ -555,7 +553,6 @@ def ring_flash_attention(
                 q, k, v, spec, scale=scale, block_q=block_q, block_kv=block_kv,
                 interpret=interpret, schedule=schedule, bwd=bwd,
                 num_q_bands=num_q_bands, kv_splits=kv_splits,
-                use_tuned=use_tuned,
             )
         from repro.core.flash import flash_attention
 
@@ -570,7 +567,7 @@ def ring_flash_attention(
         spec=spec, layout=layout, mesh=mesh, axis=axis, batch_axes=batch_axes,
         impl=impl, block_q=block_q, block_kv=block_kv, scale=scale,
         interpret=interpret, schedule=schedule, bwd=bwd,
-        num_q_bands=num_q_bands, kv_splits=kv_splits, use_tuned=use_tuned,
+        num_q_bands=num_q_bands, kv_splits=kv_splits,
     )
     _record_ring_pass(meta, k, backward=False)
     return _ring(q, k, v, meta)

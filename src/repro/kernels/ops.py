@@ -27,14 +27,9 @@ Memory contract (DESIGN.md Section 2):
   * Tile scheduling is ``schedule="compact"`` by default (see
     kernels/schedule.py); ``"dense"`` keeps the legacy visit-every-tile
     grid for comparison.
-  * Knob resolution is measurement-driven (ISSUE 6): whenever a
-    ``PallasFlashConfig`` knob is ``None``, :func:`resolve_pallas_knobs`
-    consults the committed tuned cache (``kernels/autotune.py`` /
-    ``tuned.json``) before falling back to the hand heuristics. Precedence,
-    per knob: explicit arg > tuned cache > heuristic
-    (``default_block_sizes`` / ``default_forward_partitions`` /
-    ``_resolve_bwd``). ``use_tuned=False`` (or env ``REPRO_TUNED_CACHE=0``)
-    disables the cache and restores pure-heuristic resolution.
+  * Knobs resolve per call: an explicit argument wins, and every ``None``
+    knob takes the shape heuristic (:func:`resolve_pallas_knobs`), the same
+    on the CPU and on the chip.
   * Block sizes default to a shape-aware table (``default_block_sizes``):
     clamped to the padded sequence length, ``block_kv`` shrinking as the
     head dim grows so the fused backward's f32 dK/dV scratch plus streamed
@@ -61,7 +56,6 @@ import jax.numpy as jnp
 
 from repro.core.masks import MaskSpec, pad_segments
 from repro.core.online_softmax import combine_lse_outputs
-from repro.kernels import autotune as _autotune
 from repro.kernels import flash_bwd as _bwd
 from repro.kernels import flash_decode as _dec
 from repro.kernels import flash_fwd as _fwd
@@ -95,26 +89,22 @@ __all__ = [
 
 @dataclasses.dataclass(frozen=True)
 class PallasFlashConfig:
-    """The five-knob kernel config. ``None`` = resolve per shape at call
-    time with precedence explicit arg > tuned cache > heuristic (see
-    :func:`resolve_pallas_knobs`)."""
+    """The kernel knobs. ``None`` = resolve per shape at call time from the
+    heuristics (see :func:`resolve_pallas_knobs`)."""
 
     spec: MaskSpec
-    block_q: Optional[int] = None   # None -> tuned / default_block_sizes
+    block_q: Optional[int] = None   # None -> default_block_sizes
     block_kv: Optional[int] = None
     scale: Optional[float] = None
     interpret: Optional[bool] = None  # None -> auto (off on TPU); compat.py
-    schedule: Optional[str] = None  # 'compact' | 'dense'; None -> tuned/'compact'
-    bwd: Optional[str] = None  # 'fused' (one-pass) | 'split'; None -> tuned/'fused'
+    schedule: Optional[str] = None  # 'compact' | 'dense'; None -> 'compact'
+    bwd: Optional[str] = None  # 'fused' (one-pass) | 'split'; None -> 'fused'
     # Forward partitioning (compact schedule; paper Section 3.2). None ->
-    # tuned cache, then the shape-aware default_forward_partitions policy;
-    # explicit ints override (1 disables). Bands are bitwise-free; kv
-    # splits change the fp summation order (exact up to merge rounding).
+    # the shape-aware default_forward_partitions policy; explicit ints
+    # override (1 disables). Bands are bitwise-free; kv splits change the
+    # fp summation order (exact up to merge rounding).
     num_q_bands: Optional[int] = None
     kv_splits: Optional[int] = None
-    # Tri-state tuned-cache switch: None -> env REPRO_TUNED_CACHE (on by
-    # default); False forces pure-heuristic resolution for every knob.
-    use_tuned: Optional[bool] = None
 
     def __post_init__(self):
         if self.schedule not in (None, "compact", "dense"):
@@ -197,9 +187,9 @@ def default_forward_partitions(bh: int, t_q: int, t_kv: int):
     return bands, splits
 
 
-def _resolve_partitions(cfg: PallasFlashConfig, tuned: dict, schedule: str,
+def _resolve_partitions(cfg: PallasFlashConfig, schedule: str,
                         bh: int, t_q: int, t_kv: int):
-    """Knobs (explicit > tuned > auto) -> concrete (num_q_bands, kv_splits)."""
+    """Knobs (explicit > auto) -> concrete (num_q_bands, kv_splits)."""
     if schedule != "compact":
         if (cfg.num_q_bands or 1) > 1 or (cfg.kv_splits or 1) > 1:
             raise ValueError(
@@ -207,10 +197,8 @@ def _resolve_partitions(cfg: PallasFlashConfig, tuned: dict, schedule: str,
             )
         return 1, 1
     auto_nb, auto_ks = default_forward_partitions(bh, t_q, t_kv)
-    nb = cfg.num_q_bands if cfg.num_q_bands is not None else \
-        tuned.get("num_q_bands", auto_nb)
-    ks = cfg.kv_splits if cfg.kv_splits is not None else \
-        tuned.get("kv_splits", auto_ks)
+    nb = cfg.num_q_bands if cfg.num_q_bands is not None else auto_nb
+    ks = cfg.kv_splits if cfg.kv_splits is not None else auto_ks
     return max(1, min(nb, t_q)), max(1, min(ks, t_kv))
 
 
@@ -246,8 +234,8 @@ def _legalize_block(name: str, val, seq: int, *, explicit: bool,
     the lane-major side arrays) and clamped to the 8-aligned padded
     sequence length -- a block that covers the whole padded axis is legal
     at any alignment. A warning fires when an *explicit* request had to
-    change (the heuristic and the tuned cache legalize silently -- clamping
-    to a short sequence is their normal operating mode, not a user error).
+    change (the heuristic legalizes silently -- clamping to a short
+    sequence is its normal operating mode, not a user error).
     """
     if isinstance(val, bool) or not isinstance(val, int):
         raise ValueError(f"{name} must be an int >= 1, got {val!r}")
@@ -265,32 +253,22 @@ def _legalize_block(name: str, val, seq: int, *, explicit: bool,
 
 def resolve_pallas_knobs(cfg: PallasFlashConfig, q_shape, k_shape,
                          dtype=jnp.float32) -> dict:
-    """Concrete knob resolution for one call -- explicit > tuned > heuristic.
+    """Concrete knob resolution for one call -- explicit arg, else heuristic.
 
-    ``q_shape``/``k_shape`` are the public-layout shapes (B, S, H, D). Every
-    ``None`` knob on ``cfg`` is filled from the tuned cache entry for
-    (impl='flash_pallas', causal, seq, heads, head dim, dtype) when the
-    cache is enabled and has a (near-enough) entry -- see
-    ``kernels/autotune.lookup`` -- and from the hand heuristics otherwise.
-    Returns the full dict the kernel call contract is built from:
-    ``block_q``, ``block_kv``, ``schedule``, ``bwd`` (VMEM-guard resolved),
-    ``num_q_bands``, ``kv_splits``, plus ``tuned`` (the raw cache knobs
-    consulted; empty when disabled or missed) for introspection.
+    ``q_shape``/``k_shape`` are the public-layout shapes (B, S, H, D);
+    no knob depends on ``dtype``, accepted for callers that pass it. Every
+    ``None`` knob on ``cfg`` takes its shape heuristic:
+    :func:`default_block_sizes` (then legalized), ``"compact"``,
+    ``"fused"`` (through the ``_resolve_bwd`` VMEM guard) and
+    :func:`default_forward_partitions`. Returns the full dict the kernel
+    call contract is built from: ``block_q``, ``block_kv``, ``schedule``,
+    ``bwd``, ``num_q_bands``, ``kv_splits``.
     """
     B, Sq, Hq, D = q_shape
     _, Sk, Hk, _ = k_shape
-    spec = cfg.spec
-    tuned = {}
-    # Windowed / sink mask families were never swept; their knob landscape
-    # differs from plain causal/full, so they stay on the heuristics.
-    if (_autotune.cache_enabled(cfg.use_tuned) and spec.window is None
-            and spec.sink == 0):
-        tuned = _autotune.lookup(
-            "flash_pallas", spec.causal, Sq, Hq, D, dtype
-        )
     bq_def, bk_def = default_block_sizes(Sq, Sk, D)
-    bq = cfg.block_q if cfg.block_q is not None else tuned.get("block_q", bq_def)
-    bk = cfg.block_kv if cfg.block_kv is not None else tuned.get("block_kv", bk_def)
+    bq = cfg.block_q if cfg.block_q is not None else bq_def
+    bk = cfg.block_kv if cfg.block_kv is not None else bk_def
     mosaic = not resolve_interpret(cfg.interpret)
     align = LANES if mosaic else 8
     bq = _legalize_block("block_q", bq, Sq, explicit=cfg.block_q is not None,
@@ -298,51 +276,15 @@ def resolve_pallas_knobs(cfg: PallasFlashConfig, q_shape, k_shape,
     bk = _legalize_block("block_kv", bk, Sk, explicit=cfg.block_kv is not None,
                          align=align)
     Sqp, Skp = _round_up(Sq, bq), _round_up(Sk, bk)
-    schedule = cfg.schedule or tuned.get("schedule") or "compact"
-    bwd = cfg.bwd or tuned.get("bwd") or "fused"
+    schedule = cfg.schedule or "compact"
     nb, ks = _resolve_partitions(
-        cfg, tuned, schedule, B * Hq, Sqp // bq, Skp // bk
+        cfg, schedule, B * Hq, Sqp // bq, Skp // bk
     )
-    _count_knob_sources(cfg, tuned, schedule)
     return dict(
         block_q=bq, block_kv=bk, schedule=schedule,
-        bwd=_resolve_bwd(bwd, Hq // Hk, Sqp),
-        num_q_bands=nb, kv_splits=ks, tuned=dict(tuned),
+        bwd=_resolve_bwd(cfg.bwd or "fused", Hq // Hk, Sqp),
+        num_q_bands=nb, kv_splits=ks,
     )
-
-
-def _count_knob_sources(cfg: PallasFlashConfig, tuned: dict, schedule: str):
-    """Telemetry: which precedence tier supplied each knob of this call.
-
-    Increments ``knobs/flash_pallas/{explicit,tuned,heuristic}`` on the
-    process-wide default registry (repro.obs.metrics) -- one hit per knob,
-    so a call resolving block_q explicitly but everything else from the
-    cache counts 1 explicit + N tuned. Runs at *trace* time (resolution
-    happens once per jit trace); cached executions do not re-count, the
-    same way they do not re-compile.
-    """
-    from repro.obs.metrics import count_knob
-
-    per_source = {"explicit": 0, "tuned": 0, "heuristic": 0}
-
-    def classify(explicit: bool, tuned_key: str):
-        if explicit:
-            per_source["explicit"] += 1
-        elif tuned_key in tuned:
-            per_source["tuned"] += 1
-        else:
-            per_source["heuristic"] += 1
-
-    classify(cfg.block_q is not None, "block_q")
-    classify(cfg.block_kv is not None, "block_kv")
-    classify(cfg.schedule is not None, "schedule")
-    classify(cfg.bwd is not None, "bwd")
-    if schedule != "dense":  # dense forces 1/1: no partition knobs in play
-        classify(cfg.num_q_bands is not None, "num_q_bands")
-        classify(cfg.kv_splits is not None, "kv_splits")
-    for source, n in per_source.items():
-        if n:
-            count_knob("flash_pallas", source, n)
 
 
 def _heads_layout(x: jnp.ndarray) -> jnp.ndarray:
@@ -389,7 +331,7 @@ def _prep_call(q, k, v, cfg: PallasFlashConfig, q_seg=None, kv_seg=None):
     tiles become cross-segment, so padded q rows attend nothing (l = 0 ->
     o = 0, lse = -inf; trimmed by the caller).
     """
-    r = resolve_pallas_knobs(cfg, q.shape, k.shape, q.dtype)
+    r = resolve_pallas_knobs(cfg, q.shape, k.shape)
     qh, kh, vh, m = _prep(q, k, v, cfg, r)
     meta = _KernelMeta(
         spec=cfg.spec, block_q=m["bq"], block_kv=m["bk"], group=m["G"],
@@ -517,20 +459,18 @@ def flash_attention_pallas(
     interpret: Optional[bool] = None, schedule: Optional[str] = None,
     bwd: Optional[str] = None,
     num_q_bands: Optional[int] = None, kv_splits: Optional[int] = None,
-    use_tuned: Optional[bool] = None,
 ):
     """Differentiable FA2 via the Pallas TPU kernels. q (B,Sq,Hq,D).
 
     ``bwd`` picks the backward: ``"fused"`` (one-pass kernel, the resolved
     default) or ``"split"`` (delta + dkv + dq baseline). Every ``None``
-    knob resolves per shape -- tuned cache first (``kernels/autotune``,
-    disable with ``use_tuned=False``), then the shape-aware heuristics
+    knob resolves per shape from the heuristics
     (:func:`default_block_sizes` / :func:`default_forward_partitions`).
     """
     cfg = PallasFlashConfig(
         spec=spec, block_q=block_q, block_kv=block_kv, scale=scale,
         interpret=interpret, schedule=schedule, bwd=bwd,
-        num_q_bands=num_q_bands, kv_splits=kv_splits, use_tuned=use_tuned,
+        num_q_bands=num_q_bands, kv_splits=kv_splits,
     )
     qh, kh, vh, _, _, m, meta = _prep_call(q, k, v, cfg)
     o = _flash_core(qh, kh, vh, meta)
@@ -544,7 +484,6 @@ def flash_attention_pallas_varlen(
     interpret: Optional[bool] = None, schedule: Optional[str] = None,
     bwd: Optional[str] = None,
     num_q_bands: Optional[int] = None, kv_splits: Optional[int] = None,
-    use_tuned: Optional[bool] = None,
 ):
     """Differentiable segment-packed (varlen) FA2 via the Pallas kernels.
 
@@ -574,7 +513,7 @@ def flash_attention_pallas_varlen(
     cfg = PallasFlashConfig(
         spec=spec, block_q=block_q, block_kv=block_kv, scale=scale,
         interpret=interpret, schedule=schedule, bwd=bwd,
-        num_q_bands=num_q_bands, kv_splits=kv_splits, use_tuned=use_tuned,
+        num_q_bands=num_q_bands, kv_splits=kv_splits,
     )
     qh, kh, vh, qs, ks, m, meta = _prep_call(q, k, v, cfg, segment_ids, kv_segment_ids)
     o = _flash_core_varlen(qh, kh, vh, qs, ks, meta)
@@ -595,7 +534,6 @@ def flash_attention_pallas_varlen_with_lse(
     block_q: Optional[int] = None, block_kv: Optional[int] = None,
     interpret: Optional[bool] = None, schedule: Optional[str] = None,
     num_q_bands: Optional[int] = None, kv_splits: Optional[int] = None,
-    use_tuned: Optional[bool] = None,
 ):
     """Forward-only varlen (serving): returns (o, lse (B, Hq, Sq))."""
     if kv_segment_ids is None:
@@ -603,7 +541,7 @@ def flash_attention_pallas_varlen_with_lse(
     cfg = PallasFlashConfig(
         spec=spec, block_q=block_q, block_kv=block_kv, scale=scale,
         interpret=interpret, schedule=schedule,
-        num_q_bands=num_q_bands, kv_splits=kv_splits, use_tuned=use_tuned,
+        num_q_bands=num_q_bands, kv_splits=kv_splits,
     )
     return _fwd_with_lse(
         q, k, v, cfg, segment_ids.astype(jnp.int32), kv_segment_ids.astype(jnp.int32)
@@ -616,12 +554,11 @@ def flash_attention_pallas_with_lse(
     block_q: Optional[int] = None, block_kv: Optional[int] = None,
     interpret: Optional[bool] = None, schedule: Optional[str] = None,
     num_q_bands: Optional[int] = None, kv_splits: Optional[int] = None,
-    use_tuned: Optional[bool] = None,
 ):
     cfg = PallasFlashConfig(
         spec=spec, block_q=block_q, block_kv=block_kv, scale=scale,
         interpret=interpret, schedule=schedule,
-        num_q_bands=num_q_bands, kv_splits=kv_splits, use_tuned=use_tuned,
+        num_q_bands=num_q_bands, kv_splits=kv_splits,
     )
     return _fwd_with_lse(q, k, v, cfg)
 
@@ -631,8 +568,7 @@ def flash_attention_pallas_shard_bwd(
     scale: Optional[float] = None,
     block_q: Optional[int] = None, block_kv: Optional[int] = None,
     interpret: Optional[bool] = None, schedule: Optional[str] = None,
-    bwd: Optional[str] = None, use_tuned: Optional[bool] = None,
-    out_dtype=None,
+    bwd: Optional[str] = None, out_dtype=None,
 ):
     """Shard-local Algorithm 2 against an externally merged (o, lse).
 
@@ -658,7 +594,7 @@ def flash_attention_pallas_shard_bwd(
     """
     cfg = PallasFlashConfig(
         spec=spec, block_q=block_q, block_kv=block_kv, scale=scale,
-        interpret=interpret, schedule=schedule, bwd=bwd, use_tuned=use_tuned,
+        interpret=interpret, schedule=schedule, bwd=bwd,
     )
     qh, kh, vh, _, _, m, meta = _prep_call(q, k, v, cfg)
     oh = _heads_layout(o.astype(jnp.float32))

@@ -113,8 +113,9 @@ def main():
             (params, _), meta = store.restore((params, None))
             print(f"[serve] restored step {meta.get('step')} from {args.ckpt_dir}")
 
-    # Knobs left at None so prefill block sizes and the decode split fan-out
-    # resolve from the committed tuned cache (kernels/autotune) per shape.
+    # Block sizes left at None so prefill resolves them per shape
+    # (kernels/ops.default_block_sizes); the decode fans out over the
+    # config's default split count.
     attn_cfg = AttentionConfig(impl=args.attn)
     obs_registry = MetricsRegistry()
     tracer = TraceRecorder(process=f"serve:{args.engine}") if args.trace_out else None
@@ -155,9 +156,8 @@ def main():
     finished = engine.finished
     toks = sum(len(r.generated) for r in finished.values())
     snap = engine.snapshot()
-    # the kernel knob-source counters live on the process-wide default
-    # registry (they increment deep inside tracing); fold them in so the
-    # exported snapshot answers "which tier did that kernel launch with"
+    # counters with no owner to hand them a registry (sharding, ring,
+    # checkpoint) live on the process-wide default one; fold them in
     snap.update(default_registry().snapshot())
     summary = {
         "engine": args.engine, "requests": len(finished),

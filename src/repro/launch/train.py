@@ -214,8 +214,8 @@ def _current_sharding_fn(template):
 
 def _train(cfg: ModelConfig, loop: TrainLoopConfig, opt_cfg: Optional[AdamWConfig] = None):
     opt_cfg = opt_cfg or AdamWConfig(total_steps=loop.steps)
-    # Block sizes left at None so training picks up tuned knobs (or the
-    # shape-aware heuristics) per shape instead of a hardcoded 256.
+    # Block sizes left at None so training picks up the shape-aware
+    # heuristics per shape instead of a hardcoded 256.
     attn_cfg = AttentionConfig(impl=loop.attn_impl, mode="auto")
     data = make_source(DataConfig(
         batch_size=loop.batch_size, seq_len=loop.seq_len,
@@ -418,7 +418,7 @@ def _train(cfg: ModelConfig, loop: TrainLoopConfig, opt_cfg: Optional[AdamWConfi
         from repro.obs import default_registry
 
         snap = obs.snapshot()
-        snap.update(default_registry().snapshot())  # kernel knob counters
+        snap.update(default_registry().snapshot())  # ring/sharding/ckpt
         with open(loop.metrics_out, "w") as f:
             json.dump(snap, f, indent=1, sort_keys=True)
         print(f"[train] wrote metrics snapshot to {loop.metrics_out}")

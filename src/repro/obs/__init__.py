@@ -6,8 +6,8 @@ shapes (pinned by tests/test_obs.py):
 
   * :mod:`repro.obs.metrics` -- counters / gauges / fixed-bucket
     histograms behind one registry with a flat-dict ``snapshot()``
-    schema. Both serving engines, the KV page pool, the kernel-knob
-    resolution path and the train loop register into it.
+    schema. Both serving engines, the KV page pool, the ring attention
+    path and the train loop register into it.
   * :mod:`repro.obs.trace`   -- span-based request-lifecycle, engine-tick
     and train-step event log exported as Chrome/Perfetto ``trace_event``
     JSON (``--trace-out`` on launch/serve.py and launch/train.py); its
@@ -23,7 +23,6 @@ from repro.obs.metrics import (  # noqa: F401
     Gauge,
     Histogram,
     MetricsRegistry,
-    count_knob,
     default_registry,
     reset_default_registry,
 )

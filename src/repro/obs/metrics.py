@@ -2,10 +2,11 @@
 
 One registry per process component (each serving engine owns one; the
 train loop builds one per run) plus a process-wide *default* registry for
-code that has no owner to hand it one (the kernel knob-resolution
-counters). Everything is plain host-side Python -- no jax arrays, no
-tracing interaction -- so attaching a registry to a jitted loop can never
-add a compile or change a traced shape (tests/test_obs.py pins this).
+code that has no owner to hand it one (the ring attention's trace-time
+``ring/*`` counters). Everything is plain host-side Python -- no jax
+arrays, no tracing interaction -- so attaching a registry to a jitted loop
+can never add a compile or change a traced shape (tests/test_obs.py pins
+this).
 
 Snapshot schema (the single flat dict every exporter consumes):
 
@@ -24,7 +25,7 @@ the existing instrument; re-requesting it as a *different kind* raises.
 from __future__ import annotations
 
 import math
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Sequence
 
 __all__ = [
     "Counter",
@@ -33,7 +34,6 @@ __all__ = [
     "MetricsRegistry",
     "default_registry",
     "reset_default_registry",
-    "count_knob",
 ]
 
 
@@ -201,23 +201,3 @@ def reset_default_registry() -> MetricsRegistry:
     global _default
     _default = MetricsRegistry()
     return _default
-
-
-_KNOB_SOURCES = ("explicit", "tuned", "heuristic")
-
-
-def count_knob(family: str, source: str, n: int = 1,
-               registry: Optional[MetricsRegistry] = None) -> None:
-    """Count one kernel-knob resolution hit: ``knobs/<family>/<source>``.
-
-    ``family`` is the kernel family (``flash_pallas``, ``flash_decode``,
-    ``flash_decode_paged<ps>``); ``source`` is which precedence tier won
-    (explicit > tuned > heuristic). Called from
-    ``kernels/ops.resolve_pallas_knobs`` and the decode-splits resolution
-    at *trace* time -- each jit trace counts once, cached executions do
-    not re-resolve (by design: resolution cost, like compile cost, is
-    per-trace).
-    """
-    if source not in _KNOB_SOURCES:
-        raise ValueError(f"unknown knob source {source!r}; want {_KNOB_SOURCES}")
-    (registry or _default).counter(f"knobs/{family}/{source}").inc(n)

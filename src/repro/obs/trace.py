@@ -69,8 +69,11 @@ class TraceRecorder:
 
     # ------------------------------------------------------------- clock
     def now_us(self) -> float:
-        """Microseconds since recorder construction (event timebase)."""
-        return (self._clock() - self._t0) * 1e6
+        """Microseconds since recorder construction (event timebase), on a
+        grid of 1/1024 us: sums and differences of such times are exact in
+        floating point, so a span recorded as (ts, dur) that ends where the
+        next one starts still nests (``ts + dur`` does not round past it)."""
+        return round((self._clock() - self._t0) * 1e6 * 1024) / 1024
 
     # ------------------------------------------------------------ events
     def name_thread(self, tid: int, name: str) -> None:

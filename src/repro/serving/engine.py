@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.core.attention import AttentionConfig, paged_decode_splits
+from repro.core.attention import AttentionConfig
 from repro.kernels.flash_decode import paged_decode_work
 from repro.launch.steps import (
     build_paged_admit_step,
@@ -526,7 +526,6 @@ class PagedServingEngine(_EngineTelemetry):
         self._c_page_oom = self.obs.counter("serving/page_oom")
         self._c_pool_in_place = self.obs.counter("serving/pool_in_place_calls")
         self._c_pool_copied = self.obs.counter("serving/pool_copied_calls")
-        self._splits: Optional[int] = None  # resolved on the first traced tick
         self.obs.gauge_fn("serving/preemptions", lambda: float(self.preemptions))
         # fraction of *allocated* page cells holding real KV
         self.obs.gauge_fn(
@@ -747,15 +746,10 @@ class PagedServingEngine(_EngineTelemetry):
         step hands it; nothing without a tracer or off the Pallas kernel."""
         if self.tracer is None or self.attn.impl != "flash_pallas":
             return {}
-        if self._splits is None:
-            width = (self.cfg.head_dim if self.cfg.mla is None
-                     else self.cfg.mla.latent_dim)  # the kernel's q width
-            self._splits = paged_decode_splits(
-                self.attn, self.n_max, self.ps, self.cfg.num_heads,
-                width, self.cfg.dtype)
         # the step attends over the new token too; empty slots over nothing
         lens = np.where(self.cache_len > 0, self.cache_len + 1, 0)
-        return paged_decode_work(lens, self.n_max, self.ps, self._splits)
+        return paged_decode_work(lens, self.n_max, self.ps,
+                                 self.attn.decode_splits)
 
     # -------------------------------------------------------------- tick
     def tick(self):

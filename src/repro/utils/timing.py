@@ -1,8 +1,8 @@
 """Interleaved min-of-N wall-clock timing.
 
-The repo's single timing discipline, shared by the benchmark harness
-(``benchmarks/timing.py`` re-exports this module) and the kernel autotuner
-(``repro.kernels.autotune``). Two rules, both load-bearing:
+The repo's single timing discipline, shared by the benchmark modules
+(``benchmarks/timing.py`` re-exports this module) and the tests that time
+kernels. Two rules, both load-bearing:
 
   * **min, not mean.** On a shared host every timing sample is the true
     cost plus non-negative noise (scheduler preemption, page faults, GC,
@@ -12,9 +12,9 @@ The repo's single timing discipline, shared by the benchmark harness
     mean-of-3 and recorded a forward-only row *slower* than the matching
     forward+backward row (BENCH_attn.json, ``ref/causal=0/seq=512``:
     438ms fwd vs 356ms fwd+bwd) -- a physical impossibility that made the
-    whole trajectory untrustworthy and blocked the autotuner.
+    whole trajectory untrustworthy.
   * **interleave competitors.** When two timings will be *compared*
-    (fwd vs fwd+bwd, tuned vs heuristic, fused vs split), round-robin the
+    (fwd vs fwd+bwd, fused vs split), round-robin the
     candidates inside each iteration instead of timing them back-to-back
     in blocks. Slow drift (thermal, co-tenant load) then hits every
     candidate equally instead of biasing whichever ran during the bad

@@ -19,10 +19,7 @@ from repro.obs import (
     MetricsRegistry,
     TraceRecorder,
     TrainEfficiency,
-    count_knob,
-    default_registry,
     peak_flops,
-    reset_default_registry,
     validate_trace,
 )
 from repro.serving.engine import PagedServingEngine, Request, ServingEngine
@@ -87,58 +84,6 @@ def test_kind_conflict_raises():
         reg.histogram("n", (1.0,))
 
 
-def test_count_knob_default_registry():
-    reset_default_registry()
-    count_knob("flash_pallas", "tuned", 3)
-    count_knob("flash_pallas", "explicit")
-    assert default_registry().snapshot() == {
-        "knobs/flash_pallas/tuned": 3.0,
-        "knobs/flash_pallas/explicit": 1.0,
-    }
-    with pytest.raises(ValueError):
-        count_knob("flash_pallas", "vibes")
-    reset_default_registry()
-    assert default_registry().snapshot() == {}
-
-
-def test_knob_resolution_sources_counted():
-    """resolve_pallas_knobs classifies each knob's winning tier."""
-    from repro.kernels.ops import PallasFlashConfig, resolve_pallas_knobs
-
-    shapes = ((1, 128, 2, 32), (1, 128, 2, 32))
-    reset_default_registry()
-    # all four knobs explicit, dense schedule -> no partition knobs in play
-    resolve_pallas_knobs(
-        PallasFlashConfig(spec=MaskSpec(causal=True), block_q=64, block_kv=64,
-                          schedule="dense", bwd="fused", use_tuned=False),
-        *shapes,
-    )
-    assert default_registry().snapshot() == {"knobs/flash_pallas/explicit": 4.0}
-
-    reset_default_registry()
-    # nothing explicit, cache off -> heuristics fill every knob (compact
-    # schedule puts num_q_bands/kv_splits in play: 6 total)
-    resolve_pallas_knobs(
-        PallasFlashConfig(spec=MaskSpec(causal=True), use_tuned=False), *shapes
-    )
-    snap = default_registry().snapshot()
-    assert snap == {"knobs/flash_pallas/heuristic": 6.0}
-    reset_default_registry()
-
-
-def test_decode_splits_source_counted():
-    from repro.kernels.autotune import resolve_decode_splits
-
-    reset_default_registry()
-    resolve_decode_splits(256, 4, 64, jnp.float32, use_tuned=False, default=4)
-    resolve_decode_splits(256, 4, 64, jnp.float32, page_size=8,
-                          use_tuned=False, default=4)
-    snap = default_registry().snapshot()
-    assert snap["knobs/flash_decode/heuristic"] == 1.0
-    assert snap["knobs/flash_decode_paged8/heuristic"] == 1.0
-    reset_default_registry()
-
-
 # ---------------------------------------------------------------------------
 # Trace recorder + validator
 # ---------------------------------------------------------------------------
@@ -174,6 +119,24 @@ def test_trace_spans_nest_and_validate(tmp_path):
     assert by_name["outer"]["dur"] == pytest.approx(3e3)
     # process metadata event is present and first
     assert doc["traceEvents"][0]["ph"] == "M"
+
+
+def test_trace_spans_that_meet_still_nest():
+    """A span recorded as (start, end - start) that ends where the next
+    one starts, as the engine records a request's prefill and decode,
+    must not read as overlapping it once ``ts + dur`` is rounded (these
+    clock readings once did)."""
+    clk = _FakeClock()
+    clk.t = 9168.874080910093
+    tr = TraceRecorder(process="unit", clock=clk)
+    clk.t = 9168.882819003838
+    t0 = tr.now_us()
+    clk.t = 9170.276731251917
+    t1 = tr.now_us()
+    tr.complete("prefill", 1, t0, t1 - t0)
+    clk.t += 0.5
+    tr.complete("decode", 1, t1, tr.now_us() - t1)
+    validate_trace(tr.to_json())
 
 
 def test_trace_validator_rejects_bad_events():

@@ -346,10 +346,10 @@ def test_default_forward_partitions_policy():
 
     cfg = PallasFlashConfig(spec=MaskSpec(causal=True), schedule="dense", num_q_bands=2)
     with pytest.raises(ValueError):
-        _resolve_partitions(cfg, {}, "dense", 4, 8, 8)
+        _resolve_partitions(cfg, "dense", 4, 8, 8)
     cfg = PallasFlashConfig(spec=MaskSpec(causal=True), num_q_bands=5, kv_splits=2)
-    # explicit knobs clamp to t_q and win over a tuned entry
-    assert _resolve_partitions(cfg, {}, "compact", 4, 3, 8) == (3, 2)
-    assert _resolve_partitions(
-        cfg, {"num_q_bands": 1, "kv_splits": 1}, "compact", 4, 3, 8
-    ) == (3, 2)
+    # explicit knobs clamp to t_q and win over the policy
+    assert _resolve_partitions(cfg, "compact", 4, 3, 8) == (3, 2)
+    auto = PallasFlashConfig(spec=MaskSpec(causal=True))
+    assert _resolve_partitions(auto, "compact", 4, 3, 8) == \
+        default_forward_partitions(4, 3, 8)
